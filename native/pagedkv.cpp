@@ -4,9 +4,11 @@
 // B+tree). This is NOT a translation of libmdbx: it is a from-scratch C++17
 // engine with the same architectural properties the reference relies on:
 //
-//   * single data file of 4 KiB pages, read through one large shared mmap —
-//     the OS page cache IS the read cache, nothing is held in process RAM
-//     (unlike native/kvstore.cpp whose std::map holds the whole DB);
+//   * single data file of 4 KiB pages, read through one shared mapping at a
+//     stable address (a reserved address range, file-backed only as far as
+//     the file reaches) — the OS page cache IS the read cache, nothing is
+//     held in process RAM (unlike native/kvstore.cpp whose std::map holds
+//     the whole DB);
 //   * copy-on-write page updates: a writer never touches a page any reader
 //     (or the last durable version) can see — MVCC snapshot isolation falls
 //     out of the design, readers are zero-cost and never block;
@@ -220,7 +222,8 @@ struct TableInfo {
 struct Env {
   int fd = -1;
   std::string dir;
-  uint8_t* map = nullptr;
+  uint8_t* map = nullptr;  // base of the MAPSIZE address reservation
+  uint64_t mapped = 0;     // leading bytes of it backed by the data file
   ~Env() {
     if (map && map != MAP_FAILED) munmap(map, MAPSIZE);
     if (fd >= 0) ::close(fd);
@@ -234,6 +237,23 @@ struct Env {
   std::vector<std::pair<uint64_t, std::vector<uint32_t>>> pending;
   std::vector<uint32_t> freelist_pages;  // current persisted chain
 };
+
+// Back [env->mapped, size) of the reservation with the data file. The
+// reservation is PROT_NONE anonymous memory that nothing touches, and the
+// file-backed part never reaches past the end of the file: on a host that
+// pins mapped memory for a device (a TPU host's IOMMU) the first fault in a
+// file mapping populates it to its full length, so a 1 TiB mapping of a
+// small file was accounted as 1 TiB resident.
+bool env_map_to(Env* env, uint64_t size) {
+  if (size <= env->mapped) return true;
+  if (size > MAPSIZE) return false;
+  void* at = mmap(env->map + env->mapped, size - env->mapped, PROT_READ,
+                  MAP_SHARED | MAP_FIXED, env->fd,
+                  static_cast<off_t>(env->mapped));
+  if (at == MAP_FAILED) return false;
+  env->mapped = size;
+  return true;
+}
 
 struct Txn {
   Env* env;
@@ -917,8 +937,10 @@ Env* env_open(const std::string& dir) {
     env->meta = (!ok1 || (ok0 && m0.txnid > m1.txnid)) ? m0 : m1;
   }
   env->map = static_cast<uint8_t*>(
-      mmap(nullptr, MAPSIZE, PROT_READ, MAP_SHARED, fd, 0));
+      mmap(nullptr, MAPSIZE, PROT_NONE,
+           MAP_PRIVATE | MAP_ANONYMOUS | MAP_NORESERVE, -1, 0));
   if (env->map == MAP_FAILED) return nullptr;
+  if (!env_map_to(env.get(), env->meta.n_pages * PAGE)) return nullptr;
   // load the persisted free list (no readers at open: all reusable)
   uint32_t pg = env->meta.freelist_head;
   while (pg) {
@@ -992,6 +1014,7 @@ int tx_commit(Txn* t) {
   // 4. grow the file, write everything, sync, flip the meta
   if (ftruncate(env->fd, static_cast<off_t>(t->next_page * PAGE)) != 0)
     return -1;
+  if (!env_map_to(env, t->next_page * PAGE)) return -1;
   for (auto& [pgno, buf] : t->dirty) {
     if (pwrite(env->fd, buf.get(), PAGE,
                static_cast<off_t>(pgno) * PAGE) != PAGE)

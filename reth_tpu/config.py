@@ -123,11 +123,6 @@ class RethTpuConfig:
     # menu AOT-compiles, promoting shapes as they warm) | "block" (finish
     # warm-up before serving)
     warmup: str = "off"
-    # persistent XLA compilation cache directory for warm-up (versioned by
-    # kernel-source digest, probe-verified before enabling; corrupt entries
-    # quarantined + rebuilt). Empty = <datadir>/compile-cache when warm-up
-    # is on (--compile-cache-dir CLI equivalent)
-    compile_cache_dir: str = ""
     # parallel sparse commit: width of the live-tip finish path's RLP
     # encode pool AND the proof-worker pool (trie/sparse.py +
     # trie/proof.py). 0 = auto (env RETH_TPU_SPARSE_WORKERS or
@@ -229,8 +224,6 @@ def load_config(path: str | Path | None) -> RethTpuConfig:
     cfg.hash_service = bool(node.get("hash_service", cfg.hash_service))
     cfg.mesh_devices = int(node.get("mesh_devices", cfg.mesh_devices))
     cfg.warmup = str(node.get("warmup", cfg.warmup))
-    cfg.compile_cache_dir = str(node.get("compile_cache_dir",
-                                         cfg.compile_cache_dir))
     cfg.sparse_workers = int(node.get("sparse_workers", cfg.sparse_workers))
     cfg.subtrie_levels = int(node.get("subtrie_levels", cfg.subtrie_levels))
     cfg.parallel_exec = bool(node.get("parallel_exec", cfg.parallel_exec))

@@ -100,11 +100,6 @@ class NodeConfig:
     # probe while serving degraded on the CPU twin ("background"), or
     # finish warm-up before serving ("block"). "off" disables.
     warmup: str = "off"
-    # --compile-cache-dir / [node] compile_cache_dir: persistent XLA
-    # compilation cache (kernel-source-versioned, probe-verified,
-    # quarantine-on-corruption). None = <datadir>/compile-cache when
-    # warm-up is on.
-    compile_cache_dir: str | Path | None = None
     # --health / [node] health: the node health & SLO engine (health.py)
     # — metric time-series retention, burn-rate SLO evaluation over the
     # default rule table, /health + debug_healthCheck/debug_sloStatus/
@@ -205,11 +200,7 @@ class Node:
         if self.warmup is None and config.warmup and config.warmup != "off":
             from ..ops.warmup import build_warmup
 
-            cache_dir = config.compile_cache_dir
-            if not cache_dir and config.datadir:
-                cache_dir = Path(config.datadir) / "compile-cache"
-            self.warmup = build_warmup(
-                supervisor=self.hasher_supervisor, cache_dir=cache_dir)
+            self.warmup = build_warmup(supervisor=self.hasher_supervisor)
             self.committer.attach_warmup(self.warmup)
             if config.warmup == "block":
                 self.warmup.run()

@@ -291,12 +291,13 @@ class KeccakDevice:
         import os
         import time as _time
 
-        from ..metrics import compile_tracker
+        from ..metrics import compile_tracker, keccak_route_metrics
 
         n = len(sub)
         batch_tier = _next_tier(n, self.min_tier, self.max_batch_tier)
         if key == _CPU_BUCKET:
             # over the declared block-tier ceiling: CPU twin, no new program
+            keccak_route_metrics.record_cpu_bucket("over_ceiling")
             return self._cpu_bucket(sub, counts)
         if self.warmup is not None:
             kind = ("keccak.exact"
@@ -306,21 +307,22 @@ class KeccakDevice:
                 # shape not warm yet (degraded-mode serving): hash this
                 # bucket on the CPU twin; it promotes to the device the
                 # moment the warm-up manager marks the shape WARM
+                keccak_route_metrics.record_cpu_bucket("unwarmed")
                 return self._cpu_bucket(sub, counts)
         if key == 1 and os.environ.get("RETH_TPU_PALLAS"):
-            # hand-written fused kernel for the dominant single-block bucket;
-            # any lowering failure falls back to the XLA path below
-            try:
-                from .keccak_pallas import keccak256_pallas_words
+            # hand-written fused kernel for the dominant single-block
+            # bucket. The user asked for it: it runs or it raises — the XLA
+            # program below never answers in its place. Off the TPU (CPU
+            # tests and rehearsals) the same kernel runs interpreted.
+            from .keccak_pallas import keccak256_pallas_words
 
-                w32 = _to_u32(pad_batch(sub, 1), batch_tier)
-                t0 = _time.perf_counter()
-                out = np.asarray(keccak256_pallas_words(w32))[:n]
-                compile_tracker.record("keccak.pallas", (1, batch_tier),
-                                       _time.perf_counter() - t0)
-                return out
-            except Exception:
-                pass
+            w32 = _to_u32(pad_batch(sub, 1), batch_tier)
+            t0 = _time.perf_counter()
+            out = np.asarray(keccak256_pallas_words(
+                w32, interpret=jax.default_backend() != "tpu"))[:n]
+            compile_tracker.record("keccak.pallas", (1, batch_tier),
+                                   _time.perf_counter() - t0)
+            return out
         t0 = _time.perf_counter()
         if self.block_tier is None and key <= self.MAX_EXACT_BLOCKS:
             kind = "keccak.exact"

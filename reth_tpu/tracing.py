@@ -28,7 +28,7 @@ Block-lifecycle layer (this repo's observability tentpole):
   JSONL on circuit-breaker open, watchdog timeout, any
   ``RETH_TPU_FAULT_*`` drill firing (:func:`fault_event`), or on demand
   (:func:`flight_dump` / the ``debug_flightRecorder`` RPC) — the wedge
-  postmortem the BENCH_r01–r05 zeros never had.
+  postmortem a bare error line never had.
 - **Exporters**: the OTLP/JSON file exporter (below) now carries
   trace/span/parent ids; :class:`ChromeTraceExporter` writes the same
   spans as Chrome trace-event JSON that Perfetto / chrome://tracing load

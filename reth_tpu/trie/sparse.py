@@ -1047,7 +1047,7 @@ class ParallelSparseCommitter:
                     min_tier=64, k=self.subtrie_levels,
                     row_floor=self.SUBTRIE_ROW_FLOOR,
                     hole_floor=self.SUBTRIE_HOLE_FLOOR)
-            except Exception:  # noqa: BLE001 — no device stack: classic path
+            except ImportError:  # no jax installed: classic path
                 return None
 
         levels = self._collect([t for _, t in live])
@@ -1187,7 +1187,7 @@ class ParallelSparseCommitter:
                         min_tier=64, k=self._arena_k,
                         row_floor=self.SUBTRIE_ROW_FLOOR,
                         hole_floor=self.SUBTRIE_HOLE_FLOOR)
-                except Exception:  # noqa: BLE001 — no device stack
+                except ImportError:  # no jax installed
                     return None
                 arena.engine = eng
                 fresh = True

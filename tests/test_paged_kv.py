@@ -49,6 +49,46 @@ def test_reopen_multi_commit(tmp_path):
     db2.close()
 
 
+def _data_file_mappings(d) -> list[tuple[int, str]]:
+    """(bytes, perms) of every mapping of the engine's data file."""
+    out = []
+    with open("/proc/self/maps") as f:
+        for line in f:
+            parts = line.split()
+            if len(parts) >= 6 and parts[5] == str(d / "data.rtpg"):
+                lo, hi = (int(x, 16) for x in parts[0].split("-"))
+                out.append((hi - lo, parts[1]))
+    return out
+
+
+def test_file_mapping_never_reaches_past_the_file(tmp_path):
+    """The read mapping is file-backed only as far as the file reaches,
+    inside an untouched address reservation: a host that pins mapped
+    memory for a device populates a file mapping to its full length on the
+    first fault, so a 1 TiB mapping of a small file ran a TPU host out of
+    memory. It grows with the file and reads stay right across growth."""
+    d = tmp_path / "kv"
+    db = paged_db(d)
+    if not os.path.exists("/proc/self/maps"):
+        pytest.skip("no /proc/self/maps here")
+    sizes = []
+    for batch in range(3):
+        with db.tx_mut() as tx:
+            for i in range(2000):
+                tx.put("t", sha(batch * 2000 + i), os.urandom(64))
+        maps = _data_file_mappings(d)
+        file_size = os.path.getsize(d / "data.rtpg")
+        assert maps and sum(n for n, _ in maps) == file_size, (maps, file_size)
+        assert all(perms.startswith("r--s") for _, perms in maps)
+        sizes.append(file_size)
+        with db.tx() as tx:
+            assert tx.entry_count("t") == (batch + 1) * 2000
+            assert tx.get("t", sha(batch * 2000)) is not None
+    assert sizes == sorted(set(sizes))  # it grew, and the mapping followed
+    db.close()
+    assert _data_file_mappings(d) == []
+
+
 def test_dup_subtree_spill_and_unspill(tmp_path):
     """Large duplicate sets spill to a nested B+tree; semantics unchanged."""
     db = paged_db(tmp_path / "kv")

@@ -354,7 +354,7 @@ def test_unwarm_k_shape_routes_per_level():
     from reth_tpu.ops.warmup import MenuShape, WarmupManager
 
     mgr = WarmupManager(menu=[MenuShape("fused.subtrie", 8, 32, 1)],
-                        enable_cache=False, registry=MetricsRegistry())
+                        registry=MetricsRegistry())
     mgr._active = True  # warm-up started, nothing warm yet
     rows = _leaf_rows(21)
     eng = _small_engine(k=8, warmup=mgr)
