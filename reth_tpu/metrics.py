@@ -8,7 +8,9 @@ globally; the node serves GET /metrics from its HTTP server.
 
 from __future__ import annotations
 
+import contextlib
 import threading
+import time
 from dataclasses import dataclass, field
 
 
@@ -308,10 +310,21 @@ def update_process_metrics(registry: MetricsRegistry | None = None) -> None:
 class TrieMetrics:
     """TrieTracker analogue (reference crates/trie metrics): per-commit
     stats for the state-commitment hot path — node/leaf counts, level
-    depth, host→device wire bytes, wall time, split by backend."""
+    depth, host→device wire bytes, wall time, split by backend — and the
+    host seconds of each phase of a turbo commit (:meth:`phase`)."""
+
+    # a turbo commit's phases in the order the serial path runs them
+    # (trie/turbo.py marshal..stage and decode; ops/fused_commit.py
+    # assemble..fetch). On the pipelined path they overlap and the
+    # counters hold thread-seconds; a backend that hashes as it is fed
+    # (the numpy twin, the per-level engines) does so inside "stage".
+    PHASES = ("marshal", "sweep", "stage", "assemble", "upload", "enqueue",
+              "device_wait", "fetch", "decode")
 
     def __init__(self, registry: MetricsRegistry | None = None):
         reg = registry or REGISTRY
+        self._phase_s = {k: reg.counter(f"trie_commit_{k}_seconds_total")
+                         for k in self.PHASES}
         self._nodes = {k: reg.counter(f"trie_commit_nodes_total_{k}")
                        for k in ("device", "numpy")}
         self._leaves = reg.counter("trie_commit_leaves_total")
@@ -334,6 +347,21 @@ class TrieMetrics:
         self.last = {"backend": backend, "nodes": nodes, "levels": levels,
                      "leaves": leaves, "wire_bytes": wire_bytes,
                      "seconds": round(seconds, 4)}
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        """One phase of a commit, measured where the work happens: a
+        ``trie::commit`` span (on the profiler's clock too) whose wall
+        adds to ``trie_commit_<name>_seconds_total``. One per phase per
+        commit or window, never per level, row or record."""
+        from . import tracing
+
+        t0 = time.perf_counter()
+        try:
+            with tracing.span("trie::commit", name):
+                yield
+        finally:
+            self._phase_s[name].increment(time.perf_counter() - t0)
 
 
 trie_metrics = TrieMetrics()
@@ -488,6 +516,18 @@ class FusedCommitMetrics:
         self._fallbacks = reg.counter(
             "fused_subtrie_fallbacks_total",
             "k-level chunks degraded to the per-level or CPU path")
+        self._h2d_bytes = reg.counter(
+            "fused_h2d_bytes_total",
+            "bytes of every host array the fused engines put on the device")
+        self._d2h_bytes = reg.counter(
+            "fused_d2h_bytes_total",
+            "bytes the fused engines' terminal fetches brought back")
+        self._rows_dispatched = reg.counter(
+            "fused_rows_dispatched_total",
+            "row tier of every staged level the device ran")
+        self._rows_needed = reg.counter(
+            "fused_rows_needed_total",
+            "trie nodes among those rows (the padding row is not one)")
         self.last: dict | None = None  # most recent commit, for events/bench
         self.dispatches_cum = 0  # lifetime count (bench deltas)
 
@@ -499,6 +539,16 @@ class FusedCommitMetrics:
 
     def record_fallback(self) -> None:
         self._fallbacks.increment()
+
+    def record_h2d(self, nbytes: int) -> None:
+        self._h2d_bytes.increment(nbytes)
+
+    def record_d2h(self, nbytes: int) -> None:
+        self._d2h_bytes.increment(nbytes)
+
+    def record_rows(self, dispatched: int, needed: int) -> None:
+        self._rows_dispatched.increment(dispatched)
+        self._rows_needed.increment(needed)
 
     def record_commit(self, *, dispatches: int, levels: int, k: int,
                       mode: str) -> None:

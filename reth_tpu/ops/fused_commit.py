@@ -58,6 +58,28 @@ def _timed_call(kind: str, shape, fn, *args):
     return out
 
 
+def _h2d(arr: np.ndarray) -> np.ndarray:
+    """Count a host array on its way to the device (every ``_device_put``
+    / ``_put_batch`` passes through here)."""
+    from ..metrics import fused_metrics
+
+    fused_metrics.record_h2d(arr.nbytes)
+    return arr
+
+
+def _to_host(dev) -> np.ndarray:
+    """A commit's terminal D2H, split at the one sync the copy makes
+    anyway: the wait for the device to finish, then the copy itself."""
+    from ..metrics import fused_metrics, trie_metrics
+
+    with trie_metrics.phase("device_wait"):
+        dev.block_until_ready()
+    with trie_metrics.phase("fetch"):
+        out = np.asarray(dev)
+    fused_metrics.record_d2h(out.nbytes)
+    return out
+
+
 def _bytes_to_words(t):
     """(N, L) u8 templates -> (N, L//4) u32 little-endian lane words."""
     w = t.reshape(t.shape[0], -1, 4).astype(jnp.uint32)
@@ -172,7 +194,9 @@ def _jitted(kind: str, b_tier: int, sharding_key=None):
         "branch": _branch_level,
     }[kind]
     donate = {"plain": 3, "splice": 6, "packed": 8, "branch": 5}[kind]
-    return jax.jit(partial(fn, b_tier=b_tier), donate_argnums=donate)
+    level = partial(fn, b_tier=b_tier)
+    level.__name__ = f"level_{kind}"  # the module's name in a device trace
+    return jax.jit(level, donate_argnums=donate)
 
 
 def _tier(n: int, min_tier: int, growth: int = 4) -> int:
@@ -348,24 +372,28 @@ class FusedLevelEngine:
 
     def finish(self) -> np.ndarray:
         buf, self._buf = self._buf, None
-        return np.asarray(buf)
+        return _to_host(buf)
+
+    def _take_slots(self, slots: np.ndarray) -> np.ndarray:
+        ids = np.zeros((_pow2(max(len(slots), 1), floor=8),), dtype=np.int32)
+        ids[: len(slots)] = slots
+        out = _to_host(jnp.take(self._buf, self._device_put(ids), axis=0))
+        return out[: len(slots)]
 
     def fetch_slots(self, slots: np.ndarray) -> np.ndarray:
         """Small D2H: gather specific digest slots (e.g. per-job roots)
         without pulling the whole buffer; ends the commit."""
-        ids = np.zeros((_pow2(max(len(slots), 1), floor=8),), dtype=np.int32)
-        ids[: len(slots)] = slots
-        out = np.asarray(jnp.take(self._buf, self._device_put(ids), axis=0))
+        out = self._take_slots(slots)
         self._buf = None
-        return out[: len(slots)]
+        return out
 
     # -- mesh seam (overridden by FusedMeshEngine) -------------------------
 
     def _device_put(self, arr: np.ndarray):
-        return jnp.asarray(arr)
+        return jnp.asarray(_h2d(arr))
 
     def _put_batch(self, arr: np.ndarray):
-        return jnp.asarray(arr)
+        return jnp.asarray(_h2d(arr))
 
     def _sharding_key(self):
         return None
@@ -588,8 +616,8 @@ def _staged_packed(b_tier: int, n_pow: int, h_pow: int, u8_len: int,
     Program count is O(log levels), each one a single masked-absorb graph.
     """
 
-    def run(u8, i32, digest_buf, flat_off, len_o, slot_o, hidx_o, hsrc_o,
-            n_valid, h_valid):
+    def mega_packed(u8, i32, digest_buf, flat_off, len_o, slot_o, hidx_o,
+                    hsrc_o, n_valid, h_valid):
         L = b_tier * RATE
         raw = jax.lax.dynamic_slice(u8, (len_o,), (2 * n_pow,))
         raw = raw.reshape(n_pow, 2).astype(jnp.uint32)
@@ -624,7 +652,7 @@ def _staged_packed(b_tier: int, n_pow: int, h_pow: int, u8_len: int,
         d = masked_absorb_words(_bytes_to_words(rows), b_tier, counts)
         return digest_buf.at[slots].set(_digests_to_bytes(d))
 
-    return jax.jit(run, donate_argnums=2)
+    return jax.jit(mega_packed, donate_argnums=2)
 
 
 @lru_cache(maxsize=64)
@@ -632,8 +660,8 @@ def _staged_branch(n_pow: int, ch_pow: int, u8_len: int, i32_len: int,
                    s_tier: int):
     """Per-level staged branch program (see `_staged_packed`)."""
 
-    def run(u8, i32, digest_buf, mask_o, slot_o, chidx_o, chsrc_o,
-            n_valid, ch_valid):
+    def mega_branch(u8, i32, digest_buf, mask_o, slot_o, chidx_o, chsrc_o,
+                    n_valid, ch_valid):
         raw = jax.lax.dynamic_slice(u8, (mask_o,), (2 * n_pow,))
         raw = raw.reshape(n_pow, 2).astype(jnp.uint32)
         vrow = jnp.arange(n_pow, dtype=jnp.int32) < n_valid
@@ -649,7 +677,7 @@ def _staged_branch(n_pow: int, ch_pow: int, u8_len: int, i32_len: int,
         return _branch_level(masks.astype(jnp.int32), slots, crn // 16,
                              crn % 16, cs, digest_buf, b_tier=4)
 
-    return jax.jit(run, donate_argnums=2)
+    return jax.jit(mega_branch, donate_argnums=2)
 
 
 class MegaFusedEngine(FusedLevelEngine):
@@ -827,10 +855,9 @@ class MegaFusedEngine(FusedLevelEngine):
                                chidx_o + ch_pow, chsrc_o + ch_pow)
         return (self._step(u8_need, 1 << 16), self._step(i32_need, 1 << 12))
 
-    def _execute(self) -> None:
-        if self._buf is not None:
-            return
-        u8_len, i32_len = self._buffer_lens()
+    def _assemble(self, u8_len: int, i32_len: int):
+        """The staged parts copied into the two contiguous arrays that
+        cross the wire."""
         u8 = np.zeros((u8_len,), dtype=np.uint8)
         off = 0
         for part in self._u8_parts:
@@ -841,33 +868,51 @@ class MegaFusedEngine(FusedLevelEngine):
         for part in self._i32_parts:
             i32[off:off + part.size] = part
             off += part.size
-        u8d = self._device_put(u8)
-        i32d = self._device_put(i32)
-        buf = self._device_put(np.zeros((self._s_tier, 32), dtype=np.uint8))
+        return u8, i32
+
+    def _execute(self) -> None:
+        from ..metrics import fused_metrics, trie_metrics
+
+        if self._buf is not None:
+            return
+        s_tier = self._s_tier
+        with trie_metrics.phase("assemble"):
+            u8_len, i32_len = self._buffer_lens()
+            u8, i32 = self._assemble(u8_len, i32_len)
+        with trie_metrics.phase("upload"):
+            u8d = self._device_put(u8)
+            i32d = self._device_put(i32)
+            buf = self._device_put(np.zeros((s_tier, 32), dtype=np.uint8))
         s32 = np.int32
-        for e in self._plan:
-            if e[0] == "packed":
-                (_, b_tier, n_pow, h_pow, flat_off, len_o, slot_o, hidx_o,
-                 hsrc_o, n_valid, h_valid) = e
-                fn = _staged_packed(b_tier, n_pow, h_pow, u8_len, i32_len,
-                                    self._s_tier)
-                buf = _timed_call(
-                    "mega.packed", (b_tier, n_pow, h_pow, u8_len, i32_len),
-                    fn, u8d, i32d, buf, s32(flat_off), s32(len_o),
-                    s32(slot_o), s32(hidx_o), s32(hsrc_o),
-                    s32(n_valid), s32(h_valid))
+        rows_dispatched = rows_needed = 0
+        with trie_metrics.phase("enqueue"):
+            for e in self._plan:
+                if e[0] == "packed":
+                    (_, b_tier, n_pow, h_pow, flat_off, len_o, slot_o, hidx_o,
+                     hsrc_o, n_valid, h_valid) = e
+                    fn = _staged_packed(b_tier, n_pow, h_pow, u8_len, i32_len,
+                                        s_tier)
+                    buf = _timed_call(
+                        "mega.packed",
+                        (b_tier, n_pow, h_pow, u8_len, i32_len, s_tier),
+                        fn, u8d, i32d, buf, s32(flat_off), s32(len_o),
+                        s32(slot_o), s32(hidx_o), s32(hsrc_o),
+                        s32(n_valid), s32(h_valid))
+                else:
+                    (_, n_pow, ch_pow, mask_o, slot_o, chidx_o, chsrc_o,
+                     n_valid, c_valid) = e
+                    fn = _staged_branch(n_pow, ch_pow, u8_len, i32_len,
+                                        s_tier)
+                    buf = _timed_call(
+                        "mega.branch",
+                        (n_pow, ch_pow, u8_len, i32_len, s_tier),
+                        fn, u8d, i32d, buf, s32(mask_o), s32(slot_o),
+                        s32(chidx_o), s32(chsrc_o), s32(n_valid),
+                        s32(c_valid))
                 self._count_dispatch()
-            else:
-                (_, n_pow, ch_pow, mask_o, slot_o, chidx_o, chsrc_o,
-                 n_valid, c_valid) = e
-                fn = _staged_branch(n_pow, ch_pow, u8_len, i32_len,
-                                    self._s_tier)
-                buf = _timed_call(
-                    "mega.branch", (n_pow, ch_pow, u8_len, i32_len),
-                    fn, u8d, i32d, buf, s32(mask_o), s32(slot_o),
-                    s32(chidx_o), s32(chsrc_o), s32(n_valid),
-                    s32(c_valid))
-                self._count_dispatch()
+                rows_dispatched += n_pow
+                rows_needed += n_valid - 1  # all but the padding row
+        fused_metrics.record_rows(rows_dispatched, rows_needed)
         self._buf = buf
         self._plan, self._u8_parts, self._i32_parts = [], [], []
 
@@ -917,10 +962,10 @@ class FusedMeshEngine(FusedLevelEngine):
         super().__init__(min_tier=-(-min_tier // mult) * mult)
 
     def _device_put(self, arr: np.ndarray):
-        return jax.device_put(arr, self._replicated)
+        return jax.device_put(_h2d(arr), self._replicated)
 
     def _put_batch(self, arr: np.ndarray):
-        return jax.device_put(arr, self._batch_sharding)
+        return jax.device_put(_h2d(arr), self._batch_sharding)
 
     def _sharding_key(self):
         return self.mesh
@@ -1130,7 +1175,7 @@ def _subtrie_program(b_tier: int, n_pow: int, h_pow: int, steps_pow: int,
         return _branch_level(masks.astype(jnp.int32), slots, crn // 16,
                              crn % 16, cs, buf, b_tier=b_tier)
 
-    def run(u8, i32, params, buf, n_steps):
+    def subtrie_chunk(u8, i32, params, buf, n_steps):
         def body(s, carry):
             p = jax.lax.dynamic_index_in_dim(params, s, axis=0,
                                              keepdims=False)
@@ -1141,7 +1186,7 @@ def _subtrie_program(b_tier: int, n_pow: int, h_pow: int, steps_pow: int,
                 carry)
         return jax.lax.fori_loop(0, n_steps, body, buf)
 
-    return jax.jit(run, donate_argnums=3)
+    return jax.jit(subtrie_chunk, donate_argnums=3)
 
 
 class SubtrieFusedEngine(MegaFusedEngine):
@@ -1403,19 +1448,13 @@ class SubtrieFusedEngine(MegaFusedEngine):
                 self._buf = self._device_put(
                     np.zeros((self._s_tier, 32), dtype=np.uint8))
             return
+        from ..metrics import trie_metrics
+
         k_plan = 1 if self._mode == "perlevel" else self.k
-        chunks = self._chunk_plan(plan, k_plan)
-        u8_len, i32_len = self._chunk_buffer_lens(chunks)
-        u8 = np.zeros((u8_len,), dtype=np.uint8)
-        off = 0
-        for part in self._u8_parts:
-            u8[off:off + part.size] = part
-            off += part.size
-        i32 = np.zeros((i32_len,), dtype=np.int32)
-        off = 0
-        for part in self._i32_parts:
-            i32[off:off + part.size] = part
-            off += part.size
+        with trie_metrics.phase("assemble"):
+            chunks = self._chunk_plan(plan, k_plan)
+            u8_len, i32_len = self._chunk_buffer_lens(chunks)
+            u8, i32 = self._assemble(u8_len, i32_len)
         self._plan, self._u8_parts, self._i32_parts = [], [], []
         self._u8_off = self._i32_off = 0
         # the journal IS the failover: replay is exact because every
@@ -1451,33 +1490,42 @@ class SubtrieFusedEngine(MegaFusedEngine):
 
     def _run_chunks(self, u8: np.ndarray, i32: np.ndarray, chunks: list,
                     u8_len: int, i32_len: int, mode: str) -> None:
-        u8d = self._device_put(u8)
-        i32d = self._device_put(i32)
+        from ..metrics import fused_metrics, trie_metrics
+
+        with trie_metrics.phase("upload"):
+            u8d = self._device_put(u8)
+            i32d = self._device_put(i32)
         s_tier = int(self._buf.shape[0])
-        for entries, b_tier, n_pow, h_pow in chunks:
-            steps_pow = _pow2(len(entries), floor=8)
-            params = np.zeros((steps_pow, _PARAM_W), dtype=np.int32)
-            for i, e in enumerate(entries):
-                if e[0] == "packed":
-                    (_t, _bt, flat_off, len_o, slot_o, hrow_o, hbyte_o,
-                     hsrc_o, n_valid, h_valid) = e
-                    params[i] = (0, flat_off, len_o, slot_o, hrow_o,
-                                 hbyte_o, hsrc_o, n_valid, h_valid, 0)
-                else:
-                    _t, mask_o, slot_o, chidx_o, chsrc_o, n_valid, c_valid = e
-                    params[i] = (1, mask_o, slot_o, chidx_o, chsrc_o, 0, 0,
-                                 n_valid, c_valid, 0)
-            if self.injector is not None:
-                self.injector.on_chunk(mode, len(entries))
-            fn = _subtrie_program(b_tier, n_pow, h_pow, steps_pow,
-                                  u8_len, i32_len, s_tier, self._mesh_arg())
-            self._buf = _timed_call(
-                "fused.subtrie",
-                (b_tier, n_pow, h_pow, steps_pow, u8_len, i32_len,
-                 self._mesh_size()),
-                fn, u8d, i32d, self._device_put(params), self._buf,
-                np.int32(len(entries)))
-            self._count_dispatch(len(entries))
+        with trie_metrics.phase("enqueue"):
+            for entries, b_tier, n_pow, h_pow in chunks:
+                steps_pow = _pow2(len(entries), floor=8)
+                params = np.zeros((steps_pow, _PARAM_W), dtype=np.int32)
+                for i, e in enumerate(entries):
+                    if e[0] == "packed":
+                        (_t, _bt, flat_off, len_o, slot_o, hrow_o, hbyte_o,
+                         hsrc_o, n_valid, h_valid) = e
+                        params[i] = (0, flat_off, len_o, slot_o, hrow_o,
+                                     hbyte_o, hsrc_o, n_valid, h_valid, 0)
+                    else:
+                        (_t, mask_o, slot_o, chidx_o, chsrc_o, n_valid,
+                         c_valid) = e
+                        params[i] = (1, mask_o, slot_o, chidx_o, chsrc_o, 0,
+                                     0, n_valid, c_valid, 0)
+                if self.injector is not None:
+                    self.injector.on_chunk(mode, len(entries))
+                fn = _subtrie_program(b_tier, n_pow, h_pow, steps_pow, u8_len,
+                                      i32_len, s_tier, self._mesh_arg())
+                self._buf = _timed_call(
+                    "fused.subtrie",
+                    (b_tier, n_pow, h_pow, steps_pow, u8_len, i32_len,
+                     s_tier, self._mesh_size()),
+                    fn, u8d, i32d, self._device_put(params), self._buf,
+                    np.int32(len(entries)))
+                self._count_dispatch(len(entries))
+                # every step runs at the chunk's row tier; e[-2] is a
+                # level's n_valid, its rows and the padding row
+                fused_metrics.record_rows(
+                    n_pow * len(entries), sum(e[-2] - 1 for e in entries))
 
     # -- degradation ladder ------------------------------------------------
 
@@ -1595,10 +1643,7 @@ class SubtrieFusedEngine(MegaFusedEngine):
         self._journal = []
         if self._mode == "cpu":  # defensive: delta never degrades to cpu
             return self._buf_np[np.asarray(slots, dtype=np.int64)].copy()
-        ids = np.zeros((_pow2(max(len(slots), 1), floor=8),), dtype=np.int32)
-        ids[: len(slots)] = slots
-        out = np.asarray(jnp.take(self._buf, self._device_put(ids), axis=0))
-        return out[: len(slots)]
+        return self._take_slots(slots)
 
 
 class SubtrieMeshEngine(SubtrieFusedEngine):
@@ -1629,7 +1674,7 @@ class SubtrieMeshEngine(SubtrieFusedEngine):
                          row_floor=row_floor, hole_floor=hole_floor)
 
     def _device_put(self, arr: np.ndarray):
-        return jax.device_put(arr, self._replicated)
+        return jax.device_put(_h2d(arr), self._replicated)
 
     def _batch_multiple(self) -> int:
         return self.mesh.devices.size

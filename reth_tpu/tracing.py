@@ -36,7 +36,10 @@ Block-lifecycle layer (this repo's observability tentpole):
 
 Enablement: span *recording* is off unless ``RETH_TPU_TRACE`` is set
 truthy or :func:`set_trace_enabled` ran (the ``--trace-blocks`` path);
-when off, ``span()`` costs what it always did (one DEBUG log call).
+when off, ``span()`` costs one DEBUG log call and, where JAX is loaded,
+one inert profiler annotation: every span is also a
+``jax.profiler.TraceAnnotation`` ``target:name``, so any profiler trace of
+the process carries the program's spans on the device ops' clock.
 Events (:func:`event` / :func:`fault_event`) record into the flight
 recorder regardless — breaker trips and fault drills are rare and are
 exactly what a postmortem needs.
@@ -251,8 +254,22 @@ def span(target: str, name: str, level: int = logging.DEBUG, **fields):
 
     With tracing enabled the span joins the current thread's trace
     (parent/child ids), records into the flight recorder + per-trace
-    timeline, and exports to the installed OTLP/Chrome exporters."""
+    timeline, and exports to the installed OTLP/Chrome exporters.
+
+    Enabled or not, the span is also a ``jax.profiler.TraceAnnotation``
+    named ``target:name``: inside a profiler session it lands in the
+    ``.xplane.pb`` on the device ops' clock, so an idle device can be
+    attributed to what the host was doing."""
     log = tracer(target)
+    # looked up, not imported: this module stays importable without jax,
+    # and a process that never loaded it has no device trace to share a
+    # clock with
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)  # None while jax half-imported
+    annotation = None
+    if profiler is not None:
+        annotation = profiler.TraceAnnotation(f"{target}:{name}")
+        annotation.__enter__()
     t0 = time.time()
     parent = None
     ctx = None
@@ -269,6 +286,8 @@ def span(target: str, name: str, level: int = logging.DEBUG, **fields):
         raise
     finally:
         dt = time.time() - t0
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
         if ctx is not None:
             _tls.ctx = parent
         extra = " ".join(f"{k}={v}" for k, v in fields.items())

@@ -340,34 +340,38 @@ def _marshal_and_build(lib, jobs, collect_branches: bool, start_depth: int):
     handle (``rtb_free``). Raises ``ValueError`` on sweep rejection —
     exactly the condition the MerkleStage uses to fall back to the general
     committer."""
-    key_arrays, val_chunks, job_off = [], [], [0]
-    for keys, values in jobs:
-        keys = np.ascontiguousarray(keys, dtype=np.uint8).reshape(-1, 32)
-        if len(keys) != len(values):
-            raise ValueError("keys/values length mismatch")
-        order = np.argsort(keys.view("S32").ravel(), kind="stable")
-        key_arrays.append(keys[order])
-        val_chunks.extend(values[i] for i in order)
-        job_off.append(job_off[-1] + len(keys))
-    all_keys = (
-        np.concatenate(key_arrays) if key_arrays else np.zeros((0, 32), np.uint8)
-    )
-    flat_vals = b"".join(val_chunks)
-    val_off = np.zeros((len(val_chunks) + 1,), dtype=np.uint64)
-    if val_chunks:
-        val_off[1:] = np.cumsum(
-            np.fromiter((len(v) for v in val_chunks), dtype=np.uint64,
-                        count=len(val_chunks))
-        )
-    vals_np = np.frombuffer(flat_vals, dtype=np.uint8) if flat_vals else np.zeros(1, np.uint8)
-    job_off_np = np.asarray(job_off, dtype=np.uint64)
+    from ..metrics import trie_metrics
+
+    with trie_metrics.phase("marshal"):
+        key_arrays, val_chunks, job_off = [], [], [0]
+        for keys, values in jobs:
+            keys = np.ascontiguousarray(keys, dtype=np.uint8).reshape(-1, 32)
+            if len(keys) != len(values):
+                raise ValueError("keys/values length mismatch")
+            order = np.argsort(keys.view("S32").ravel(), kind="stable")
+            key_arrays.append(keys[order])
+            val_chunks.extend(values[i] for i in order)
+            job_off.append(job_off[-1] + len(keys))
+        all_keys = np.ascontiguousarray(
+            np.concatenate(key_arrays) if key_arrays
+            else np.zeros((0, 32), np.uint8))
+        flat_vals = b"".join(val_chunks)
+        val_off = np.zeros((len(val_chunks) + 1,), dtype=np.uint64)
+        if val_chunks:
+            val_off[1:] = np.cumsum(
+                np.fromiter((len(v) for v in val_chunks), dtype=np.uint64,
+                            count=len(val_chunks))
+            )
+        vals_np = np.frombuffer(flat_vals, dtype=np.uint8) if flat_vals else np.zeros(1, np.uint8)
+        job_off_np = np.asarray(job_off, dtype=np.uint64)
     err = ctypes.c_int32(0)
-    h = lib.rtb_build(
-        _ptr(np.ascontiguousarray(all_keys), _u8p), len(all_keys),
-        _ptr(job_off_np, _u64p), len(jobs),
-        _ptr(vals_np, _u8p), _ptr(val_off, _u64p),
-        1 if collect_branches else 0, start_depth, ctypes.byref(err),
-    )
+    with trie_metrics.phase("sweep"):
+        h = lib.rtb_build(
+            _ptr(all_keys, _u8p), len(all_keys),
+            _ptr(job_off_np, _u64p), len(jobs),
+            _ptr(vals_np, _u8p), _ptr(val_off, _u64p),
+            1 if collect_branches else 0, start_depth, ctypes.byref(err),
+        )
     if not h:
         reason = {1: "unsorted", 2: "duplicate keys", 3: "bad input",
                   4: "oversized leaf value"}.get(err.value, "unknown")
@@ -407,11 +411,14 @@ class _SweepResult:
 def _sweep_group(lib, jobs, job_ids, collect_branches, start_depth) -> _SweepResult:
     """Producer body: native sweep of one job group (the C++ build releases
     the GIL, so groups sweep concurrently) + full array extraction."""
+    from ..metrics import trie_metrics
+
     t0 = time.perf_counter()
     h, key_arrays = _marshal_and_build(lib, jobs, collect_branches, start_depth)
     try:
         n_levels = lib.rtb_num_levels(h)
-        levels = [_Level(lib, h, i) for i in range(n_levels)]
+        with trie_metrics.phase("stage"):
+            levels = [_Level(lib, h, i) for i in range(n_levels)]
         root_slots = np.zeros((len(jobs),), dtype=np.int32)
         lib.rtb_roots(h, _ptr(root_slots, _i32p))
         root_inlines: list[bytes | None] = [None] * len(jobs)
@@ -590,7 +597,7 @@ class RebuildPipeline:
         self.wire_bytes = 0
 
     def run(self, jobs, collect_branches: bool = False, start_depth: int = 0):
-        from ..metrics import pipeline_metrics
+        from ..metrics import pipeline_metrics, trie_metrics
 
         if not jobs:
             return []
@@ -666,10 +673,12 @@ class RebuildPipeline:
             def dispatch():
                 t1 = time.perf_counter()
                 t1_wall = time.time()
-                for m in merged:
-                    backend.dispatch_packed(m.flat, m.row_off, m.row_len,
-                                            m.row_slot, m.holes, m.b_tier)
-                    backend.dispatch_branch(m.masks, m.bmp_slot, m.children)
+                with trie_metrics.phase("stage"):
+                    for m in merged:
+                        backend.dispatch_packed(m.flat, m.row_off, m.row_len,
+                                                m.row_slot, m.holes, m.b_tier)
+                        backend.dispatch_branch(m.masks, m.bmp_slot,
+                                                m.children)
                 # k-level window boundary: a whole-subtrie engine STAGES
                 # the per-depth calls above and executes the window here
                 # as O(levels/k) fused dispatches — so device hashing of
@@ -753,6 +762,8 @@ class RebuildPipeline:
                         **{k: round(v, 4) for k, v in stages.items()}})
 
     def _collect(self, swept, results, collect_branches, start_depth, stages):
+        from ..metrics import trie_metrics
+
         t0 = time.perf_counter()
         backend = self.backend
         if collect_branches:
@@ -781,14 +792,16 @@ class RebuildPipeline:
         if results:
             results[-1].hashed_nodes = total_hashed
         if collect_branches:
-            for base, sw in swept:
-                if sw.meta_rec is None or not len(sw.meta_rec):
-                    continue
-                job_starts = np.cumsum([0] + [len(k) for k in sw.key_arrays])
-                group_results = [results[j] for j in sw.job_ids]
-                _collect_meta_records(sw.meta_rec, sw.key_arrays, job_starts,
-                                      digests, group_results, start_depth,
-                                      slot_base=base)
+            with trie_metrics.phase("decode"):
+                for base, sw in swept:
+                    if sw.meta_rec is None or not len(sw.meta_rec):
+                        continue
+                    job_starts = np.cumsum(
+                        [0] + [len(k) for k in sw.key_arrays])
+                    group_results = [results[j] for j in sw.job_ids]
+                    _collect_meta_records(sw.meta_rec, sw.key_arrays,
+                                          job_starts, digests, group_results,
+                                          start_depth, slot_base=base)
         stages["fetch"] += time.perf_counter() - t0
         return results
 
@@ -984,14 +997,16 @@ class TurboCommitter:
         n_levels = lib.rtb_num_levels(h)
         hashed_per_level = []
         wire_bytes = 0
-        for i in range(n_levels):
-            lv = _Level(lib, h, i)
-            backend.dispatch_packed(lv.flat, lv.row_off, lv.row_len, lv.row_slot,
-                                    lv.holes, lv.b_tier)
-            backend.dispatch_branch(lv.masks, lv.bmp_slot, lv.children)
-            hashed_per_level.append(len(lv.row_slot) + len(lv.masks))
-            wire_bytes += (lv.flat.nbytes + lv.row_off.nbytes + lv.row_len.nbytes
-                           + lv.masks.nbytes + lv.children.nbytes)
+        with trie_metrics.phase("stage"):
+            for i in range(n_levels):
+                lv = _Level(lib, h, i)
+                backend.dispatch_packed(lv.flat, lv.row_off, lv.row_len,
+                                        lv.row_slot, lv.holes, lv.b_tier)
+                backend.dispatch_branch(lv.masks, lv.bmp_slot, lv.children)
+                hashed_per_level.append(len(lv.row_slot) + len(lv.masks))
+                wire_bytes += (lv.flat.nbytes + lv.row_off.nbytes
+                               + lv.row_len.nbytes + lv.masks.nbytes
+                               + lv.children.nbytes)
         root_slots = np.zeros((n_jobs,), dtype=np.int32)
         lib.rtb_roots(h, _ptr(root_slots, _i32p))
         meta_rec = None
@@ -1034,8 +1049,9 @@ class TurboCommitter:
             seconds=_time.time() - t_start)
         if collect_branches and meta_rec is not None and len(meta_rec):
             job_starts = np.cumsum([0] + [len(k) for k in key_arrays])
-            _collect_meta_records(meta_rec, key_arrays, job_starts, digests,
-                                  results, start_depth)
+            with trie_metrics.phase("decode"):
+                _collect_meta_records(meta_rec, key_arrays, job_starts,
+                                      digests, results, start_depth)
         return results
 
 
