@@ -325,6 +325,7 @@ class TrieMetrics:
         reg = registry or REGISTRY
         self._phase_s = {k: reg.counter(f"trie_commit_{k}_seconds_total")
                          for k in self.PHASES}
+        self._decode_records = reg.counter("trie_commit_decode_records_total")
         self._nodes = {k: reg.counter(f"trie_commit_nodes_total_{k}")
                        for k in ("device", "numpy")}
         self._leaves = reg.counter("trie_commit_leaves_total")
@@ -347,6 +348,12 @@ class TrieMetrics:
         self.last = {"backend": backend, "nodes": nodes, "levels": levels,
                      "leaves": leaves, "wire_bytes": wire_bytes,
                      "seconds": round(seconds, 4)}
+
+    def record_decode(self, records: int) -> None:
+        """Branch records turned into BranchNodes by one decode call:
+        ``trie_commit_decode_seconds_total`` over this is seconds a
+        record, whatever the chunk size."""
+        self._decode_records.increment(records)
 
     @contextlib.contextmanager
     def phase(self, name: str):

@@ -23,12 +23,14 @@ work and the device does all hashing.
 from __future__ import annotations
 
 import ctypes
+import gc
 import os
 import queue as queue_mod
 import subprocess
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
+from itertools import islice
 from pathlib import Path
 
 import numpy as np
@@ -796,11 +798,9 @@ class RebuildPipeline:
                 for base, sw in swept:
                     if sw.meta_rec is None or not len(sw.meta_rec):
                         continue
-                    job_starts = np.cumsum(
-                        [0] + [len(k) for k in sw.key_arrays])
                     group_results = [results[j] for j in sw.job_ids]
                     _collect_meta_records(sw.meta_rec, sw.key_arrays,
-                                          job_starts, digests, group_results,
+                                          digests, group_results,
                                           start_depth, slot_base=base)
         stages["fetch"] += time.perf_counter() - t0
         return results
@@ -1048,42 +1048,69 @@ class TurboCommitter:
             leaves=sum(len(k) for k in key_arrays), wire_bytes=wire_bytes,
             seconds=_time.time() - t_start)
         if collect_branches and meta_rec is not None and len(meta_rec):
-            job_starts = np.cumsum([0] + [len(k) for k in key_arrays])
             with trie_metrics.phase("decode"):
-                _collect_meta_records(meta_rec, key_arrays, job_starts,
-                                      digests, results, start_depth)
+                _collect_meta_records(meta_rec, key_arrays, digests, results,
+                                      start_depth)
         return results
 
 
-def _collect_meta_records(meta_rec, key_arrays, job_starts, digests, results,
+# one native BranchMeta record as rtb_meta_get packs it
+# (native/triebuild.cpp), 80 bytes
+_META_REC = np.dtype([
+    ("job", "<u4"), ("rep_key", "<u4"), ("depth", "<u2"),
+    ("state_mask", "<u2"), ("tree_mask", "<u2"), ("hash_mask", "<u2"),
+    ("child_slot", "<i4", (16,)),
+])
+
+
+def _collect_meta_records(meta_rec, key_arrays, digests, results,
                           start_depth=0, slot_base=0):
-    """Decode native BranchMeta records into per-job TrieUpdates.
+    """Decode native BranchMeta records into per-job TrieUpdates, every
+    record of the call at once: the fields, the path nibbles and the child
+    hashes are gathered by numpy into Python lists and two blobs, and the
+    one loop over records only slices those and builds the objects.
     ``slot_base`` rebases the records' group-local digest slots into the
     pipeline's shared arena slot space."""
-    jobs_f = meta_rec[:, 0:4].copy().view("<u4").ravel()
-    reps = meta_rec[:, 4:8].copy().view("<u4").ravel()
-    depths = meta_rec[:, 8:10].copy().view("<u2").ravel()
-    smasks = meta_rec[:, 10:12].copy().view("<u2").ravel()
-    tmasks = meta_rec[:, 12:14].copy().view("<u2").ravel()
-    hmasks = meta_rec[:, 14:16].copy().view("<u2").ravel()
-    cslots = meta_rec[:, 16:80].copy().view("<i4").reshape(-1, 16)
-    for k in range(len(meta_rec)):
-        j = int(jobs_f[k])
-        keys = key_arrays[j]
-        d = int(depths[k])
-        key = keys[int(reps[k]) - int(job_starts[j])]  # rep_key is global
-        nibs = np.empty((64,), dtype=np.uint8)
-        nibs[0::2] = key >> 4
-        nibs[1::2] = key & 0xF
-        # BranchMeta depths are SUBTRIE-relative; the stored path must
-        # skip the start_depth prefix nibbles of the full key
-        path = bytes(nibs[start_depth : start_depth + d])
-        hm = int(hmasks[k])
-        hashes = tuple(
-            digests[cslots[k, nb] + slot_base].tobytes()
-            for nb in range(16) if (hm >> nb) & 1
-        )
-        results[j].branch_nodes[path] = BranchNode(
-            int(smasks[k]), int(tmasks[k]), hm, hashes
-        )
+    from ..metrics import trie_metrics
+
+    rec = np.ascontiguousarray(meta_rec).view(_META_REC).ravel()
+    n = len(rec)
+    trie_metrics.record_decode(n)
+    if not n:
+        return results
+    # paths: the leading nibbles of every record's representative key, row
+    # after row in one blob. BranchMeta depths are SUBTRIE-relative; the
+    # stored path skips the start_depth prefix nibbles of the full key
+    depth = rec["depth"].astype(np.intp)
+    n_bytes = (start_depth + int(depth.max()) + 1) // 2
+    heads = np.concatenate([k[:, :n_bytes] for k in key_arrays])[
+        rec["rep_key"]]  # rep_key is global
+    nibs = np.empty((n, 2 * n_bytes), dtype=np.uint8)
+    nibs[:, 0::2] = heads >> 4
+    nibs[:, 1::2] = heads & 0xF
+    path_blob = nibs.tobytes()
+    path_lo = np.arange(n, dtype=np.intp) * (2 * n_bytes) + start_depth
+    # child hashes: the hashed children's digests of all records, in record
+    # order and ascending nibble order within a record, as one list
+    hashed = ((rec["hash_mask"][:, None] >> np.arange(16, dtype=np.uint16))
+              & 1).astype(bool)
+    child_hashes = iter(
+        digests[rec["child_slot"][hashed] + slot_base]
+        .view("V32").ravel().tolist())
+    branch_nodes = [r.branch_nodes for r in results]
+    # the nodes and tuples made here hold no cycle; the collector's passes
+    # over them, were it left on, would add a third to this function's time
+    gc_was_enabled = gc.isenabled()
+    gc.disable()
+    try:
+        for j, lo, hi, state, tree, hmask, n_hashed in zip(
+                rec["job"].tolist(), path_lo.tolist(),
+                (path_lo + depth).tolist(), rec["state_mask"].tolist(),
+                rec["tree_mask"].tolist(), rec["hash_mask"].tolist(),
+                hashed.sum(axis=1).tolist()):
+            branch_nodes[j][path_blob[lo:hi]] = BranchNode(
+                state, tree, hmask, tuple(islice(child_hashes, n_hashed)))
+    finally:
+        if gc_was_enabled:
+            gc.enable()
     return results

@@ -24,6 +24,8 @@ from reth_tpu.trie.turbo import TurboCommitter
 
 ROOT = Path(__file__).resolve().parents[1]
 PHASES = [f"trie_commit_{p}_seconds_total" for p in TrieMetrics.PHASES]
+DECODE = ["trie_commit_decode_seconds_total",
+          "trie_commit_decode_records_total"]
 FUSED = ["fused_h2d_bytes_total", "fused_d2h_bytes_total",
          "fused_rows_dispatched_total", "fused_rows_needed_total"]
 
@@ -80,6 +82,54 @@ def test_every_phase_moves_on_the_pipelined_path_with_two_jobs():
     serial = committer.commit_hashed_many(jobs, collect_branches=True,
                                           start_depth=2)
     assert [r.root for r in res] == [r.root for r in serial]
+
+
+def test_the_pipelined_decode_gives_the_serial_paths_branch_nodes(monkeypatch):
+    """Three sweep groups, so two of the decode's calls rebase their
+    records' slots into the shared arena (``slot_base`` above 0)."""
+    from reth_tpu.trie import turbo
+
+    bases = []
+    real = turbo._collect_meta_records
+
+    def spy(*args, slot_base=0):
+        bases.append(slot_base)
+        return real(*args, slot_base=slot_base)
+
+    monkeypatch.setattr(turbo, "_collect_meta_records", spy)
+    committer = TurboCommitter(backend="numpy")
+    jobs = [_job(n, 20 + i, prefix=0x40 + i)
+            for i, n in enumerate((900, 1, 300, 1500, 40))]
+    tracing.set_trace_enabled(True)
+    try:
+        rec = tracing.flight_recorder()
+
+        def commit(fn, **knobs):
+            before, n0 = _counters(DECODE), rec.recorded
+            out = fn(jobs, collect_branches=True, start_depth=2, **knobs)
+            spans = [s for s in rec.snapshot()[-(rec.recorded - n0):]
+                     if (s["target"], s["name"]) == ("trie::commit", "decode")]
+            return out, _moved(before, DECODE), spans
+
+        serial, s_moved, s_spans = commit(committer.commit_hashed_many)
+        assert bases == [0]
+        piped, p_moved, p_spans = commit(committer.commit_hashed_pipelined,
+                                         jobs_per_sweep=2)
+    finally:
+        tracing.set_trace_enabled(False)
+    assert len(bases) == 4 and sorted(bases[1:])[0] == 0 < sorted(bases[1:])[1]
+    n_records = sum(len(r.branch_nodes) for r in serial)
+    assert n_records > 500
+    for got, want in zip(piped, serial):
+        assert got.root == want.root
+        assert type(got.branch_nodes) is dict
+        assert list(got.branch_nodes.items()) == list(want.branch_nodes.items())
+    # one decode phase a commit, however many sweep groups it decodes
+    for moved, spans in ((s_moved, s_spans), (p_moved, p_spans)):
+        assert moved["trie_commit_decode_records_total"] == n_records
+        assert len(spans) == 1
+        assert moved["trie_commit_decode_seconds_total"] == pytest.approx(
+            spans[0]["dur_ms"] / 1e3, rel=0.2, abs=2e-3)
 
 
 def test_phase_is_a_span_and_counts_when_its_body_raises():
