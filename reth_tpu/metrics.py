@@ -313,13 +313,15 @@ class TrieMetrics:
     depth, host→device wire bytes, wall time, split by backend — and the
     host seconds of each phase of a turbo commit (:meth:`phase`)."""
 
-    # a turbo commit's phases in the order the serial path runs them
-    # (trie/turbo.py marshal..stage and decode; ops/fused_commit.py
-    # assemble..fetch). On the pipelined path they overlap and the
-    # counters hold thread-seconds; a backend that hashes as it is fed
-    # (the numpy twin, the per-level engines) does so inside "stage".
-    PHASES = ("marshal", "sweep", "stage", "assemble", "upload", "enqueue",
-              "device_wait", "fetch", "decode")
+    # a turbo commit's phases in the order a commit runs them (trie/turbo.py
+    # marshal..stage and decode; ops/fused_commit.py assemble..fetch).
+    # "pack" is the pipelined path's alone (RebuildPipeline: one window's
+    # levels merged across subtries); there marshal, sweep and the levels'
+    # extraction run on the sweep pool and their counters hold
+    # thread-seconds. A backend that hashes as it is fed (the numpy twin,
+    # the per-level engines) does so inside "stage".
+    PHASES = ("marshal", "sweep", "pack", "stage", "assemble", "upload",
+              "enqueue", "device_wait", "fetch", "decode")
 
     def __init__(self, registry: MetricsRegistry | None = None):
         reg = registry or REGISTRY
@@ -376,8 +378,9 @@ trie_metrics = TrieMetrics()
 
 class PipelineMetrics:
     """Rebuild-pipeline observability (trie/turbo.py RebuildPipeline):
-    per-stage walls (sweep/pack/dispatch/fetch), bounded-queue depth, sweep
-    pool occupancy, window/packing counts, and queue drains onto the CPU
+    per-stage walls (sweep thread-seconds, the consumer's wait for the next
+    sweep in order, pack/dispatch/fetch), swept groups parked, sweep pool
+    occupancy, window/packing counts, and queue drains onto the CPU
     twin after a mid-rebuild device trip — what an operator needs to see
     where the chunked Merkle rebuild is spending its time."""
 
@@ -385,7 +388,7 @@ class PipelineMetrics:
         reg = registry or REGISTRY
         self._stage_s = {
             k: reg.counter(f"trie_pipeline_{k}_seconds_total")
-            for k in ("sweep", "pack", "dispatch", "fetch")
+            for k in ("sweep", "wait", "pack", "dispatch", "fetch")
         }
         self._runs = reg.counter("trie_pipeline_runs_total")
         self._windows = reg.counter(
@@ -396,7 +399,9 @@ class PipelineMetrics:
             "trie_pipeline_queue_drains_total",
             "windows hashed on the CPU twin after a mid-rebuild failover")
         self._qdepth = reg.gauge(
-            "trie_pipeline_queue_depth", "swept groups waiting for hashing")
+            "trie_pipeline_queue_depth",
+            "sweeps finished ahead of the consumer, parked until their turn "
+            "in submission order")
         self._busy = reg.gauge(
             "trie_pipeline_pool_busy", "native sweeps currently running")
         self.last: dict | None = None  # most recent run, for events/bench
@@ -409,20 +414,21 @@ class PipelineMetrics:
 
     def record_run(self, *, jobs: int, groups: int, windows: int,
                    queue_peak: int, drained_windows: int, backend,
-                   wall_s: float, sweep: float, pack: float, dispatch: float,
-                   fetch: float) -> None:
+                   wall_s: float, sweep: float, wait: float, pack: float,
+                   dispatch: float, fetch: float) -> None:
         self._runs.increment()
         self._windows.increment(windows)
         self._subtries.increment(jobs)
         self._drains.increment(drained_windows)
-        for k, v in (("sweep", sweep), ("pack", pack),
+        for k, v in (("sweep", sweep), ("wait", wait), ("pack", pack),
                      ("dispatch", dispatch), ("fetch", fetch)):
             self._stage_s[k].increment(round(v, 6))
         self.last = {
             "jobs": jobs, "groups": groups, "windows": windows,
             "queue_peak": queue_peak, "drained_windows": drained_windows,
             "backend": backend, "wall_s": round(wall_s, 4),
-            "sweep_s": round(sweep, 4), "pack_s": round(pack, 4),
+            "sweep_s": round(sweep, 4), "wait_s": round(wait, 4),
+            "pack_s": round(pack, 4),
             "dispatch_s": round(dispatch, 4), "fetch_s": round(fetch, 4),
         }
 
@@ -535,6 +541,9 @@ class FusedCommitMetrics:
         self._rows_needed = reg.counter(
             "fused_rows_needed_total",
             "trie nodes among those rows (the padding row is not one)")
+        self._arena_grows = reg.counter(
+            "fused_arena_grows_total",
+            "ensure() calls that raised the digest arena's tier")
         self.last: dict | None = None  # most recent commit, for events/bench
         self.dispatches_cum = 0  # lifetime count (bench deltas)
 
@@ -552,6 +561,9 @@ class FusedCommitMetrics:
 
     def record_d2h(self, nbytes: int) -> None:
         self._d2h_bytes.increment(nbytes)
+
+    def record_arena_grow(self) -> None:
+        self._arena_grows.increment()
 
     def record_rows(self, dispatched: int, needed: int) -> None:
         self._rows_dispatched.increment(dispatched)

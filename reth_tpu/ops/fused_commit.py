@@ -364,6 +364,9 @@ class FusedLevelEngine:
         cur = 0 if self._buf is None else self._buf.shape[0]
         if need <= cur:
             return
+        from ..metrics import fused_metrics
+
+        fused_metrics.record_arena_grow()
         new_tier = _pow2(need, floor=max(self.min_tier, 2, cur))
         grown = self._device_put(np.zeros((new_tier, 32), dtype=np.uint8))
         if cur:
@@ -721,8 +724,12 @@ class MegaFusedEngine(FusedLevelEngine):
         """Staged variant: before ``_execute`` the buffer is only a planned
         shape, so growth is free — just raise the tier."""
         if self._buf is None:
-            self._s_tier = max(self._s_tier,
-                               _pow2(max_slots + 1, floor=max(self.min_tier, 2)))
+            tier = _pow2(max_slots + 1, floor=max(self.min_tier, 2))
+            if tier > self._s_tier:
+                from ..metrics import fused_metrics
+
+                fused_metrics.record_arena_grow()
+                self._s_tier = tier
         else:  # already materialized (post-fetch reuse): real copy-grow
             super().ensure(max_slots)
 

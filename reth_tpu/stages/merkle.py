@@ -72,10 +72,11 @@ class MerkleStage(Stage):
         return self._turbo
 
     def _commit_subtries(self, jobs, start_depth: int = 0):
-        """Commit (keys, values) subtrie jobs through the OVERLAPPED rebuild
-        pipeline (trie/turbo.RebuildPipeline): pooled native sweeps feed a
-        bounded queue, same-depth levels from different subtries pack into
-        fused dispatches against the resident digest arena. Falls back to
+        """Commit (keys, values) subtrie jobs through the rebuild pipeline
+        (trie/turbo.RebuildPipeline): pooled native sweeps taken in job
+        order, same-depth levels from different subtries packed into fused
+        dispatches against the resident digest arena, every program shape
+        a function of the chunk. Falls back to
         the general committer when the fast path rejects the input (native
         build unavailable / oversized values — the same degradation the
         single-shot path documents). A committer carrying a supervisor

@@ -25,10 +25,10 @@ from __future__ import annotations
 import ctypes
 import gc
 import os
-import queue as queue_mod
 import subprocess
 import threading
 import time
+from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from itertools import islice
 from pathlib import Path
@@ -558,13 +558,34 @@ class RebuildPipeline:
     """Producer/consumer rebuild pipeline over the turbo commit path.
 
     A small thread pool runs ``native/triebuild.cpp`` sweeps for groups of
-    prefix subtries concurrently (the ctypes call releases the GIL),
-    feeding swept level arrays through a bounded queue; the consumer packs
-    same-depth levels from different subtries into fused dispatches
-    (``_pack_window``) against a resident digest arena, so the host sweep
-    of subtrie group k+1..k+P overlaps hashing of group k. Optional hash
-    workers parallelize window hashing on the numpy twin (windows touch
-    disjoint arena slot ranges, so they are independent).
+    prefix subtries concurrently (the ctypes call releases the GIL) and
+    ahead of the consumer, which packs same-depth levels from different
+    subtries into fused dispatches (``_pack_window``) against a resident
+    digest arena.
+
+    What runs is a function of the job list alone. The sweep groups
+    (``_group_jobs``) and the windows (``pack_window`` consecutive groups
+    each) are laid out before the first sweep starts; the consumer takes
+    sweep results in SUBMISSION order and waits for the next one in order,
+    however the pool's threads finish; the arena is grown to the
+    power-of-two tier that holds the slots swept so far (the tier the
+    engines round to themselves), so it rises, and the hash pool is
+    drained for it, O(log) times a commit. So the merged
+    levels' row and hole tiers, the staged buffer lengths, the arena's
+    tier and the number of windows, everything that keys a device
+    program, repeat from run to run and do not depend on the chunk that
+    came before.
+
+    What overlaps what depends on the backend. The numpy twin and the
+    per-level engines hash a window as it is dispatched, and an engine
+    with ``flush_window`` (the whole-subtrie family) executes its staged
+    window there: on those, hashing window k overlaps the sweeps of
+    window k+1. ``MegaFusedEngine`` (the single-chip default) only STAGES
+    what it is fed and runs every level program in ``finish()``: on it
+    the sweeps overlap one another and the packing and staging of earlier
+    windows, and the device starts when the last window is staged.
+    Optional hash workers parallelize window hashing on the numpy twin
+    (windows touch disjoint arena slot ranges, so they are independent).
 
     Fault surface: a supervised backend ("auto") fails over mid-commit to
     the numpy twin via its journal — the pipeline keeps feeding it, which
@@ -588,6 +609,7 @@ class RebuildPipeline:
             hash_workers or env.get("RETH_TPU_PIPELINE_HASHERS", 1)))
         self.pack_window = int(
             pack_window or env.get("RETH_TPU_PIPELINE_WINDOW", 0) or 16)
+        # sweeps submitted ahead of the consumer, running or finished
         self.queue_depth = int(queue_depth or 2 * self.sweep_workers)
         self.leaves_per_sweep = int(
             leaves_per_sweep
@@ -606,41 +628,29 @@ class RebuildPipeline:
         t_wall = time.perf_counter()
         met = pipeline_metrics
         groups = _group_jobs(jobs, self.leaves_per_sweep, self.jobs_per_sweep)
-        q: queue_mod.Queue = queue_mod.Queue(maxsize=self.queue_depth)
-        stop = threading.Event()
         busy = [0]
         busy_lock = threading.Lock()
         lib, backend = self.lib, self.backend
 
         def task(lo: int, hi: int):
-            if stop.is_set():
-                return
             with busy_lock:
                 busy[0] += 1
                 met.set_pool_busy(busy[0])
             try:
-                out = _sweep_group(lib, jobs[lo:hi], range(lo, hi),
-                                   collect_branches, start_depth)
-            except BaseException as e:  # noqa: BLE001 — re-raised by consumer
-                out = e
+                return _sweep_group(lib, jobs[lo:hi], range(lo, hi),
+                                    collect_branches, start_depth)
             finally:
                 with busy_lock:
                     busy[0] -= 1
                     met.set_pool_busy(busy[0])
-            while not stop.is_set():
-                try:
-                    q.put(out, timeout=0.05)
-                    met.set_queue_depth(q.qsize())
-                    return
-                except queue_mod.Full:
-                    continue
 
         pool = ThreadPoolExecutor(max_workers=self.sweep_workers,
                                   thread_name_prefix="trie-sweep")
         hash_pool = (ThreadPoolExecutor(max_workers=self.hash_workers,
                                         thread_name_prefix="trie-hash")
                      if self.hash_workers > 1 else None)
-        stages = {"sweep": 0.0, "pack": 0.0, "dispatch": 0.0, "fetch": 0.0}
+        stages = {"sweep": 0.0, "wait": 0.0, "pack": 0.0, "dispatch": 0.0,
+                  "fetch": 0.0}
         results: list = [None] * len(jobs)
         swept: list[tuple[int, _SweepResult]] = []  # (slot_base, sweep)
         pending: list = []
@@ -652,22 +662,28 @@ class RebuildPipeline:
 
         def flush(window: list[_SweepResult]) -> None:
             t0 = time.perf_counter()
-            parts = []
-            for sw in window:
-                base = next_slot[0] - 1  # group slot s -> arena slot base+s
-                next_slot[0] += sw.max_slot
-                parts.append((base, sw))
-                swept.append((base, sw))
-            merged = _pack_window(parts)
+            with trie_metrics.phase("pack"):
+                parts = []
+                for sw in window:
+                    base = next_slot[0] - 1  # group slot s -> arena slot base+s
+                    next_slot[0] += sw.max_slot
+                    parts.append((base, sw))
+                    swept.append((base, sw))
+                merged = _pack_window(parts)
             stages["pack"] += time.perf_counter() - t0
+            # grow to the power-of-two tier that holds what has been swept
+            # (capacity hwm + 1: slot 0 is the dummy), the tier the engines
+            # would round ensure(hwm) to themselves: the arena a commit
+            # ends with follows from the slots it holds, not from how they
+            # arrived, and the hash pool is drained only when the tier
+            # really rises, O(log) times a commit
             hwm = next_slot[0] - 1
             if hwm > ensured[0]:
                 for f in pending:
                     f.result()
                 del pending[:]
-                want = max(hwm, 2 * ensured[0])
-                backend.ensure(want)
-                ensured[0] = want
+                ensured[0] = (1 << hwm.bit_length()) - 1
+                backend.ensure(ensured[0])
             if self.injector is not None:
                 self.injector.on_pipeline_window()
             failed_over = getattr(backend, "failed_over", False)
@@ -684,7 +700,7 @@ class RebuildPipeline:
                 # k-level window boundary: a whole-subtrie engine STAGES
                 # the per-depth calls above and executes the window here
                 # as O(levels/k) fused dispatches — so device hashing of
-                # this window still overlaps the next window's sweep
+                # this window overlaps the next window's sweeps
                 flush = getattr(backend, "flush_window", None)
                 if flush is not None:
                     flush()
@@ -708,32 +724,27 @@ class RebuildPipeline:
 
         try:
             backend.begin(0)
-            for lo, hi in groups:
-                pool.submit(task, lo, hi)
-            remaining = len(groups)
-            while remaining:
-                sw = q.get()
-                self.queue_peak = max(self.queue_peak, q.qsize() + 1)
-                met.set_queue_depth(q.qsize())
-                if isinstance(sw, BaseException):
-                    raise sw
-                remaining -= 1
-                stages["sweep"] += sw.sweep_s
-                self.wire_bytes += sw.wire_bytes
-                window = [sw]
-                # fill the window with whatever has already been swept —
-                # never wait: overlap beats packing width
-                while len(window) < self.pack_window and remaining:
-                    try:
-                        sw2 = q.get_nowait()
-                    except queue_mod.Empty:
-                        break
-                    if isinstance(sw2, BaseException):
-                        raise sw2
-                    remaining -= 1
-                    stages["sweep"] += sw2.sweep_s
-                    self.wire_bytes += sw2.wire_bytes
-                    window.append(sw2)
+            todo = iter(groups)
+            ahead: deque = deque()  # sweeps in flight, in submission order
+            for k in range(0, len(groups), self.pack_window):
+                window = []
+                for _ in groups[k:k + self.pack_window]:
+                    ahead.extend(
+                        pool.submit(task, lo, hi) for lo, hi in
+                        islice(todo, self.queue_depth - len(ahead)))
+                    # the next sweep IN ORDER, waited for: one that landed
+                    # early stays parked in ``ahead``
+                    t0 = time.perf_counter()
+                    sw = ahead.popleft().result()
+                    stages["wait"] += time.perf_counter() - t0
+                    # the depth gauge: sweeps finished and not yet taken
+                    # (queue_peak counts the one in hand)
+                    parked = sum(f.done() for f in ahead)
+                    self.queue_peak = max(self.queue_peak, parked + 1)
+                    met.set_queue_depth(parked)
+                    stages["sweep"] += sw.sweep_s
+                    self.wire_bytes += sw.wire_bytes
+                    window.append(sw)
                 flush(window)
             for f in pending:
                 f.result()
@@ -741,15 +752,9 @@ class RebuildPipeline:
             return self._collect(swept, results, collect_branches,
                                  start_depth, stages)
         finally:
-            stop.set()
-            pool.shutdown(wait=True)
+            pool.shutdown(wait=True, cancel_futures=True)
             if hash_pool is not None:
                 hash_pool.shutdown(wait=True)
-            while True:  # unblock producers stuck on a full queue
-                try:
-                    q.get_nowait()
-                except queue_mod.Empty:
-                    break
             met.set_queue_depth(0)
             wall_s = time.perf_counter() - t_wall
             met.record_run(
@@ -933,11 +938,18 @@ class TurboCommitter:
         start_depth: int = 0,
         **knobs,
     ) -> list[TrieBuildResult]:
-        """Overlapped variant of :meth:`commit_hashed_many`: sweep groups of
-        subtries on a thread pool, pack same-depth levels across subtries
-        into fused dispatches, hash into the resident digest arena. Same
-        results bit-for-bit (parity pinned by tests/test_turbo_pipeline.py);
-        ``RETH_TPU_PIPELINE=0`` forces the serial path for A/B runs."""
+        """Pipelined variant of :meth:`commit_hashed_many` for a chunk of
+        two or more tries (:class:`RebuildPipeline`): groups of subtries are
+        swept side by side on a thread pool, same-depth levels packed
+        across subtries into fused dispatches, and hashed into the resident
+        digest arena. The windows, the arena's tier and every program shape
+        follow from the job list alone, never from which sweep thread
+        finished first. Hashing overlaps the sweeps on the numpy twin, the
+        per-level engines and the whole-subtrie engines; the single-chip
+        default, ``MegaFusedEngine``, stages the windows and starts the
+        device in ``finish()``. Same results bit-for-bit (parity pinned by
+        tests/test_turbo_pipeline.py); one job takes the serial path, and
+        ``RETH_TPU_PIPELINE=0`` forces it for A/B runs."""
         if not jobs:
             return []
         if len(jobs) == 1 or os.environ.get("RETH_TPU_PIPELINE", "1") == "0":

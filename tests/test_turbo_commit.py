@@ -306,16 +306,22 @@ def test_bulk_decode_stays_faster_than_the_loop():
     meta_rec = _synthetic_records(rng, 20_000, (60_000,), 1 << 16)
 
     def timed(fn):
-        t0 = time.perf_counter()
+        t0 = time.thread_time()
         out = fn()
-        return time.perf_counter() - t0, out
+        return time.thread_time() - t0, out
 
-    new_s, got = min(
-        (timed(lambda: turbo._collect_meta_records(
-            meta_rec, keys, digests, [TrieBuildResult(root=b"")], 2))
-         for _ in range(2)), key=lambda r: r[0])
-    loop_s, want = timed(
-        lambda: _loop_results(meta_rec, keys, digests, 1, 2, 0))
+    # this thread's CPU seconds, so a turn the scheduler parked counts as
+    # what it ran; and the best of five turns a side, taken in turn, so
+    # what else slows a loaded machine (six test workers share it) has to
+    # hit every turn of one side and none of the other
+    new, loop = [], []
+    for _ in range(5):
+        new.append(timed(lambda: turbo._collect_meta_records(
+            meta_rec, keys, digests, [TrieBuildResult(root=b"")], 2)))
+        loop.append(timed(
+            lambda: _loop_results(meta_rec, keys, digests, 1, 2, 0)))
+    (new_s, got), (loop_s, want) = (min(r, key=lambda t: t[0])
+                                    for r in (new, loop))
     _assert_same_branch_nodes(got, want)
     assert loop_s >= 1.5 * new_s, (loop_s, new_s)
 

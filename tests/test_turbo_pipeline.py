@@ -11,8 +11,11 @@ tests/test_turbo_commit.py).
 
 from __future__ import annotations
 
+import itertools
 import os
 import subprocess
+import threading
+import time
 from pathlib import Path
 
 import numpy as np
@@ -288,3 +291,236 @@ def test_pipeline_concurrent_sweeps_deterministic(turbo_np):
         for _ in range(3)
     ]
     assert runs[0] == runs[1] == runs[2]
+
+
+# -- steady program shapes: a function of the chunk, not of thread timing ----
+#
+# For a given chunk (the job list and start_depth) the compile-tracker keys
+# the commit asks for, the arena's tier, the staged buffer lengths and the
+# number of windows are the same whatever order the sweep threads finish in
+# and whatever chunk came before.
+
+# one sweep group a job (as 125,000-leaf subtries are at the default
+# leaves_per_sweep), every sweep in flight at once
+_STEADY_KNOBS = dict(leaves_per_sweep=200, sweep_workers=4)
+_CHUNK_A = (700, 260, 420, 330)
+_CHUNK_B = (1500, 240, 900)
+_ORDERS = list(itertools.permutations(range(4)))
+
+
+def _chunk(sizes, seed, prefix0=0x30):
+    """Account-chunk-shaped jobs: job k's keys under the prefix byte
+    ``prefix0 + k`` (committed at start_depth=2)."""
+    jobs = []
+    for k, n in enumerate(sizes):
+        keys, values = _job(n, seed + k, val_len=(60, 80))
+        keys[:, 0] = prefix0 + k
+        jobs.append((keys, values))
+    return jobs
+
+
+def _plant_completion_order(monkeypatch, order, gap=0.01):
+    """Make the sweep groups FINISH in ``order`` (a permutation of group
+    numbers, one job a group): each waits for its predecessor's return."""
+    from reth_tpu.trie import turbo
+
+    real = turbo._sweep_group
+    done = [threading.Event() for _ in order]
+    rank = {g: r for r, g in enumerate(order)}
+
+    def ordered(lib, jobs, job_ids, *rest):
+        out = real(lib, jobs, job_ids, *rest)
+        r = rank[job_ids[0]]
+        if r:
+            assert done[r - 1].wait(30)
+            time.sleep(gap)
+        done[r].set()
+        return out
+
+    monkeypatch.setattr(turbo, "_sweep_group", ordered)
+
+
+def _commit_signature(monkeypatch, committer, jobs, order=None):
+    """What one pipelined commit asked of the device and of the arena."""
+    from reth_tpu.metrics import compile_tracker, pipeline_metrics
+
+    keys = set()
+    real = compile_tracker.record
+
+    def spy(kind, shape, seconds):
+        keys.add((kind,) + tuple(shape))
+        return real(kind, shape, seconds)
+
+    with monkeypatch.context() as mp:
+        mp.setattr(compile_tracker, "record", spy)
+        if order is not None:
+            _plant_completion_order(mp, order)
+        results = committer.commit_hashed_pipelined(
+            jobs, collect_branches=True, start_depth=2, **_STEADY_KNOBS)
+    mega = [k for k in keys if k[0].startswith("mega.")]
+    sig = {
+        "keys": frozenset(keys),
+        # (u8_len, i32_len) and s_tier close every level program's key
+        "buffer_lens": {k[-3:-1] for k in mega},
+        "s_tier": ({k[-1] for k in mega} if mega
+                   else {committer.arena.digest_buf(1).shape[0]}),
+        "windows": pipeline_metrics.last["windows"],
+    }
+    return sig, results
+
+
+def _steady_committer(backend):
+    return TurboCommitter(backend=backend, min_tier=8)
+
+
+@pytest.fixture(scope="module")
+def steady_baseline():
+    """Chunk A committed once by each backend, its sweeps left to finish as
+    they will, and the serial path's answers."""
+    jobs = _chunk(_CHUNK_A, seed=900)
+    mp = pytest.MonkeyPatch()
+    try:
+        out = {b: _commit_signature(mp, _steady_committer(b), jobs)
+               for b in ("numpy", "device")}
+    finally:
+        mp.undo()
+    serial = TurboCommitter(backend="numpy").commit_hashed_many(
+        jobs, collect_branches=True, start_depth=2)
+    return jobs, out, serial
+
+
+@pytest.mark.parametrize("order", _ORDERS, ids=lambda o: "".join(map(str, o)))
+@pytest.mark.parametrize("backend", ["numpy", "device"])
+def test_shapes_do_not_follow_sweep_completion_order(
+        monkeypatch, steady_baseline, backend, order):
+    jobs, baseline, serial = steady_baseline
+    want, _ = baseline[backend]
+    got, results = _commit_signature(
+        monkeypatch, _steady_committer(backend), jobs, order)
+    assert got["windows"] == want["windows"] == 1   # 4 groups, pack_window 16
+    assert got["s_tier"] == want["s_tier"] and len(got["s_tier"]) == 1
+    assert got["buffer_lens"] == want["buffer_lens"]
+    assert got["keys"] == want["keys"]
+    if backend == "device":
+        assert len(got["buffer_lens"]) == 1 and len(got["keys"]) >= 2
+    assert [r.root for r in results] == [r.root for r in serial]
+    for g, w in zip(results, serial):
+        assert g.branch_nodes == w.branch_nodes
+
+
+@pytest.mark.parametrize("backend", ["numpy", "device"])
+def test_a_chunk_after_a_different_chunk_asks_for_nothing_new(
+        monkeypatch, backend):
+    committer = _steady_committer(backend)
+    a, b = _chunk(_CHUNK_A, seed=910), _chunk(_CHUNK_B, seed=920, prefix0=0x80)
+    first, res_first = _commit_signature(monkeypatch, committer, a)
+    other, _ = _commit_signature(monkeypatch, committer, b, order=(2, 0, 1))
+    grows = committer.arena.grows
+    again, res_again = _commit_signature(monkeypatch, committer, a,
+                                         order=(3, 2, 1, 0))
+    assert again["keys"] == first["keys"]
+    assert again["buffer_lens"] == first["buffer_lens"]
+    assert again["windows"] == first["windows"] == other["windows"] == 1
+    if backend == "device":
+        assert again["s_tier"] == first["s_tier"]
+        assert other["keys"] != first["keys"]        # B is another chunk
+    else:  # the twin's arena is resident: B grew it, A's return does not
+        assert committer.arena.grows == grows
+    assert [r.root for r in res_again] == [r.root for r in res_first]
+
+
+def _mixed_sizes(n_jobs):
+    """1-3 leaves mixed with thousands, the same for a given job count."""
+    rng = np.random.default_rng(n_jobs)
+    sizes = rng.integers(1, 4, size=n_jobs)
+    big = rng.choice(n_jobs, size=max(1, n_jobs // 8), replace=False)
+    sizes[big] = rng.integers(1000, 3000, size=len(big))
+    return [int(s) for s in sizes]
+
+
+@pytest.mark.parametrize("backend,n_jobs", [
+    ("numpy", 2), ("numpy", 5), ("numpy", 17), ("numpy", 64), ("device", 5)])
+@pytest.mark.parametrize("start_depth", [0, 2])
+def test_pipelined_equals_serial_and_the_plain_reference(
+        backend, n_jobs, start_depth):
+    from benchmark.reference.mpt import build_trie
+
+    jobs = _chunk(_mixed_sizes(n_jobs), seed=1000 + n_jobs, prefix0=0x10)
+    committer = _steady_committer(backend)
+    serial = committer.commit_hashed_many(jobs, collect_branches=True,
+                                          start_depth=start_depth)
+    # several sweep groups and several windows, so slots are rebased
+    piped = committer.commit_hashed_pipelined(
+        jobs, collect_branches=True, start_depth=start_depth,
+        leaves_per_sweep=1000, jobs_per_sweep=3, pack_window=2)
+    for (keys, values), got, want in zip(jobs, piped, serial):
+        order = np.argsort(keys.view("S32").ravel())
+        ref = build_trie(keys[order], [values[i] for i in order], start_depth)
+        assert got.root == want.root == ref.root
+        assert got.branch_nodes == want.branch_nodes
+        plain = {bytes(p): (b.state_mask, b.tree_mask, b.hash_mask,
+                            tuple(b.hashes))
+                 for p, b in got.branch_nodes.items()}
+        assert plain == ref.branches
+
+
+def test_pack_phase_and_arena_grows_move():
+    from reth_tpu import tracing
+    from reth_tpu.metrics import REGISTRY
+
+    names = ["trie_commit_pack_seconds_total", "fused_arena_grows_total",
+             "trie_pipeline_wait_seconds_total", "trie_pipeline_windows_total"]
+    before = {n: REGISTRY.counter(n).value for n in names}
+    tracing.set_trace_enabled(True)
+    try:
+        rec = tracing.flight_recorder()
+        n0 = rec.recorded
+        t0 = time.perf_counter()
+        _steady_committer("device").commit_hashed_pipelined(
+            _chunk(_CHUNK_A, seed=930), collect_branches=True, start_depth=2,
+            **_STEADY_KNOBS)
+        wall = time.perf_counter() - t0
+        spans = [s for s in rec.snapshot()[-(rec.recorded - n0):]
+                 if (s["target"], s["name"]) == ("trie::commit", "pack")]
+    finally:
+        tracing.set_trace_enabled(False)
+    moved = {n: REGISTRY.counter(n).value - before[n] for n in names}
+    assert len(spans) == 1                      # one window, one pack span
+    # the counter's clock brackets the span's
+    assert (spans[0]["dur_ms"] / 1e3 * 0.8 - 2e-3
+            <= moved["trie_commit_pack_seconds_total"] <= wall)
+    assert moved["trie_commit_pack_seconds_total"] > 0
+    assert moved["fused_arena_grows_total"] == 1   # begin(0), then one ensure
+    assert moved["trie_pipeline_windows_total"] == 1
+    assert moved["trie_pipeline_wait_seconds_total"] >= 0
+    rendered = REGISTRY.render()
+    assert "trie_commit_pack_seconds_total" in rendered
+    assert "fused_arena_grows_total" in rendered
+
+
+@pytest.mark.parametrize("hash_workers", [1, 3])
+def test_the_arena_rises_a_tier_at_a_time_however_many_windows(
+        turbo_np, hash_workers):
+    """48 windows ask the backend for room O(log) times, each time for a
+    whole power-of-two tier: the hash pool is drained for the arena only
+    then, so its workers keep several windows hashing in between."""
+    jobs = [_job(40 + 3 * i, seed=400 + i) for i in range(48)]
+    asks = []
+
+    class Spy(_NumpyBackend):
+        def ensure(self, max_slots):
+            asks.append(max_slots)
+            super().ensure(max_slots)
+
+    pipe = RebuildPipeline(Spy(), hash_workers=hash_workers,
+                           jobs_per_sweep=1, pack_window=1)
+    got = pipe.run(jobs, collect_branches=True)
+    assert pipe.windows == 48
+    slots = got[-1].hashed_nodes            # the commit's slot high-water mark
+    assert asks == sorted(set(asks)) and 1 < len(asks) <= slots.bit_length()
+    assert all((a + 1) & a == 0 for a in asks)      # capacity a + 1: 2**k
+    assert asks[-1] == (1 << slots.bit_length()) - 1
+    want = turbo_np.commit_hashed_many(jobs, collect_branches=True)
+    assert [r.root for r in got] == [r.root for r in want]
+    for g, w in zip(got, want):
+        assert g.branch_nodes == w.branch_nodes
