@@ -544,6 +544,13 @@ class FusedCommitMetrics:
         self._arena_grows = reg.counter(
             "fused_arena_grows_total",
             "ensure() calls that raised the digest arena's tier")
+        self._gather_operand_bytes = reg.counter(
+            "fused_gather_operand_bytes_total",
+            "bytes of the staging buffer each packed dispatch's row read "
+            "addresses (the level's extent)")
+        self._gather_rows = reg.counter(
+            "fused_gather_rows_total",
+            "row tier of every packed dispatch whose rows were read")
         self.last: dict | None = None  # most recent commit, for events/bench
         self.dispatches_cum = 0  # lifetime count (bench deltas)
 
@@ -568,6 +575,10 @@ class FusedCommitMetrics:
     def record_rows(self, dispatched: int, needed: int) -> None:
         self._rows_dispatched.increment(dispatched)
         self._rows_needed.increment(needed)
+
+    def record_gather(self, operand_bytes: int, rows: int) -> None:
+        self._gather_operand_bytes.increment(operand_bytes)
+        self._gather_rows.increment(rows)
 
     def record_commit(self, *, dispatches: int, levels: int, k: int,
                       mode: str) -> None:

@@ -605,6 +605,57 @@ class FusedLevelEngine:
         self._count_dispatch()
 
 
+def _level_extent(n_pow: int, L: int, u8_len: int) -> int:
+    """Bytes of the staging buffer a packed level's row read addresses: a
+    level's rows are contiguous from its ``flat_off`` and at most ``n_pow``
+    rows of ``L`` bytes long, so no more than that (all of a buffer that is
+    shorter) is the operand of the read."""
+    return min(n_pow * L, u8_len)
+
+
+# the aligned blocks a level's extent is read in: the TPU's lane width in bytes
+_ROW_BLOCK = 128
+
+
+def _level_rows(u8, flat_off, row_off, row_len, L: int):
+    """(n_pow, L) u8: the tightly staged rows of ONE level, zero past each
+    row's length.
+
+    The operand of the read is the level's own extent of ``u8``, not the
+    buffer, and no index addresses a single byte: on the TPU a per-byte
+    gather costs ~8 ns an index whatever it reads from and more out of a
+    large operand (PERF.md, PR 30). The extent's start is clamped by hand so
+    that it ends inside the buffer, and the level's offset in it carries
+    what the clamp moved: ``dynamic_slice`` would clamp silently and
+    misalign the level. All of a level's bytes lie in
+    ``[first[0], first[0] + sum(row_len))``, inside ``[0, ext)``.
+
+    A row starts at any byte, so it is read as the whole ``_ROW_BLOCK``-byte
+    blocks it can lie in (a gather of aligned rows of a 2-D view of the
+    extent, ``n_blk`` indices a row) and then moved left by its phase in
+    the first block, one bit of the phase at a time, highest first: a barrel
+    shifter of static slices and selects, each stage keeping only the
+    columns a later stage can still reach. The zero padding keeps the last
+    row's blocks, and the junk rows' at ``sum(row_len)``, in bounds."""
+    n_pow = row_off.shape[0]
+    ext = _level_extent(n_pow, L, u8.shape[0])
+    start = jnp.clip(flat_off, 0, u8.shape[0] - ext)
+    seg = jax.lax.dynamic_slice(u8, (start,), (ext,))
+    first = (flat_off - start) + row_off
+    W = _ROW_BLOCK
+    n_blk = -(-(W - 1 + L) // W)
+    blocks = jnp.pad(seg, (0, -ext % W + n_blk * W)).reshape(-1, W)
+    at = (first // W)[:, None] + jnp.arange(n_blk, dtype=jnp.int32)[None, :]
+    rows = blocks[at].reshape(n_pow, n_blk * W)
+    phase = first % W
+    for bit in reversed(range((W - 1).bit_length())):
+        step, keep = 1 << bit, L + (1 << bit) - 1
+        rows = jnp.where(((phase >> bit) & 1)[:, None] == 1,
+                         rows[:, step:step + keep], rows[:, :keep])
+    col = jnp.arange(L, dtype=jnp.int32)[None, :]
+    return jnp.where(col < row_len[:, None].astype(jnp.int32), rows, 0)
+
+
 @lru_cache(maxsize=64)
 def _staged_packed(b_tier: int, n_pow: int, h_pow: int, u8_len: int,
                    i32_len: int, s_tier: int):
@@ -617,6 +668,8 @@ def _staged_packed(b_tier: int, n_pow: int, h_pow: int, u8_len: int,
     are pow2 row/hole tiers, while the level's location in the staging
     buffers (offsets) and its live row/hole counts arrive as traced scalars.
     Program count is O(log levels), each one a single masked-absorb graph.
+    The level's rows are read from its own extent of ``u8`` (`_level_rows`),
+    never from the whole buffer.
     """
 
     def mega_packed(u8, i32, digest_buf, flat_off, len_o, slot_o, hidx_o,
@@ -631,11 +684,8 @@ def _staged_packed(b_tier: int, n_pow: int, h_pow: int, u8_len: int,
         counts = (row_len // RATE + 1).astype(jnp.int32)
         slots = jnp.where(
             vrow, jax.lax.dynamic_slice(i32, (slot_o,), (n_pow,)), 0)
-        # rows gather straight from the staging buffer (no slice
-        # materialization, no padding of the staged bytes)
         col = jnp.arange(L, dtype=jnp.int32)[None, :]
-        idx = jnp.minimum(flat_off + row_off[:, None] + col, u8.shape[0] - 1)
-        rows = jnp.where(col < row_len[:, None].astype(jnp.int32), u8[idx], 0)
+        rows = _level_rows(u8, flat_off, row_off, row_len, L)
         rl = row_len[:, None].astype(jnp.int32)
         rows = rows ^ jnp.where(col == rl, 0x01, 0).astype(jnp.uint8)
         last = (counts * RATE - 1)[:, None]
@@ -892,11 +942,14 @@ class MegaFusedEngine(FusedLevelEngine):
             buf = self._device_put(np.zeros((s_tier, 32), dtype=np.uint8))
         s32 = np.int32
         rows_dispatched = rows_needed = 0
+        gather_bytes = gather_rows = 0
         with trie_metrics.phase("enqueue"):
             for e in self._plan:
                 if e[0] == "packed":
                     (_, b_tier, n_pow, h_pow, flat_off, len_o, slot_o, hidx_o,
                      hsrc_o, n_valid, h_valid) = e
+                    gather_bytes += _level_extent(n_pow, b_tier * RATE, u8_len)
+                    gather_rows += n_pow
                     fn = _staged_packed(b_tier, n_pow, h_pow, u8_len, i32_len,
                                         s_tier)
                     buf = _timed_call(
@@ -920,6 +973,7 @@ class MegaFusedEngine(FusedLevelEngine):
                 rows_dispatched += n_pow
                 rows_needed += n_valid - 1  # all but the padding row
         fused_metrics.record_rows(rows_dispatched, rows_needed)
+        fused_metrics.record_gather(gather_bytes, gather_rows)
         self._buf = buf
         self._plan, self._u8_parts, self._i32_parts = [], [], []
 
@@ -1138,8 +1192,7 @@ def _subtrie_program(b_tier: int, n_pow: int, h_pow: int, steps_pow: int,
         slots = jnp.where(
             vrow, jax.lax.dynamic_slice(i32, (slot_o,), (n_pow,)), 0)
         col = jnp.arange(L, dtype=jnp.int32)[None, :]
-        idx = jnp.minimum(flat_off + row_off[:, None] + col, u8.shape[0] - 1)
-        rows = jnp.where(col < row_len[:, None].astype(jnp.int32), u8[idx], 0)
+        rows = _level_rows(u8, flat_off, row_off, row_len, L)
         rl = row_len[:, None].astype(jnp.int32)
         rows = rows ^ jnp.where(col == rl, 0x01, 0).astype(jnp.uint8)
         last = (counts * RATE - 1)[:, None]
