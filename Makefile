@@ -240,8 +240,8 @@ test-subtrie:
 	JAX_PLATFORMS=cpu \
 	  python -m pytest tests/test_subtrie_fused.py -q -p no:cacheprovider
 
-# overlapped rebuild pipeline: parity vs the serial committer, packing,
-# arena residency, abort/failover drills, chunked-resume — fast, CPU-only
+# rebuild pipeline (the one turbo commit path): parity between layouts and
+# with the plain reference, the one-group chunk, packing, arena residency, abort/failover drills, chunked-resume — fast, CPU-only
 # (the sanitizer stress build is `-m slow`; run it via tsan-triebuild);
 # the whole-subtrie k-level backend rides along (it is a pipeline
 # backend: flush_window per packed window)

@@ -73,10 +73,12 @@ class MerkleStage(Stage):
 
     def _commit_subtries(self, jobs, start_depth: int = 0):
         """Commit (keys, values) subtrie jobs through the rebuild pipeline
-        (trie/turbo.RebuildPipeline): pooled native sweeps taken in job
-        order, same-depth levels from different subtries packed into fused
-        dispatches against the resident digest arena, every program shape
-        a function of the chunk. Falls back to
+        (trie/turbo.RebuildPipeline, the one turbo commit path): native
+        sweeps taken in job order (pooled when the chunk is more than one
+        sweep group, by this thread when it is one), same-depth levels
+        from different subtries packed into fused dispatches against the
+        resident digest arena, every program shape a function of the
+        chunk. Falls back to
         the general committer when the fast path rejects the input (native
         build unavailable / oversized values — the same degradation the
         single-shot path documents). A committer carrying a supervisor

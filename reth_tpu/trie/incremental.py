@@ -380,9 +380,9 @@ def full_state_root_turbo(provider: DatabaseProvider, backend: str = "device",
             if pairs else np.zeros((0, 32), dtype=np.uint8)
         )
         turbo_jobs.append((keys, [v for _, v in pairs]))
-    # storage tries ride the overlapped pipeline: pooled native sweeps +
-    # cross-subtrie level packing (trie/turbo.RebuildPipeline); the single
-    # account-trie job below stays on the serial fast path
+    # storage tries: pooled native sweeps + cross-subtrie level packing
+    # (trie/turbo.RebuildPipeline); the single account-trie job below is the
+    # same path's one-group case, swept by this thread
     results = committer.commit_hashed_pipelined(turbo_jobs, collect_branches=True)
     for addr, res in zip(addrs, results):
         for path, node in res.branch_nodes.items():

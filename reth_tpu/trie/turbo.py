@@ -1,18 +1,24 @@
 """Turbo commit path: native structure sweep + array-level hashing backends.
 
-The end-to-end MerkleStage rebuild pipeline with NO per-node Python:
+The MerkleStage rebuild and every other full-trie commit, with NO per-node
+Python, on ONE path (``TurboCommitter.commit_hashed_pipelined``;
+``commit_hashed_many`` is its older name):
 
-  sorted 32-byte hashed keys + RLP values
-    └─ native/triebuild.cpp  (C++ sweep: structure + RLP templates/masks,
-       flat per-level arrays — replaces trie/committer.py's per-node
-       recursion for the secure-trie full-rebuild shape)
-        └─ per level, deepest first:
-           PACKED rows  → FusedLevelEngine.dispatch_packed   (device)
-           BITMAP rows  → FusedLevelEngine.dispatch_branch   (device)
-           ... or the numpy twin (`_NumpyBackend`) — the measured CPU
-           baseline and the no-jax fallback
-            └─ ONE digest fetch: roots (+ branch-node hashes when
-               TrieUpdates collection is requested)
+  jobs: 32-byte hashed keys + RLP values, one job a trie
+    └─ _group_jobs → sweep groups; PACK_WINDOW consecutive groups a window
+        └─ _sweep_group, one a group: native/triebuild.cpp (C++ sweep:
+           structure + RLP templates/masks, flat per-level arrays, in place
+           of trie/committer.py's per-node recursion). Several groups: on a
+           thread pool, side by side. ONE group (a chunk of one subtrie, a
+           live-tip trie): by the caller, no thread
+            └─ _pack_window: same-depth levels of a window's groups merged
+               (one group's pass through uncopied); per level, deepest first:
+               PACKED rows  → backend.dispatch_packed   (device)
+               BITMAP rows  → backend.dispatch_branch   (device)
+               ... or the numpy twin (`_NumpyBackend`), the measured CPU
+               baseline and the no-jax fallback
+                └─ ONE digest fetch: roots (+ branch-node hashes when
+                   TrieUpdates collection is requested)
 
 Reference analogue: StateRoot's cursor walk + HashBuilder + asm-keccak
 (reference crates/trie/trie/src/trie.rs:32, crates/stages/stages/src/
@@ -158,8 +164,8 @@ class DigestArena:
     between rebuild chunks, and each hashing thread keeps a resident
     row-staging scratch — replacing the per-subtrie buffer allocations the
     chunked rebuild used to pay once per prefix per pass. Growth preserves
-    already-written digests, so a pipelined commit can extend the arena
-    mid-flight (``ensure``) without re-hashing earlier subtries."""
+    already-written digests, so a commit can extend the arena mid-flight
+    (``ensure``) without re-hashing earlier subtries."""
 
     def __init__(self):
         self._digests: np.ndarray | None = None
@@ -181,7 +187,7 @@ class DigestArena:
 
     def rows(self, n: int, length: int) -> np.ndarray:
         """Per-thread resident staging for one dispatch's padded rows
-        (thread-local: hash workers never share a scratch buffer)."""
+        (thread-local: commits on two threads never share a scratch buffer)."""
         need = n * length
         buf = getattr(self._tls, "buf", None)
         if buf is None or buf.size < need:
@@ -215,9 +221,9 @@ class _NumpyBackend:
 
     def ensure(self, max_slots: int) -> None:
         """Grow the digest buffer to hold ``max_slots`` slots, preserving
-        written digests. The pipelined committer only learns a window's
-        slot high-water mark when its sweep lands, so capacity extends
-        mid-commit. Callers must not have dispatches in flight."""
+        written digests. The committer only learns a window's slot
+        high-water mark when its sweeps land, so capacity extends
+        mid-commit."""
         need = max_slots + 1
         if self._buf is not None and self._buf.shape[0] >= need:
             return
@@ -381,7 +387,7 @@ def _marshal_and_build(lib, jobs, collect_branches: bool, start_depth: int):
     return h, key_arrays
 
 
-# -- pipelined rebuild --------------------------------------------------------
+# -- the rebuild pipeline: every commit's path ------------------------------
 
 
 class _SweepResult:
@@ -536,6 +542,24 @@ def _pack_window(parts: list[tuple[int, _SweepResult]]) -> list[_MergedLevel]:
     return out
 
 
+# The layout of a commit: constants, not options. No caller ever set one,
+# and a value that moves, moves the program shapes every chunk asks for.
+# Sweep threads: rtb_build releases the GIL, marshalling does not, so more
+# than four only contend for the interpreter; never fewer than two, so one
+# group is swept while the consumer packs another. 2 * SWEEP_THREADS sweeps
+# are submitted ahead of the consumer (a result parked behind every running
+# sweep; no more host arrays alive than that).
+SWEEP_THREADS = max(2, min(4, os.cpu_count() or 1))
+# consecutive groups a window: same-depth rows of up to 16 * 64 small tries
+# share a dispatch, and hashing starts before a storage chunk of thousands
+# of tries is all swept
+PACK_WINDOW = 16
+# a group closes at the job that reaches either bound: a sweep short against
+# a chunk of 500,000 leaves, long against the cost of a native call
+LEAVES_PER_SWEEP = 32768
+JOBS_PER_SWEEP = 64
+
+
 def _group_jobs(jobs, max_leaves: int, max_jobs: int):
     """Slice the job list into sweep groups: each group is one native
     build (shared levels within the group), bounded by leaves and job
@@ -555,26 +579,26 @@ def _group_jobs(jobs, max_leaves: int, max_jobs: int):
 
 
 class RebuildPipeline:
-    """Producer/consumer rebuild pipeline over the turbo commit path.
+    """The commit of one chunk: sweep groups, windows, one digest arena.
 
-    A small thread pool runs ``native/triebuild.cpp`` sweeps for groups of
-    prefix subtries concurrently (the ctypes call releases the GIL) and
-    ahead of the consumer, which packs same-depth levels from different
-    subtries into fused dispatches (``_pack_window``) against a resident
-    digest arena.
+    The job list is cut into sweep groups (``_group_jobs``) and the groups
+    into windows of ``PACK_WINDOW`` consecutive groups before the first
+    sweep starts. ONE group (one large subtrie, a live-tip trie) is swept
+    by the calling thread; several by a small thread pool
+    (``native/triebuild.cpp``; the ctypes call releases the GIL), side by
+    side and ahead of the consumer, which takes the results in SUBMISSION
+    order, however the threads finish, and packs same-depth levels of a
+    window's groups into fused dispatches (``_pack_window``) against the
+    resident digest arena. A window of one group is that group's own
+    arrays, slot base 0, passed through.
 
-    What runs is a function of the job list alone. The sweep groups
-    (``_group_jobs``) and the windows (``pack_window`` consecutive groups
-    each) are laid out before the first sweep starts; the consumer takes
-    sweep results in SUBMISSION order and waits for the next one in order,
-    however the pool's threads finish; the arena is grown to the
-    power-of-two tier that holds the slots swept so far (the tier the
-    engines round to themselves), so it rises, and the hash pool is
-    drained for it, O(log) times a commit. So the merged
-    levels' row and hole tiers, the staged buffer lengths, the arena's
-    tier and the number of windows, everything that keys a device
-    program, repeat from run to run and do not depend on the chunk that
-    came before.
+    What runs is a function of the job list alone. The arena is grown to
+    the power-of-two tier that holds the slots swept so far (the tier the
+    engines round to themselves; for one group, ``begin(max_slot)``'s), so
+    it rises O(log) times a commit. So the merged levels' row and hole
+    tiers, the staged buffer lengths, the arena's tier and the number of
+    windows, everything that keys a device program, repeat from run to run
+    and do not depend on the chunk that came before.
 
     What overlaps what depends on the backend. The numpy twin and the
     per-level engines hash a window as it is dispatched, and an engine
@@ -583,42 +607,56 @@ class RebuildPipeline:
     window k+1. ``MegaFusedEngine`` (the single-chip default) only STAGES
     what it is fed and runs every level program in ``finish()``: on it
     the sweeps overlap one another and the packing and staging of earlier
-    windows, and the device starts when the last window is staged.
-    Optional hash workers parallelize window hashing on the numpy twin
-    (windows touch disjoint arena slot ranges, so they are independent).
+    windows, and with one group nothing overlaps anything.
 
     Fault surface: a supervised backend ("auto") fails over mid-commit to
-    the numpy twin via its journal — the pipeline keeps feeding it, which
+    the numpy twin via its journal; the pipeline keeps feeding it, which
     is exactly the "drain the queue onto the CPU" semantics; an injected
     ``RETH_TPU_FAULT_PIPELINE_ABORT`` kills the run at a window boundary
     to exercise chunked-rebuild resume.
     """
 
-    def __init__(self, backend, lib=None, *, sweep_workers=None,
-                 hash_workers=1, pack_window=None, queue_depth=None,
-                 leaves_per_sweep=None, jobs_per_sweep=None, injector=None):
-        env = os.environ
-        cpus = os.cpu_count() or 1
+    def __init__(self, backend, lib=None, injector=None):
         self.backend = backend
         self.lib = lib or load_library()
-        self.sweep_workers = int(
-            sweep_workers
-            or env.get("RETH_TPU_PIPELINE_SWEEPERS", 0)
-            or max(2, min(4, cpus)))
-        self.hash_workers = max(1, int(
-            hash_workers or env.get("RETH_TPU_PIPELINE_HASHERS", 1)))
-        self.pack_window = int(
-            pack_window or env.get("RETH_TPU_PIPELINE_WINDOW", 0) or 16)
-        # sweeps submitted ahead of the consumer, running or finished
-        self.queue_depth = int(queue_depth or 2 * self.sweep_workers)
-        self.leaves_per_sweep = int(
-            leaves_per_sweep
-            or env.get("RETH_TPU_PIPELINE_SWEEP_LEAVES", 0) or 32768)
-        self.jobs_per_sweep = int(jobs_per_sweep or 64)
         self.injector = injector
         self.windows = 0
         self.queue_peak = 0
         self.wire_bytes = 0
+
+    def _sweeps(self, groups, sweep, stages):
+        """Each group's ``sweep(lo, hi)``, in the groups' order. One group:
+        the caller's thread runs it. Several: a thread pool runs them side
+        by side, ``2 * SWEEP_THREADS`` submitted ahead of the consumer, who
+        waits for the next one IN ORDER; one that landed early stays parked
+        in ``ahead``."""
+        from ..metrics import pipeline_metrics as met
+
+        if len(groups) == 1:
+            self.queue_peak = 1
+            yield sweep(*groups[0])
+            return
+        pool = ThreadPoolExecutor(max_workers=SWEEP_THREADS,
+                                  thread_name_prefix="trie-sweep")
+        try:
+            todo = iter(groups)
+            ahead: deque = deque()  # sweeps in flight, in submission order
+            for _ in groups:
+                ahead.extend(
+                    pool.submit(sweep, lo, hi) for lo, hi in
+                    islice(todo, 2 * SWEEP_THREADS - len(ahead)))
+                t0 = time.perf_counter()
+                sw = ahead.popleft().result()
+                stages["wait"] += time.perf_counter() - t0
+                # the depth gauge: sweeps finished and not yet taken
+                # (queue_peak counts the one in hand)
+                parked = sum(f.done() for f in ahead)
+                self.queue_peak = max(self.queue_peak, parked + 1)
+                met.set_queue_depth(parked)
+                yield sw
+        finally:
+            pool.shutdown(wait=True, cancel_futures=True)
+            met.set_queue_depth(0)
 
     def run(self, jobs, collect_branches: bool = False, start_depth: int = 0):
         from ..metrics import pipeline_metrics, trie_metrics
@@ -627,12 +665,12 @@ class RebuildPipeline:
             return []
         t_wall = time.perf_counter()
         met = pipeline_metrics
-        groups = _group_jobs(jobs, self.leaves_per_sweep, self.jobs_per_sweep)
+        groups = _group_jobs(jobs, LEAVES_PER_SWEEP, JOBS_PER_SWEEP)
         busy = [0]
         busy_lock = threading.Lock()
         lib, backend = self.lib, self.backend
 
-        def task(lo: int, hi: int):
+        def sweep(lo: int, hi: int):
             with busy_lock:
                 busy[0] += 1
                 met.set_pool_busy(busy[0])
@@ -644,51 +682,39 @@ class RebuildPipeline:
                     busy[0] -= 1
                     met.set_pool_busy(busy[0])
 
-        pool = ThreadPoolExecutor(max_workers=self.sweep_workers,
-                                  thread_name_prefix="trie-sweep")
-        hash_pool = (ThreadPoolExecutor(max_workers=self.hash_workers,
-                                        thread_name_prefix="trie-hash")
-                     if self.hash_workers > 1 else None)
         stages = {"sweep": 0.0, "wait": 0.0, "pack": 0.0, "dispatch": 0.0,
                   "fetch": 0.0}
-        results: list = [None] * len(jobs)
         swept: list[tuple[int, _SweepResult]] = []  # (slot_base, sweep)
-        pending: list = []
-        next_slot = [1]
-        ensured = [0]
-        drained = [0]
-
+        hwm = 0        # arena slots handed out so far (slot 0 is the dummy)
+        ensured = 0    # the slot count the arena was last grown to hold
+        drained = 0
         trace_ctx = tracing.current_context()
-
-        def flush(window: list[_SweepResult]) -> None:
-            t0 = time.perf_counter()
-            with trie_metrics.phase("pack"):
-                parts = []
-                for sw in window:
-                    base = next_slot[0] - 1  # group slot s -> arena slot base+s
-                    next_slot[0] += sw.max_slot
-                    parts.append((base, sw))
-                    swept.append((base, sw))
-                merged = _pack_window(parts)
-            stages["pack"] += time.perf_counter() - t0
-            # grow to the power-of-two tier that holds what has been swept
-            # (capacity hwm + 1: slot 0 is the dummy), the tier the engines
-            # would round ensure(hwm) to themselves: the arena a commit
-            # ends with follows from the slots it holds, not from how they
-            # arrived, and the hash pool is drained only when the tier
-            # really rises, O(log) times a commit
-            hwm = next_slot[0] - 1
-            if hwm > ensured[0]:
-                for f in pending:
-                    f.result()
-                del pending[:]
-                ensured[0] = (1 << hwm.bit_length()) - 1
-                backend.ensure(ensured[0])
-            if self.injector is not None:
-                self.injector.on_pipeline_window()
-            failed_over = getattr(backend, "failed_over", False)
-
-            def dispatch():
+        sweeps = self._sweeps(groups, sweep, stages)
+        try:
+            backend.begin(0)
+            for _ in range(0, len(groups), PACK_WINDOW):
+                window = list(islice(sweeps, PACK_WINDOW))
+                t0 = time.perf_counter()
+                with trie_metrics.phase("pack"):
+                    parts = []
+                    for sw in window:
+                        stages["sweep"] += sw.sweep_s
+                        self.wire_bytes += sw.wire_bytes
+                        parts.append((hwm, sw))  # group slot s -> arena hwm+s
+                        hwm += sw.max_slot
+                    swept += parts
+                    merged = _pack_window(parts)
+                stages["pack"] += time.perf_counter() - t0
+                # grow to the power-of-two tier that holds what has been
+                # swept (capacity hwm + 1), the tier the engines would round
+                # ensure(hwm) to themselves: the arena a commit ends with
+                # follows from the slots it holds, not from how they
+                # arrived, and it rises O(log) times a commit
+                if hwm > ensured:
+                    ensured = (1 << hwm.bit_length()) - 1
+                    backend.ensure(ensured)
+                if self.injector is not None:
+                    self.injector.on_pipeline_window()
                 t1 = time.perf_counter()
                 t1_wall = time.time()
                 with trie_metrics.phase("stage"):
@@ -697,69 +723,30 @@ class RebuildPipeline:
                                                 m.row_slot, m.holes, m.b_tier)
                         backend.dispatch_branch(m.masks, m.bmp_slot,
                                                 m.children)
-                # k-level window boundary: a whole-subtrie engine STAGES
-                # the per-depth calls above and executes the window here
-                # as O(levels/k) fused dispatches — so device hashing of
-                # this window overlaps the next window's sweeps
-                flush = getattr(backend, "flush_window", None)
-                if flush is not None:
-                    flush()
+                # k-level window boundary: a whole-subtrie engine STAGES the
+                # per-depth calls above and executes the window here as
+                # O(levels/k) fused dispatches, so device hashing of this
+                # window overlaps the next window's sweeps
+                flush_window = getattr(backend, "flush_window", None)
+                if flush_window is not None:
+                    flush_window()
                 dt = time.perf_counter() - t1
                 stages["dispatch"] += dt
-                # window dispatch may run on the hash pool: attribute it to
-                # the rebuild's trace explicitly (queue/pool handoff)
                 tracing.record_span(
                     "trie::pipeline", "rebuild.window", t1_wall, dt,
                     ctx=trace_ctx,
-                    fields={"levels": len(merged),
-                            "subtries": len(window)})
-
-            if hash_pool is not None and not failed_over:
-                pending.append(hash_pool.submit(dispatch))
-            else:
-                dispatch()
-            if getattr(backend, "failed_over", False):
-                drained[0] += 1
-            self.windows += 1
-
-        try:
-            backend.begin(0)
-            todo = iter(groups)
-            ahead: deque = deque()  # sweeps in flight, in submission order
-            for k in range(0, len(groups), self.pack_window):
-                window = []
-                for _ in groups[k:k + self.pack_window]:
-                    ahead.extend(
-                        pool.submit(task, lo, hi) for lo, hi in
-                        islice(todo, self.queue_depth - len(ahead)))
-                    # the next sweep IN ORDER, waited for: one that landed
-                    # early stays parked in ``ahead``
-                    t0 = time.perf_counter()
-                    sw = ahead.popleft().result()
-                    stages["wait"] += time.perf_counter() - t0
-                    # the depth gauge: sweeps finished and not yet taken
-                    # (queue_peak counts the one in hand)
-                    parked = sum(f.done() for f in ahead)
-                    self.queue_peak = max(self.queue_peak, parked + 1)
-                    met.set_queue_depth(parked)
-                    stages["sweep"] += sw.sweep_s
-                    self.wire_bytes += sw.wire_bytes
-                    window.append(sw)
-                flush(window)
-            for f in pending:
-                f.result()
-            del pending[:]
-            return self._collect(swept, results, collect_branches,
+                    fields={"levels": len(merged), "subtries": len(window)})
+                if getattr(backend, "failed_over", False):
+                    drained += 1
+                self.windows += 1
+            return self._collect(swept, len(jobs), collect_branches,
                                  start_depth, stages)
         finally:
-            pool.shutdown(wait=True, cancel_futures=True)
-            if hash_pool is not None:
-                hash_pool.shutdown(wait=True)
-            met.set_queue_depth(0)
+            sweeps.close()  # an aborted run: the pool is shut down here
             wall_s = time.perf_counter() - t_wall
             met.record_run(
                 jobs=len(jobs), groups=len(groups), windows=self.windows,
-                queue_peak=self.queue_peak, drained_windows=drained[0],
+                queue_peak=self.queue_peak, drained_windows=drained,
                 backend=getattr(backend, "effective_kind", None),
                 wall_s=wall_s, **stages)
             tracing.record_span(
@@ -768,11 +755,12 @@ class RebuildPipeline:
                 fields={"jobs": len(jobs), "windows": self.windows,
                         **{k: round(v, 4) for k, v in stages.items()}})
 
-    def _collect(self, swept, results, collect_branches, start_depth, stages):
+    def _collect(self, swept, n_jobs, collect_branches, start_depth, stages):
         from ..metrics import trie_metrics
 
         t0 = time.perf_counter()
         backend = self.backend
+        results: list = [None] * n_jobs
         if collect_branches:
             digests = backend.finish()
             roots_raw = None
@@ -780,7 +768,7 @@ class RebuildPipeline:
             digests = None
             flat_slots = np.concatenate([
                 np.where(sw.root_slots > 0, sw.root_slots + base, 0)
-                for base, sw in swept]) if swept else np.zeros((0,), np.int32)
+                for base, sw in swept])
             roots_raw = backend.fetch_slots(flat_slots)
         cursor = 0
         total_hashed = 0
@@ -909,161 +897,65 @@ class TurboCommitter:
             return self.hash_service.lease_backend(factory=build)
         return build()
 
-    def commit_hashed_many(
-        self,
-        jobs: list[tuple[np.ndarray, list[bytes]]],
-        collect_branches: bool = False,
-        start_depth: int = 0,
-    ) -> list[TrieBuildResult]:
-        """Commit many independent secure tries with shared level batching.
-
-        ``jobs``: (keys (n, 32) uint8 — need not be sorted, values aligned
-        RLP-encoded bytes) per trie. ``start_depth`` builds each job as the
-        SUBTRIE below that nibble depth (keys must share the prefix); the
-        root is then the embedded subtree node's hash — the chunked-rebuild
-        boundary stitch uses this. Returns one TrieBuildResult per job
-        (root + optional BranchNode TrieUpdates, paths subtrie-relative)."""
-        lib = self._lib
-        n_jobs = len(jobs)
-        h, key_arrays = _marshal_and_build(lib, jobs, collect_branches, start_depth)
-        try:
-            return self._run(lib, h, n_jobs, key_arrays, collect_branches, start_depth)
-        finally:
-            lib.rtb_free(h)
-
     def commit_hashed_pipelined(
         self,
         jobs: list[tuple[np.ndarray, list[bytes]]],
         collect_branches: bool = False,
         start_depth: int = 0,
-        **knobs,
     ) -> list[TrieBuildResult]:
-        """Pipelined variant of :meth:`commit_hashed_many` for a chunk of
-        two or more tries (:class:`RebuildPipeline`): groups of subtries are
-        swept side by side on a thread pool, same-depth levels packed
-        across subtries into fused dispatches, and hashed into the resident
-        digest arena. The windows, the arena's tier and every program shape
-        follow from the job list alone, never from which sweep thread
-        finished first. Hashing overlaps the sweeps on the numpy twin, the
-        per-level engines and the whole-subtrie engines; the single-chip
-        default, ``MegaFusedEngine``, stages the windows and starts the
-        device in ``finish()``. Same results bit-for-bit (parity pinned by
-        tests/test_turbo_pipeline.py); one job takes the serial path, and
-        ``RETH_TPU_PIPELINE=0`` forces it for A/B runs."""
+        """Commit many independent secure tries: THE commit, whatever the
+        number of jobs (:class:`RebuildPipeline`).
+
+        ``jobs``: (keys (n, 32) uint8, need not be sorted; values aligned
+        RLP-encoded bytes) per trie. ``start_depth`` builds each job as the
+        SUBTRIE below that nibble depth (keys must share the prefix); the
+        root is then the embedded subtree node's hash: the chunked-rebuild
+        boundary stitch uses this. Returns one TrieBuildResult per job
+        (root + optional BranchNode TrieUpdates, paths subtrie-relative).
+
+        Groups of jobs are swept side by side on a thread pool (one group:
+        by the caller, no thread), same-depth levels packed across them
+        into fused dispatches, and hashed into the resident digest arena.
+        The windows, the arena's tier and every program shape follow from
+        the job list alone, never from which sweep thread finished first.
+        Hashing overlaps the sweeps on the numpy twin, the per-level engines
+        and the whole-subtrie engines; the single-chip default,
+        ``MegaFusedEngine``, stages the windows and starts the device in
+        ``finish()``. A sweep's rejection is a ``ValueError``, the condition
+        on which the MerkleStage falls back to the general committer."""
         if not jobs:
             return []
-        if len(jobs) == 1 or os.environ.get("RETH_TPU_PIPELINE", "1") == "0":
-            return self.commit_hashed_many(jobs, collect_branches, start_depth)
-        import time as _time
-
         from ..metrics import trie_metrics
         from ..ops.supervisor import FaultInjector
 
-        t_start = _time.time()
+        t_start = time.time()
         backend = self._make_backend()
         injector = getattr(self.supervisor, "injector", None)
         if injector is None:
             injector = FaultInjector.from_env()
-        if self.backend_kind in ("device", "auto") and "hash_workers" not in knobs:
-            knobs["hash_workers"] = 1  # one device; supervised journal is serial
-        pipe = RebuildPipeline(backend, self._lib, injector=injector, **knobs)
+        pipe = RebuildPipeline(backend, self._lib, injector)
         try:
             results = pipe.run(jobs, collect_branches, start_depth)
         finally:
             release = getattr(backend, "release", None)
             if release is not None:
                 release()  # idempotent: aborted commits must drop the lease
-        effective = getattr(backend, "effective_kind", self.backend_kind)
-        trie_metrics.record_commit(
-            backend=effective,
-            nodes=results[-1].hashed_nodes if results else 0,
-            levels=max((r.levels for r in results), default=0),
-            leaves=sum(len(j[1]) for j in jobs),
-            wire_bytes=pipe.wire_bytes,
-            seconds=_time.time() - t_start)
-        return results
-
-    def _run(self, lib, h, n_jobs, key_arrays, collect_branches, start_depth=0):
-        import time as _time
-
-        from ..metrics import trie_metrics
-
-        t_start = _time.time()
-        backend = self._make_backend()
-        try:
-            return self._run_inner(lib, h, n_jobs, key_arrays, collect_branches,
-                                   start_depth, backend, t_start)
-        finally:
-            release = getattr(backend, "release", None)
-            if release is not None:
-                release()  # idempotent: failed commits must drop the lease
-
-    def _run_inner(self, lib, h, n_jobs, key_arrays, collect_branches,
-                   start_depth, backend, t_start):
-        import time as _time
-
-        from ..metrics import trie_metrics
-
-        max_slot = lib.rtb_max_slot(h)
-        backend.begin(max_slot)
-        n_levels = lib.rtb_num_levels(h)
-        hashed_per_level = []
-        wire_bytes = 0
-        with trie_metrics.phase("stage"):
-            for i in range(n_levels):
-                lv = _Level(lib, h, i)
-                backend.dispatch_packed(lv.flat, lv.row_off, lv.row_len,
-                                        lv.row_slot, lv.holes, lv.b_tier)
-                backend.dispatch_branch(lv.masks, lv.bmp_slot, lv.children)
-                hashed_per_level.append(len(lv.row_slot) + len(lv.masks))
-                wire_bytes += (lv.flat.nbytes + lv.row_off.nbytes
-                               + lv.row_len.nbytes + lv.masks.nbytes
-                               + lv.children.nbytes)
-        root_slots = np.zeros((n_jobs,), dtype=np.int32)
-        lib.rtb_roots(h, _ptr(root_slots, _i32p))
-        meta_rec = None
-        if collect_branches:
-            nmeta = int(lib.rtb_meta_count(h))
-            meta_rec = np.zeros((nmeta, 80), dtype=np.uint8)
-            if nmeta:
-                lib.rtb_meta_get(h, _ptr(meta_rec, _u8p))
-            digests = backend.finish()
-        else:
-            digests = None
-            roots_raw = backend.fetch_slots(np.maximum(root_slots, 0))
-        results = []
-        total_hashed = sum(hashed_per_level)
-        for j in range(n_jobs):
-            slot = int(root_slots[j])
-            if slot > 0:
-                root = (digests[slot] if digests is not None else roots_raw[j]).tobytes()
-            else:
-                ln = lib.rtb_root_inline_len(h, j)
-                if ln == 0:
-                    root = EMPTY_ROOT_HASH
-                else:
-                    buf = np.zeros((ln,), dtype=np.uint8)
-                    lib.rtb_root_inline(h, j, _ptr(buf, _u8p))
-                    root = keccak256(buf.tobytes())
-            results.append(TrieBuildResult(root=root, levels=n_levels))
-        if results:
-            # attribute the shared hash count to the batch (job-level split
-            # is not tracked in turbo mode; totals are what the stage reports)
-            results[-1].hashed_nodes = total_hashed
         # TrieTracker-style commit stats (reference trie metrics/tracker):
-        # what the hot path actually did, on /metrics and in bench triage —
         # a supervised commit that failed over reports the backend that
         # actually produced the digests, not the one that was asked for
         effective = getattr(backend, "effective_kind", self.backend_kind)
         trie_metrics.record_commit(
-            backend=effective, nodes=total_hashed, levels=n_levels,
-            leaves=sum(len(k) for k in key_arrays), wire_bytes=wire_bytes,
-            seconds=_time.time() - t_start)
-        if collect_branches and meta_rec is not None and len(meta_rec):
-            with trie_metrics.phase("decode"):
-                _collect_meta_records(meta_rec, key_arrays, digests, results,
-                                      start_depth)
+            backend=effective,
+            nodes=results[-1].hashed_nodes,
+            levels=max(r.levels for r in results),
+            leaves=sum(len(j[1]) for j in jobs),
+            wire_bytes=pipe.wire_bytes,
+            seconds=time.time() - t_start)
         return results
+
+    def commit_hashed_many(self, jobs, collect_branches=False, start_depth=0):
+        """The older name of :meth:`commit_hashed_pipelined`."""
+        return self.commit_hashed_pipelined(jobs, collect_branches, start_depth)
 
 
 # one native BranchMeta record as rtb_meta_get packs it

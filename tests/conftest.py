@@ -8,6 +8,8 @@ Must run before the first `import jax` anywhere in the test session.
 
 import os
 
+import pytest
+
 # FORCE cpu — a setdefault would let an inherited JAX_PLATFORMS (or a chip
 # attached to the host) run the tests on a device. Tests must be hermetic
 # on the virtual CPU mesh; naming the cpu here is also what entitles
@@ -23,3 +25,36 @@ def pytest_configure(config):
     # style targets opt back in with `-m slow`
     config.addinivalue_line(
         "markers", "slow: sanitizer builds / stress runs excluded from tier-1")
+
+
+@pytest.fixture
+def rebuild_layout(monkeypatch):
+    """Set the rebuild's layout constants (``reth_tpu/trie/turbo.py``:
+    ``SWEEP_THREADS``, ``PACK_WINDOW``, ``LEAVES_PER_SWEEP``,
+    ``JOBS_PER_SWEEP``) for one test, so that a tiny chunk is laid out as
+    many sweep groups and windows: ``rebuild_layout(JOBS_PER_SWEEP=1)``."""
+    from reth_tpu.trie import turbo
+
+    def set_layout(**constants):
+        for name, value in constants.items():
+            monkeypatch.setattr(turbo, name, value, raising=True)
+
+    return set_layout
+
+
+@pytest.fixture
+def seen_plans(monkeypatch):
+    """What MegaFusedEngine is about to execute, copied before it runs."""
+    from reth_tpu.ops import fused_commit as fc
+
+    seen = []
+    orig = fc.MegaFusedEngine._execute
+
+    def spy(self):
+        if self._buf is None:
+            seen.append({"plan": list(self._plan), "s_tier": self._s_tier,
+                         "lens": self._buffer_lens()})
+        return orig(self)
+
+    monkeypatch.setattr(fc.MegaFusedEngine, "_execute", spy)
+    return seen

@@ -62,16 +62,18 @@ def test_every_phase_moves_on_the_serial_path_and_sums_within_the_wall():
     committer.commit_hashed_many(jobs, collect_branches=True, start_depth=2)
     wall = time.perf_counter() - t0
     moved = _moved(before, PHASES)
-    # "pack" is the pipelined path's alone: one job merges nothing
-    assert moved.pop("trie_commit_pack_seconds_total") == 0
+    # "pack" too: one job is a window of one group, which merges nothing
+    # and copies nothing but is still walked
     assert all(v > 0 for v in moved.values()), moved
-    # the phases follow one another on one thread: no second is counted twice
+    # one group is swept by the caller, so the phases follow one another on
+    # one thread: no second is counted twice
     assert sum(moved.values()) <= wall
 
 
-def test_every_phase_moves_on_the_pipelined_path_with_two_jobs():
+def test_every_phase_moves_on_the_pipelined_path_with_two_jobs(rebuild_layout):
     committer = TurboCommitter(backend="device", min_tier=8)
     jobs = [_job(2000, 2, prefix=0x10), _job(2000, 3, prefix=0x11)]
+    rebuild_layout(JOBS_PER_SWEEP=1)    # two groups: the sweep pool
     before = _counters(PHASES)
     t0 = time.perf_counter()
     res = committer.commit_hashed_pipelined(jobs, collect_branches=True,
@@ -87,9 +89,12 @@ def test_every_phase_moves_on_the_pipelined_path_with_two_jobs():
     assert [r.root for r in res] == [r.root for r in serial]
 
 
-def test_the_pipelined_decode_gives_the_serial_paths_branch_nodes(monkeypatch):
-    """Three sweep groups, so two of the decode's calls rebase their
-    records' slots into the shared arena (``slot_base`` above 0)."""
+def test_the_pipelined_decode_gives_the_serial_paths_branch_nodes(
+        monkeypatch, rebuild_layout):
+    """The chunk as ONE sweep group (what the serial path was: one decode
+    call, ``slot_base`` 0), then as three, so two of the decode's calls
+    rebase their records' slots into the shared arena (``slot_base`` above
+    0)."""
     from reth_tpu.trie import turbo
 
     bases = []
@@ -113,17 +118,17 @@ def test_the_pipelined_decode_gives_the_serial_paths_branch_nodes(monkeypatch):
     try:
         rec = tracing.flight_recorder()
 
-        def commit(fn, **knobs):
+        def commit(fn):
             before, n0 = _counters(DECODE), rec.recorded
-            out = fn(jobs, collect_branches=True, start_depth=2, **knobs)
+            out = fn(jobs, collect_branches=True, start_depth=2)
             spans = [s for s in rec.snapshot()[-(rec.recorded - n0):]
                      if (s["target"], s["name"]) == ("trie::commit", "decode")]
             return out, _moved(before, DECODE), spans
 
         serial, s_moved, s_spans = commit(committer.commit_hashed_many)
         assert bases == [0]
-        piped, p_moved, p_spans = commit(committer.commit_hashed_pipelined,
-                                         jobs_per_sweep=2)
+        rebuild_layout(JOBS_PER_SWEEP=2)
+        piped, p_moved, p_spans = commit(committer.commit_hashed_pipelined)
     finally:
         tracing.set_trace_enabled(False)
         gc.enable()
@@ -212,22 +217,6 @@ def test_span_works_where_jax_was_never_imported():
 
 
 # -- bytes and rows -----------------------------------------------------------
-
-
-@pytest.fixture
-def seen_plans(monkeypatch):
-    """What MegaFusedEngine is about to execute, copied before it runs."""
-    seen = []
-    orig = fc.MegaFusedEngine._execute
-
-    def spy(self):
-        if self._buf is None:
-            seen.append({"plan": list(self._plan), "s_tier": self._s_tier,
-                         "lens": self._buffer_lens()})
-        return orig(self)
-
-    monkeypatch.setattr(fc.MegaFusedEngine, "_execute", spy)
-    return seen
 
 
 @pytest.mark.parametrize("collect_branches", [True, False])

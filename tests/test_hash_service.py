@@ -247,7 +247,7 @@ def test_lease_pauses_device_dispatch_and_bypasses_aged():
     assert svc.leases == 1
 
 
-def test_lease_backend_wraps_turbo_commit():
+def test_lease_backend_wraps_turbo_commit(rebuild_layout):
     """TurboCommitter(hash_service=...) holds the exclusive lease for each
     commit; roots stay bit-identical to the unleased committer, and an
     aborted commit releases the lease (no wedged service)."""
@@ -286,8 +286,8 @@ def test_lease_backend_wraps_turbo_commit():
     # aborted pipelined commit: the finally-path must drop the lease
     dev.supervisor = type("S", (), {"injector": FaultInjector(pipeline_abort=1)})()
     with pytest.raises(InjectedPipelineAbort):
-        dev.commit_hashed_pipelined(jobs, pack_window=1, sweep_workers=1,
-                                    leaves_per_sweep=64)
+        rebuild_layout(PACK_WINDOW=1, SWEEP_THREADS=1, LEAVES_PER_SWEEP=64)
+        dev.commit_hashed_pipelined(jobs)
     with svc._cond:
         assert not svc._leased
     # and the service still works afterwards

@@ -1,12 +1,15 @@
-"""Overlapped rebuild pipeline (trie/turbo.py RebuildPipeline): parity,
-packing, arena residency, fault drills, and the threaded native sweep.
+"""The rebuild pipeline (trie/turbo.py RebuildPipeline), the one path of
+every turbo commit: parity, packing, arena residency, fault drills, the
+threaded native sweep, and the one-group chunk.
 
-The pipeline must be bit-identical to the serial turbo path it overlaps:
-pooled `native/triebuild.cpp` sweeps + cross-subtrie level packing +
-resident digest arena may change WHEN rows hash, never WHAT they hash.
-Roots and TrieUpdates branch metadata are pinned against
-``commit_hashed_many`` (itself pinned to the Python oracle by
-tests/test_turbo_commit.py).
+However a chunk is laid out, as many sweep groups and windows or as ONE
+group swept by the caller, the answers are bit-identical: pooled
+`native/triebuild.cpp` sweeps + cross-subtrie level packing + resident
+digest arena may change WHEN rows hash, never WHAT they hash. Roots and
+TrieUpdates branch metadata are pinned against the same chunk as one group
+(the layout of every chunk at the program's constants below 64 jobs and
+32,768 leaves; ``commit_hashed_many`` is the same call by its older name)
+and against the plain reference (``benchmark/reference/mpt.py``).
 """
 
 from __future__ import annotations
@@ -16,18 +19,23 @@ import os
 import subprocess
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
 
 import numpy as np
 import pytest
 
+from reth_tpu.ops import fused_commit as fc
 from reth_tpu.primitives.rlp import rlp_encode
+from reth_tpu.trie import turbo
 from reth_tpu.trie.turbo import (
     DigestArena,
     RebuildPipeline,
     TurboCommitter,
     _group_jobs,
     _NumpyBackend,
+    _pack_window,
+    _sweep_group,
 )
 
 NATIVE = Path(__file__).resolve().parent.parent / "native"
@@ -65,29 +73,30 @@ def turbo_np():
 # -- parity ------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("knobs", [
-    dict(jobs_per_sweep=1, pack_window=1),       # no packing, max overlap
-    dict(jobs_per_sweep=4, pack_window=16),      # grouped sweeps, wide packs
-    dict(jobs_per_sweep=64, leaves_per_sweep=200),  # leaf-bounded groups
-    dict(hash_workers=3),                        # parallel window hashing
+@pytest.mark.parametrize("layout", [
+    dict(JOBS_PER_SWEEP=1, PACK_WINDOW=1),       # no packing, max overlap
+    dict(JOBS_PER_SWEEP=4, PACK_WINDOW=16),      # grouped sweeps, wide packs
+    dict(JOBS_PER_SWEEP=64, LEAVES_PER_SWEEP=200),  # leaf-bounded groups
 ])
-def test_pipelined_root_and_branch_parity(turbo_np, knobs):
+def test_pipelined_root_and_branch_parity(turbo_np, rebuild_layout, layout):
     jobs = [_job(30 + 17 * i, seed=i) for i in range(12)]
-    want = turbo_np.commit_hashed_many(jobs, collect_branches=True)
-    got = turbo_np.commit_hashed_pipelined(jobs, collect_branches=True, **knobs)
+    want = turbo_np.commit_hashed_many(jobs, collect_branches=True)  # 1 group
+    rebuild_layout(**layout)
+    got = turbo_np.commit_hashed_pipelined(jobs, collect_branches=True)
     assert [r.root for r in got] == [r.root for r in want]
     for g, w in zip(got, want):
         assert g.branch_nodes == w.branch_nodes
 
 
-def test_pipelined_subtrie_start_depth_parity(turbo_np):
+def test_pipelined_subtrie_start_depth_parity(turbo_np, rebuild_layout):
     """The chunked Merkle rebuild's exact call shape: prefix subtries at
     start_depth=2, branch paths subtrie-relative."""
     jobs = _prefix_jobs(600, seed=7)
     want = [turbo_np.commit_hashed_many([j], collect_branches=True,
                                         start_depth=2)[0] for j in jobs]
+    rebuild_layout(JOBS_PER_SWEEP=8)
     got = turbo_np.commit_hashed_pipelined(jobs, collect_branches=True,
-                                           start_depth=2, jobs_per_sweep=8)
+                                           start_depth=2)
     assert [r.root for r in got] == [r.root for r in want]
     for g, w in zip(got, want):
         assert g.branch_nodes == w.branch_nodes
@@ -97,7 +106,7 @@ def test_pipelined_empty_and_single(turbo_np):
     from reth_tpu.primitives.types import EMPTY_ROOT_HASH
 
     assert turbo_np.commit_hashed_pipelined([]) == []
-    # <=1 job short-circuits to the serial path
+    # one job: one group, swept by the caller
     one = turbo_np.commit_hashed_pipelined([_job(40, seed=3)])
     assert one[0].root == turbo_np.commit_hashed_many([_job(40, seed=3)])[0].root
     mixed = turbo_np.commit_hashed_pipelined(
@@ -105,24 +114,17 @@ def test_pipelined_empty_and_single(turbo_np):
     assert mixed[0].root == EMPTY_ROOT_HASH
 
 
-def test_pipeline_env_kill_switch(turbo_np, monkeypatch):
-    """RETH_TPU_PIPELINE=0 forces the serial path — the A/B switch bench.py
-    uses; both must agree regardless."""
-    monkeypatch.setenv("RETH_TPU_PIPELINE", "0")
-    jobs = [_job(25, seed=i) for i in range(6)]
-    got = turbo_np.commit_hashed_pipelined(jobs)
-    want = turbo_np.commit_hashed_many(jobs)
-    assert [r.root for r in got] == [r.root for r in want]
-
-
-def test_pipelined_rejects_like_serial(turbo_np):
+def test_pipelined_rejects_like_serial(turbo_np, rebuild_layout):
     """Oversized leaf values reject in the sweep — the same ValueError the
-    MerkleStage catches to fall back to the general committer."""
+    MerkleStage catches to fall back to the general committer — whether
+    the caller swept the group or a pool thread did."""
     keys, values = _job(8, seed=2)
     values[3] = b"\xb9\xff\xff" + bytes(65535)  # > native leaf cap
     with pytest.raises(ValueError, match="oversized"):
-        turbo_np.commit_hashed_pipelined(
-            [(keys, values), _job(10, seed=4)], jobs_per_sweep=1)
+        turbo_np.commit_hashed_pipelined([(keys, values)])
+    rebuild_layout(JOBS_PER_SWEEP=1)
+    with pytest.raises(ValueError, match="oversized"):
+        turbo_np.commit_hashed_pipelined([(keys, values), _job(10, seed=4)])
 
 
 # -- grouping / packing ------------------------------------------------------
@@ -138,11 +140,12 @@ def test_group_jobs_bounds():
     assert _group_jobs([], 100, 4) == []
 
 
-def test_pipeline_metrics_recorded(turbo_np):
+def test_pipeline_metrics_recorded(turbo_np, rebuild_layout):
     from reth_tpu.metrics import pipeline_metrics
 
     jobs = [_job(30, seed=40 + i) for i in range(8)]
-    turbo_np.commit_hashed_pipelined(jobs, jobs_per_sweep=2)
+    rebuild_layout(JOBS_PER_SWEEP=2)
+    turbo_np.commit_hashed_pipelined(jobs)
     last = pipeline_metrics.last
     assert last is not None
     assert last["jobs"] == 8 and last["groups"] == 4
@@ -201,23 +204,25 @@ def test_arena_rows_thread_local():
 # -- fault drills ------------------------------------------------------------
 
 
-def test_injected_pipeline_abort(turbo_np, monkeypatch):
+def test_injected_pipeline_abort(turbo_np, monkeypatch, rebuild_layout):
     """RETH_TPU_FAULT_PIPELINE_ABORT kills the commit at a window boundary
     — the in-process crash-mid-queue drill the resume test builds on."""
     from reth_tpu.ops.supervisor import InjectedPipelineAbort
 
-    monkeypatch.setenv("RETH_TPU_FAULT_PIPELINE_ABORT", "2")
     jobs = [_job(20, seed=60 + i) for i in range(8)]
+    want = turbo_np.commit_hashed_many(jobs)    # one group, one window
+    monkeypatch.setenv("RETH_TPU_FAULT_PIPELINE_ABORT", "2")
+    rebuild_layout(JOBS_PER_SWEEP=1, PACK_WINDOW=1)
     with pytest.raises(InjectedPipelineAbort, match="window #2"):
-        turbo_np.commit_hashed_pipelined(jobs, jobs_per_sweep=1, pack_window=1)
+        turbo_np.commit_hashed_pipelined(jobs)
     # the wounded committer must still complete the next (clean) commit
     monkeypatch.delenv("RETH_TPU_FAULT_PIPELINE_ABORT")
-    got = turbo_np.commit_hashed_pipelined(jobs, jobs_per_sweep=1)
-    want = turbo_np.commit_hashed_many(jobs)
+    rebuild_layout(PACK_WINDOW=16)
+    got = turbo_np.commit_hashed_pipelined(jobs)
     assert [r.root for r in got] == [r.root for r in want]
 
 
-def test_mid_pipeline_failover_drains_onto_cpu():
+def test_mid_pipeline_failover_drains_onto_cpu(rebuild_layout):
     """Wedge every device dispatch under the supervised ('auto') route: the
     pipeline keeps feeding the failed-over backend, the queue drains onto
     the numpy twin, and the roots still match the oracle."""
@@ -233,7 +238,8 @@ def test_mid_pipeline_failover_drains_onto_cpu():
     auto = TurboCommitter(backend="auto", min_tier=64, supervisor=sup)
     jobs = [_job(40, seed=80 + i) for i in range(10)]
     want = TurboCommitter(backend="numpy").commit_hashed_many(jobs)
-    got = auto.commit_hashed_pipelined(jobs, jobs_per_sweep=2)
+    rebuild_layout(JOBS_PER_SWEEP=2)
+    got = auto.commit_hashed_pipelined(jobs)
     assert [r.root for r in got] == [r.root for r in want]
     assert sup.failovers >= 1
     last = pipeline_metrics.last
@@ -281,13 +287,13 @@ def test_triebuild_threaded_stress(tmp_path):
     assert "STRESS_OK" in r.stdout
 
 
-def test_pipeline_concurrent_sweeps_deterministic(turbo_np):
+def test_pipeline_concurrent_sweeps_deterministic(turbo_np, rebuild_layout):
     """Python-level rerun determinism: many small groups racing through the
     pool must always produce the same roots."""
     jobs = [_job(15, seed=200 + i) for i in range(16)]
+    rebuild_layout(JOBS_PER_SWEEP=1, PACK_WINDOW=2)
     runs = [
-        [r.root for r in turbo_np.commit_hashed_pipelined(
-            jobs, jobs_per_sweep=1, pack_window=2)]
+        [r.root for r in turbo_np.commit_hashed_pipelined(jobs)]
         for _ in range(3)
     ]
     assert runs[0] == runs[1] == runs[2]
@@ -300,9 +306,9 @@ def test_pipeline_concurrent_sweeps_deterministic(turbo_np):
 # number of windows are the same whatever order the sweep threads finish in
 # and whatever chunk came before.
 
-# one sweep group a job (as 125,000-leaf subtries are at the default
-# leaves_per_sweep), every sweep in flight at once
-_STEADY_KNOBS = dict(leaves_per_sweep=200, sweep_workers=4)
+# one sweep group a job (as 125,000-leaf subtries are at the program's
+# LEAVES_PER_SWEEP), every sweep in flight at once
+_STEADY_LAYOUT = dict(LEAVES_PER_SWEEP=200, SWEEP_THREADS=4)
 _CHUNK_A = (700, 260, 420, 330)
 _CHUNK_B = (1500, 240, 900)
 _ORDERS = list(itertools.permutations(range(4)))
@@ -319,11 +325,22 @@ def _chunk(sizes, seed, prefix0=0x30):
     return jobs
 
 
+def _assert_equals_the_plain_reference(jobs, results, start_depth):
+    from benchmark.reference.mpt import build_trie
+
+    for (keys, values), got in zip(jobs, results):
+        order = np.argsort(keys.view("S32").ravel())
+        ref = build_trie(keys[order], [values[i] for i in order], start_depth)
+        assert got.root == ref.root
+        plain = {bytes(p): (b.state_mask, b.tree_mask, b.hash_mask,
+                            tuple(b.hashes))
+                 for p, b in got.branch_nodes.items()}
+        assert plain == ref.branches
+
+
 def _plant_completion_order(monkeypatch, order, gap=0.01):
     """Make the sweep groups FINISH in ``order`` (a permutation of group
     numbers, one job a group): each waits for its predecessor's return."""
-    from reth_tpu.trie import turbo
-
     real = turbo._sweep_group
     done = [threading.Event() for _ in order]
     rank = {g: r for r, g in enumerate(order)}
@@ -341,7 +358,8 @@ def _plant_completion_order(monkeypatch, order, gap=0.01):
 
 
 def _commit_signature(monkeypatch, committer, jobs, order=None):
-    """What one pipelined commit asked of the device and of the arena."""
+    """What one commit, laid out as ``_STEADY_LAYOUT`` says, asked of the
+    device and of the arena."""
     from reth_tpu.metrics import compile_tracker, pipeline_metrics
 
     keys = set()
@@ -353,10 +371,12 @@ def _commit_signature(monkeypatch, committer, jobs, order=None):
 
     with monkeypatch.context() as mp:
         mp.setattr(compile_tracker, "record", spy)
+        for name, value in _STEADY_LAYOUT.items():
+            mp.setattr(turbo, name, value)
         if order is not None:
             _plant_completion_order(mp, order)
         results = committer.commit_hashed_pipelined(
-            jobs, collect_branches=True, start_depth=2, **_STEADY_KNOBS)
+            jobs, collect_branches=True, start_depth=2)
     mega = [k for k in keys if k[0].startswith("mega.")]
     sig = {
         "keys": frozenset(keys),
@@ -376,7 +396,7 @@ def _steady_committer(backend):
 @pytest.fixture(scope="module")
 def steady_baseline():
     """Chunk A committed once by each backend, its sweeps left to finish as
-    they will, and the serial path's answers."""
+    they will, and the answers of the same chunk as ONE group."""
     jobs = _chunk(_CHUNK_A, seed=900)
     mp = pytest.MonkeyPatch()
     try:
@@ -397,7 +417,7 @@ def test_shapes_do_not_follow_sweep_completion_order(
     want, _ = baseline[backend]
     got, results = _commit_signature(
         monkeypatch, _steady_committer(backend), jobs, order)
-    assert got["windows"] == want["windows"] == 1   # 4 groups, pack_window 16
+    assert got["windows"] == want["windows"] == 1   # 4 groups, PACK_WINDOW 16
     assert got["s_tier"] == want["s_tier"] and len(got["s_tier"]) == 1
     assert got["buffer_lens"] == want["buffer_lens"]
     assert got["keys"] == want["keys"]
@@ -442,29 +462,25 @@ def _mixed_sizes(n_jobs):
     ("numpy", 2), ("numpy", 5), ("numpy", 17), ("numpy", 64), ("device", 5)])
 @pytest.mark.parametrize("start_depth", [0, 2])
 def test_pipelined_equals_serial_and_the_plain_reference(
-        backend, n_jobs, start_depth):
-    from benchmark.reference.mpt import build_trie
-
+        rebuild_layout, backend, n_jobs, start_depth):
+    """"Serial" is the chunk as ONE group in one window (what the serial
+    path was); "pipelined" the same chunk as several groups and windows."""
     jobs = _chunk(_mixed_sizes(n_jobs), seed=1000 + n_jobs, prefix0=0x10)
     committer = _steady_committer(backend)
+    rebuild_layout(LEAVES_PER_SWEEP=10**9, JOBS_PER_SWEEP=10**9)
     serial = committer.commit_hashed_many(jobs, collect_branches=True,
                                           start_depth=start_depth)
     # several sweep groups and several windows, so slots are rebased
+    rebuild_layout(LEAVES_PER_SWEEP=1000, JOBS_PER_SWEEP=3, PACK_WINDOW=2)
     piped = committer.commit_hashed_pipelined(
-        jobs, collect_branches=True, start_depth=start_depth,
-        leaves_per_sweep=1000, jobs_per_sweep=3, pack_window=2)
-    for (keys, values), got, want in zip(jobs, piped, serial):
-        order = np.argsort(keys.view("S32").ravel())
-        ref = build_trie(keys[order], [values[i] for i in order], start_depth)
-        assert got.root == want.root == ref.root
+        jobs, collect_branches=True, start_depth=start_depth)
+    for got, want in zip(piped, serial):
+        assert got.root == want.root
         assert got.branch_nodes == want.branch_nodes
-        plain = {bytes(p): (b.state_mask, b.tree_mask, b.hash_mask,
-                            tuple(b.hashes))
-                 for p, b in got.branch_nodes.items()}
-        assert plain == ref.branches
+    _assert_equals_the_plain_reference(jobs, piped, start_depth)
 
 
-def test_pack_phase_and_arena_grows_move():
+def test_pack_phase_and_arena_grows_move(rebuild_layout):
     from reth_tpu import tracing
     from reth_tpu.metrics import REGISTRY
 
@@ -475,10 +491,10 @@ def test_pack_phase_and_arena_grows_move():
     try:
         rec = tracing.flight_recorder()
         n0 = rec.recorded
+        rebuild_layout(**_STEADY_LAYOUT)
         t0 = time.perf_counter()
         _steady_committer("device").commit_hashed_pipelined(
-            _chunk(_CHUNK_A, seed=930), collect_branches=True, start_depth=2,
-            **_STEADY_KNOBS)
+            _chunk(_CHUNK_A, seed=930), collect_branches=True, start_depth=2)
         wall = time.perf_counter() - t0
         spans = [s for s in rec.snapshot()[-(rec.recorded - n0):]
                  if (s["target"], s["name"]) == ("trie::commit", "pack")]
@@ -498,13 +514,12 @@ def test_pack_phase_and_arena_grows_move():
     assert "fused_arena_grows_total" in rendered
 
 
-@pytest.mark.parametrize("hash_workers", [1, 3])
 def test_the_arena_rises_a_tier_at_a_time_however_many_windows(
-        turbo_np, hash_workers):
+        turbo_np, rebuild_layout):
     """48 windows ask the backend for room O(log) times, each time for a
-    whole power-of-two tier: the hash pool is drained for the arena only
-    then, so its workers keep several windows hashing in between."""
+    whole power-of-two tier."""
     jobs = [_job(40 + 3 * i, seed=400 + i) for i in range(48)]
+    want = turbo_np.commit_hashed_many(jobs, collect_branches=True)
     asks = []
 
     class Spy(_NumpyBackend):
@@ -512,15 +527,195 @@ def test_the_arena_rises_a_tier_at_a_time_however_many_windows(
             asks.append(max_slots)
             super().ensure(max_slots)
 
-    pipe = RebuildPipeline(Spy(), hash_workers=hash_workers,
-                           jobs_per_sweep=1, pack_window=1)
+    rebuild_layout(JOBS_PER_SWEEP=1, PACK_WINDOW=1)
+    pipe = RebuildPipeline(Spy())
     got = pipe.run(jobs, collect_branches=True)
     assert pipe.windows == 48
     slots = got[-1].hashed_nodes            # the commit's slot high-water mark
     assert asks == sorted(set(asks)) and 1 < len(asks) <= slots.bit_length()
     assert all((a + 1) & a == 0 for a in asks)      # capacity a + 1: 2**k
     assert asks[-1] == (1 << slots.bit_length()) - 1
-    want = turbo_np.commit_hashed_many(jobs, collect_branches=True)
+    assert [r.root for r in got] == [r.root for r in want]
+    for g, w in zip(got, want):
+        assert g.branch_nodes == w.branch_nodes
+
+
+# -- a chunk of one job: the pipeline's one-group case ------------------------
+#
+# ``rebuild.accounts`` commits one 1,171,875-leaf subtrie a chunk and the live
+# tip one small trie a commit: one sweep group, swept by the caller, one
+# window, slot base 0, the arena tier ``begin(max_slot)`` would have given.
+
+# what ``MegaFusedEngine._execute`` was about to run for ``_one_job_chunk()``
+# under ``small_tiers`` at commit c1fa191 (the parent of the PR that took the
+# serial path out), through its ``commit_hashed_many`` → ``_run_inner``:
+# entry = (kind, [b_tier,] row tier, hole tier, offsets..., rows + 1,
+# holes + 1), deepest level first, a level over the row cap split in order.
+# ``start_depth=2`` stops short of the last entry (the extension over the
+# shared first byte).
+_GOLDEN_S_TIER = 4096
+_GOLDEN_LENS = (327680, 12288)
+_GOLDEN_PLAN = [
+    ("packed", 1, 64, 64, 0, 646, 0, 7, 8, 7, 1),
+    ("packed", 1, 128, 64, 660, 9923, 9, 98, 99, 89, 1),
+    ("branch", 64, 64, 10101, 100, 104, 111, 4, 7),
+    ("packed", 1, 1024, 64, 10109, 119169, 118, 1142, 1144, 1024, 2),
+    ("packed", 1, 64, 64, 121217, 124218, 1146, 1175, 1176, 29, 1),
+    ("branch", 64, 128, 124276, 1177, 1222, 1312, 45, 90),
+    ("packed", 1, 1024, 64, 124366, 231865, 1402, 2426, 2448, 1024, 22),
+    ("packed", 1, 512, 64, 233913, 271807, 2470, 2831, 2838, 361, 7),
+    ("branch", 512, 2048, 272529, 2845, 3335, 4405, 490, 1070),
+    ("branch", 512, 2048, 273509, 5475, 5732, 7605, 257, 1873),
+    ("branch", 64, 512, 274023, 9478, 9495, 9752, 17, 257),
+    ("branch", 64, 64, 274057, 10009, 10011, 10028, 2, 17),
+    ("packed", 1, 64, 64, 274061, 274098, 10045, 10047, 10049, 2, 2),
+]
+
+
+def _one_job_chunk():
+    return _chunk((2500,), seed=3100, prefix0=0x5A)
+
+
+@pytest.fixture
+def small_tiers(monkeypatch):
+    """Row tiers from 64 and a row cap of 1024, so that a 2,500-leaf trie
+    asks for several tiers and splits a level."""
+    monkeypatch.setattr(fc.MegaFusedEngine, "_ROW_FLOOR", 64)
+    monkeypatch.setattr(fc.MegaFusedEngine, "_HOLE_FLOOR", 64)
+    monkeypatch.setattr(fc.FusedLevelEngine, "_row_cap", lambda self: 1024)
+
+
+@pytest.fixture
+def pipeline_spy(monkeypatch, seen_plans):
+    """What a commit did: ``ensure`` asks, plans about to execute, the
+    names of the threads started, the widths of the sweep pools made."""
+    seen = {"ensure": [], "plans": seen_plans, "threads": [], "pools": []}
+
+    def pool(*args, max_workers=None, **kwargs):
+        seen["pools"].append(max_workers)
+        return ThreadPoolExecutor(*args, max_workers=max_workers, **kwargs)
+
+    monkeypatch.setattr(turbo, "ThreadPoolExecutor", pool)
+    for cls in (_NumpyBackend, fc.MegaFusedEngine):
+        def ensure(self, max_slots, _real=cls.ensure):
+            seen["ensure"].append(max_slots)
+            return _real(self, max_slots)
+        monkeypatch.setattr(cls, "ensure", ensure)
+    start = threading.Thread.start
+
+    def spy_start(self):
+        seen["threads"].append(self.name)
+        return start(self)
+
+    monkeypatch.setattr(threading.Thread, "start", spy_start)
+    return seen
+
+
+@pytest.mark.parametrize("start_depth", [0, 2])
+@pytest.mark.parametrize("backend", ["numpy", "device"])
+def test_a_chunk_of_one_job_is_one_group_one_window(
+        small_tiers, pipeline_spy, backend, start_depth):
+    from reth_tpu.metrics import pipeline_metrics
+
+    jobs = _one_job_chunk()
+    committer = _steady_committer(backend)
+    results = committer.commit_hashed_pipelined(
+        jobs, collect_branches=True, start_depth=start_depth)
+    _assert_equals_the_plain_reference(jobs, results, start_depth)
+    last = pipeline_metrics.last
+    assert (last["jobs"], last["groups"], last["windows"]) == (1, 1, 1)
+    max_slot = results[-1].hashed_nodes     # one group: its slots are 1..n
+    s_tier = fc._pow2(max_slot + 1)         # what begin(max_slot) gave
+    assert pipeline_spy["ensure"] == [s_tier - 1]
+    if backend == "numpy":
+        assert committer.arena.digest_buf(1).shape[0] == s_tier
+        return
+    (p,) = pipeline_spy["plans"]
+    assert p["s_tier"] == s_tier == _GOLDEN_S_TIER
+    assert p["lens"] == _GOLDEN_LENS
+    assert p["plan"] == (_GOLDEN_PLAN if start_depth == 0
+                         else _GOLDEN_PLAN[:-1])
+
+
+def test_a_window_of_one_sweep_copies_nothing():
+    jobs = _chunk((900, 40), seed=3200)
+    sw = _sweep_group(turbo.load_library(), jobs, range(2), True, 2)
+    own = [(lv.depth, lv.flat, lv.row_off, lv.row_len, lv.row_slot, lv.holes,
+            lv.masks, lv.bmp_slot, lv.children) for lv in sw.levels]
+    merged = _pack_window([(0, sw)])
+    assert [m.depth for m in merged] == [lv[0] for lv in own]
+    assert [m.depth for m in merged] == sorted(
+        (m.depth for m in merged), reverse=True)
+    shared = 0
+    for m, (_, flat, row_off, row_len, row_slot, holes, masks, bmp_slot,
+            children) in zip(merged, own):
+        if len(row_slot):
+            pairs = [(m.flat, flat), (m.row_off, row_off),
+                     (m.row_len, row_len), (m.row_slot, row_slot)]
+            if holes is not None:
+                pairs.append((m.holes, holes))
+            else:
+                assert m.holes is None
+        else:
+            pairs = []
+            assert len(m.row_slot) == 0
+        if len(bmp_slot):
+            pairs += [(m.masks, masks), (m.bmp_slot, bmp_slot),
+                      (m.children, children)]
+        else:
+            assert len(m.bmp_slot) == 0
+        for mine, theirs in pairs:
+            assert mine is theirs or np.shares_memory(mine, theirs)
+            shared += 1
+    assert shared >= 4 * len(merged)
+
+
+def test_a_one_group_chunk_makes_no_thread(pipeline_spy, turbo_np):
+    one = _chunk((300, 200), seed=3300)          # two jobs, one group
+    assert _group_jobs(one, turbo.LEAVES_PER_SWEEP,
+                       turbo.JOBS_PER_SWEEP) == [(0, 2)]
+    turbo_np.commit_hashed_pipelined(one, collect_branches=True,
+                                     start_depth=2)
+    turbo_np.commit_hashed_many(one[:1])
+    assert pipeline_spy["threads"] == []
+    two = _chunk((40000, 300), seed=3310)        # the first fills a group
+    assert len(_group_jobs(two, turbo.LEAVES_PER_SWEEP,
+                           turbo.JOBS_PER_SWEEP)) == 2
+    turbo_np.commit_hashed_pipelined(two, start_depth=2)
+    assert pipeline_spy["threads"]
+    assert all(n.startswith("trie-sweep") for n in pipeline_spy["threads"])
+    assert pipeline_spy["pools"] == [turbo.SWEEP_THREADS]
+
+
+@pytest.mark.parametrize("name,hostile", [
+    ("RETH_TPU_PIPELINE", "0"),
+    ("RETH_TPU_PIPELINE_SWEEPERS", "1"),
+    ("RETH_TPU_PIPELINE_HASHERS", "999"),
+    ("RETH_TPU_PIPELINE_WINDOW", "1"),
+    ("RETH_TPU_PIPELINE_SWEEP_LEAVES", "1"),
+])
+def test_deleted_names_are_not_read(monkeypatch, pipeline_spy, name, hostile):
+    """The five environment names of the rebuild are gone: a value that
+    once forced the serial path, one sweep thread, a hash pool, a window a
+    group or a group a job changes neither the layout nor the answers."""
+    from reth_tpu.metrics import pipeline_metrics
+
+    jobs = _chunk((40000, 300, 200, 100), seed=3400)
+
+    def commit():
+        del pipeline_spy["threads"][:], pipeline_spy["pools"][:]
+        pipeline_metrics.last = None
+        got = TurboCommitter(backend="numpy").commit_hashed_pipelined(
+            jobs, collect_branches=True, start_depth=2)
+        last = pipeline_metrics.last
+        assert all(t.startswith("trie-sweep") for t in pipeline_spy["threads"])
+        return got, (last["groups"], last["windows"], pipeline_spy["pools"][:])
+
+    want, layout = commit()
+    assert layout == (2, 1, [turbo.SWEEP_THREADS])
+    monkeypatch.setenv(name, hostile)
+    got, layout_hostile = commit()
+    assert layout_hostile == layout
     assert [r.root for r in got] == [r.root for r in want]
     for g, w in zip(got, want):
         assert g.branch_nodes == w.branch_nodes
