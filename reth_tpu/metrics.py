@@ -551,6 +551,13 @@ class FusedCommitMetrics:
         self._gather_rows = reg.counter(
             "fused_gather_rows_total",
             "row tier of every packed dispatch whose rows were read")
+        self._branch_index_elems = reg.counter(
+            "fused_branch_index_elems_total",
+            "elements of every index array the branch dispatches' gathers "
+            "and scatters take")
+        self._branch_rows = reg.counter(
+            "fused_branch_rows_total",
+            "row tier of every branch dispatch")
         self.last: dict | None = None  # most recent commit, for events/bench
         self.dispatches_cum = 0  # lifetime count (bench deltas)
 
@@ -579,6 +586,10 @@ class FusedCommitMetrics:
     def record_gather(self, operand_bytes: int, rows: int) -> None:
         self._gather_operand_bytes.increment(operand_bytes)
         self._gather_rows.increment(rows)
+
+    def record_branch_index(self, index_elems: int, rows: int) -> None:
+        self._branch_index_elems.increment(index_elems)
+        self._branch_rows.increment(rows)
 
     def record_commit(self, *, dispatches: int, levels: int, k: int,
                       mode: str) -> None:

@@ -141,45 +141,69 @@ def _branch_level(masks, slots, ch_row, ch_nib, ch_src, digest_buf, *, b_tier: i
     determined byte layout: list header (f8 <len> for <=7 children, f9
     <len:2> above), then per nibble (a0 + 32-byte ref) or 80, then 80
     (empty value). Only the mask and the child (row, nibble, digest-slot)
-    triples cross the wire — ~250x less H2D than the 532-byte template."""
-    L = b_tier * RATE
+    triples cross the wire — ~250x less H2D than the 532-byte template.
+
+    No index addresses a single byte of the rows (on the TPU a per-byte
+    scatter costs ~5 ns an index and drags in a sort of all of them;
+    PERF.md, PR 32): the triples become a dense ``(n, 16)`` table of digest
+    slots by one scatter of WORDS (junk triples land on a padding row,
+    whose mask is 0; entries of absent nibbles are never read), the digests
+    reach the rows as 32-byte rows of one gather, and `_branch_rows` lays
+    them out by static slices and selects."""
     n = masks.shape[0]
-    nibs = jnp.arange(16, dtype=jnp.int32)[None, :]
-    present = ((masks[:, None].astype(jnp.int32) >> nibs) & 1).astype(jnp.int32)  # (n,16)
-    sizes = 1 + 32 * present
-    csum = jnp.cumsum(sizes, axis=1) - sizes          # exclusive prefix
-    payload = jnp.sum(sizes, axis=1) + 1              # + empty value byte
-    hl = jnp.where(payload > 0xFF, 3, 2)              # header length
-    total = hl + payload
-    col = jnp.arange(L, dtype=jnp.int32)[None, :]
-    rows = jnp.zeros((n, L), dtype=jnp.uint8)
-    rows = rows.at[:, 0].set(jnp.where(hl == 3, 0xF9, 0xF8).astype(jnp.uint8))
-    rows = rows.at[:, 1].set(
-        jnp.where(hl == 3, payload >> 8, payload & 0xFF).astype(jnp.uint8)
-    )
-    # byte 2 = low len byte for f9 rows; f8 rows overwrite it with their
-    # first child marker below (csum[:, 0] == 0 puts it exactly at hl == 2)
-    rows = rows.at[:, 2].set((payload & 0xFF).astype(jnp.uint8))
-    # child markers: 0xa0 when present else 0x80, at hl + csum
-    marker = jnp.where(present == 1, 0xA0, 0x80).astype(jnp.uint8)
-    flat = rows.reshape(-1)
-    midx = (jnp.arange(n, dtype=jnp.int32)[:, None] * L + hl[:, None] + csum).reshape(-1)
-    flat = flat.at[midx].set(marker.reshape(-1))
-    # empty branch value right after the children
-    vidx = jnp.arange(n, dtype=jnp.int32) * L + (total - 1)
-    flat = flat.at[vidx].set(jnp.uint8(0x80))
-    # splice child digests at marker+1
-    dig = digest_buf[ch_src]
-    off = hl[ch_row] + csum[ch_row, ch_nib] + 1
-    sidx = (ch_row * L + off)[:, None] + jnp.arange(32, dtype=jnp.int32)[None, :]
-    flat = flat.at[sidx.reshape(-1)].set(dig.reshape(-1))
-    rows = flat.reshape(n, L)
+    table = jnp.zeros((n * 16,), jnp.int32).at[ch_row * 16 + ch_nib].set(
+        ch_src).reshape(n, 16)
+    rows, total = _branch_rows(masks, digest_buf[table], b_tier * RATE)
     # keccak padding from the computed total length
+    col = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :]
     counts = total // RATE + 1
     rows = rows ^ jnp.where(col == total[:, None], 0x01, 0).astype(jnp.uint8)
     rows = rows ^ jnp.where(col == (counts * RATE - 1)[:, None], 0x80, 0).astype(jnp.uint8)
-    d = masked_absorb_words(_bytes_to_words(rows), b_tier, counts.astype(jnp.int32))
+    d = masked_absorb_words(_bytes_to_words(rows), b_tier, counts)
     return digest_buf.at[slots].set(_digests_to_bytes(d))
+
+
+def _branch_rows(masks, dense, L: int):
+    """(n, L) u8 branch-node RLPs, zero past each row's length, and the
+    (n,) i32 lengths, from the (n,) i32 state masks and the (n, 16, 32) u8
+    digests of the sixteen child slots (read only where the mask's bit is
+    set).
+
+    Built from the tail, as `_level_rows`' barrel shifter: ``rest`` starts
+    as the empty value's 0x80 and each nibble, 15 down to 0, prepends either
+    0xa0 + its digest or 0x80 by one select between two static
+    concatenations, the width growing by the 33 bytes a later stage can
+    still need; one more select puts the list header (f8 <len>, or f9
+    <len:2> above 255) in front."""
+    n = masks.shape[0]
+    byte = lambda v: jnp.full((n, 1), v, jnp.uint8)  # noqa: E731
+    gap = jnp.zeros((n, 32), jnp.uint8)
+    rest = byte(0x80)
+    for k in reversed(range(16)):
+        rest = jnp.where(
+            ((masks >> k) & 1)[:, None] == 1,
+            jnp.concatenate([byte(0xA0), dense[:, k], rest], axis=1),
+            jnp.concatenate([byte(0x80), rest, gap], axis=1))
+    payload = 17 + 32 * jax.lax.population_count(masks)
+    long = payload > 0xFF
+    low = (payload & 0xFF).astype(jnp.uint8)[:, None]
+    rows = jnp.where(
+        long[:, None],
+        jnp.concatenate(
+            [byte(0xF9), (payload >> 8).astype(jnp.uint8)[:, None], low, rest],
+            axis=1),
+        jnp.concatenate([byte(0xF8), low, rest, byte(0)], axis=1))
+    # a subtrie chunk of packed steps alone still traces its branch step, at
+    # the chunk's narrower L; no branch row runs there
+    rows = jnp.pad(rows, ((0, 0), (0, max(L - rows.shape[1], 0))))[:, :L]
+    return rows, payload + jnp.where(long, 3, 2)
+
+
+def _branch_index_elems(n_pow: int, ch_pow: int) -> int:
+    """Elements of every index array a branch level program's gathers and
+    scatters take: the table's word scatter (one a triple), the digest-row
+    gather (sixteen a row) and the digest write (one a row)."""
+    return ch_pow + 16 * n_pow + n_pow
 
 
 @lru_cache(maxsize=None)
@@ -943,6 +967,7 @@ class MegaFusedEngine(FusedLevelEngine):
         s32 = np.int32
         rows_dispatched = rows_needed = 0
         gather_bytes = gather_rows = 0
+        branch_index_elems = branch_rows = 0
         with trie_metrics.phase("enqueue"):
             for e in self._plan:
                 if e[0] == "packed":
@@ -961,6 +986,8 @@ class MegaFusedEngine(FusedLevelEngine):
                 else:
                     (_, n_pow, ch_pow, mask_o, slot_o, chidx_o, chsrc_o,
                      n_valid, c_valid) = e
+                    branch_index_elems += _branch_index_elems(n_pow, ch_pow)
+                    branch_rows += n_pow
                     fn = _staged_branch(n_pow, ch_pow, u8_len, i32_len,
                                         s_tier)
                     buf = _timed_call(
@@ -974,6 +1001,7 @@ class MegaFusedEngine(FusedLevelEngine):
                 rows_needed += n_valid - 1  # all but the padding row
         fused_metrics.record_rows(rows_dispatched, rows_needed)
         fused_metrics.record_gather(gather_bytes, gather_rows)
+        fused_metrics.record_branch_index(branch_index_elems, branch_rows)
         self._buf = buf
         self._plan, self._u8_parts, self._i32_parts = [], [], []
 
