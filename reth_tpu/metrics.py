@@ -319,9 +319,12 @@ class TrieMetrics:
     # levels merged across subtries); there marshal, sweep and the levels'
     # extraction run on the sweep pool and their counters hold
     # thread-seconds. A backend that hashes as it is fed (the numpy twin,
-    # the per-level engines) does so inside "stage".
+    # the per-level engines) does so inside "stage". "collect" is the loop
+    # that builds one result a job after the digests are back: nothing for
+    # a chunk of one subtrie, a phase of its own for a storage chunk of
+    # tens of thousands of tries.
     PHASES = ("marshal", "sweep", "pack", "stage", "assemble", "upload",
-              "enqueue", "device_wait", "fetch", "decode")
+              "enqueue", "device_wait", "fetch", "collect", "decode")
 
     def __init__(self, registry: MetricsRegistry | None = None):
         reg = registry or REGISTRY
@@ -395,6 +398,9 @@ class PipelineMetrics:
             "trie_pipeline_windows_total",
             "cross-subtrie packed dispatch windows")
         self._subtries = reg.counter("trie_pipeline_subtries_total")
+        self._groups = reg.counter(
+            "trie_pipeline_groups_total",
+            "sweep groups laid out: one native build and one decode call each")
         self._drains = reg.counter(
             "trie_pipeline_queue_drains_total",
             "windows hashed on the CPU twin after a mid-rebuild failover")
@@ -419,6 +425,7 @@ class PipelineMetrics:
         self._runs.increment()
         self._windows.increment(windows)
         self._subtries.increment(jobs)
+        self._groups.increment(groups)
         self._drains.increment(drained_windows)
         for k, v in (("sweep", sweep), ("wait", wait), ("pack", pack),
                      ("dispatch", dispatch), ("fetch", fetch)):

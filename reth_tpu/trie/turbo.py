@@ -425,18 +425,20 @@ def _sweep_group(lib, jobs, job_ids, collect_branches, start_depth) -> _SweepRes
     h, key_arrays = _marshal_and_build(lib, jobs, collect_branches, start_depth)
     try:
         n_levels = lib.rtb_num_levels(h)
+        # one "stage" a group: the levels and the roots out of the handle
+        # (the root loop is per job: 64 a group of a storage chunk)
         with trie_metrics.phase("stage"):
             levels = [_Level(lib, h, i) for i in range(n_levels)]
-        root_slots = np.zeros((len(jobs),), dtype=np.int32)
-        lib.rtb_roots(h, _ptr(root_slots, _i32p))
-        root_inlines: list[bytes | None] = [None] * len(jobs)
-        for j in range(len(jobs)):
-            if root_slots[j] <= 0:
-                ln = lib.rtb_root_inline_len(h, j)
-                buf = np.zeros((ln,), dtype=np.uint8)
-                if ln:
-                    lib.rtb_root_inline(h, j, _ptr(buf, _u8p))
-                root_inlines[j] = buf.tobytes()
+            root_slots = np.zeros((len(jobs),), dtype=np.int32)
+            lib.rtb_roots(h, _ptr(root_slots, _i32p))
+            root_inlines: list[bytes | None] = [None] * len(jobs)
+            for j in range(len(jobs)):
+                if root_slots[j] <= 0:
+                    ln = lib.rtb_root_inline_len(h, j)
+                    buf = np.zeros((ln,), dtype=np.uint8)
+                    if ln:
+                        lib.rtb_root_inline(h, j, _ptr(buf, _u8p))
+                    root_inlines[j] = buf.tobytes()
         meta_rec = None
         if collect_branches:
             nmeta = int(lib.rtb_meta_count(h))
@@ -772,18 +774,20 @@ class RebuildPipeline:
             roots_raw = backend.fetch_slots(flat_slots)
         cursor = 0
         total_hashed = 0
-        for base, sw in swept:
-            total_hashed += sw.hashed_nodes
-            for k, j in enumerate(sw.job_ids):
-                slot = int(sw.root_slots[k])
-                if slot > 0:
-                    root = (digests[base + slot] if digests is not None
-                            else roots_raw[cursor + k]).tobytes()
-                else:
-                    inline = sw.root_inlines[k]
-                    root = keccak256(inline) if inline else EMPTY_ROOT_HASH
-                results[j] = TrieBuildResult(root=root, levels=sw.n_levels)
-            cursor += len(sw.job_ids)
+        # one result a job: a storage chunk holds tens of thousands
+        with trie_metrics.phase("collect"):
+            for base, sw in swept:
+                total_hashed += sw.hashed_nodes
+                for k, j in enumerate(sw.job_ids):
+                    slot = int(sw.root_slots[k])
+                    if slot > 0:
+                        root = (digests[base + slot] if digests is not None
+                                else roots_raw[cursor + k]).tobytes()
+                    else:
+                        inline = sw.root_inlines[k]
+                        root = keccak256(inline) if inline else EMPTY_ROOT_HASH
+                    results[j] = TrieBuildResult(root=root, levels=sw.n_levels)
+                cursor += len(sw.job_ids)
         if results:
             results[-1].hashed_nodes = total_hashed
         if collect_branches:

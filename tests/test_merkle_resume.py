@@ -171,6 +171,61 @@ def test_stale_target_progress_restarts_rebuild():
         assert p.stage_progress(MerkleStage.id) is None
 
 
+def test_storage_chunk_hands_whole_tries_in_hashed_address_order():
+    """What ``_storage_chunk`` gives ``_commit_subtries``: WHOLE storage tries
+    taken in hashed-address order until the chunk holds ``chunk_leaves``
+    slots, each a job of its slots' keys ascending with RLP values of 1-33
+    bytes, committed at ``start_depth`` 0. The benchmark's storage cell
+    copies this shape (``benchmark/harness/traffic_storage.py``)."""
+    import numpy as np
+
+    from reth_tpu.primitives.rlp import encode_int, rlp_encode
+    from reth_tpu.primitives.types import EMPTY_ROOT_HASH
+
+    rng = np.random.default_rng(33)
+    sizes = [1, 3, 1, 6, 2, 1, 4]                 # in hashed-address order
+    addrs = sorted(bytes(rng.integers(0, 256, 32, dtype=np.uint8))
+                   for _ in sizes)
+    table = {}
+    factory = ProviderFactory(MemDb())
+    with factory.provider_rw() as p:
+        for addr, n in zip(addrs, sizes):
+            slots = sorted(bytes(rng.integers(0, 256, 32, dtype=np.uint8))
+                           for _ in range(n))
+            # values of every width: a flag, a short integer, a full word
+            values = [int(v) for v in rng.choice(
+                [1, 0x7F, 0x80, 0x1234, 2**160 - 3, 2**256 - 1], size=n)]
+            table[addr] = list(zip(slots, values))
+            for slot, value in reversed(table[addr]):   # written unordered
+                p.put_hashed_storage(addr, slot, value)
+    stage = MerkleStage(CPU, chunk_leaves=5)
+    calls = []
+
+    def spy(jobs, start_depth=0):
+        calls.append((jobs, start_depth))
+        return [type("R", (), {"root": EMPTY_ROOT_HASH, "branch_nodes": {}})()
+                for _ in jobs]
+
+    stage._commit_subtries = spy
+    tb = (7).to_bytes(8, "big")
+    with factory.provider_rw() as p:
+        p.save_stage_progress(stage.id, b"S" + tb)
+        for _ in range(4):
+            stage._storage_chunk(p, tb, p.stage_progress(stage.id)[9:])
+        assert p.stage_progress(stage.id) == b"A" + tb   # the phase is over
+    # 1 + 3 + 1 = 5 closes the first chunk; 6; 2 + 1 + 4 = 7 (a trie is
+    # never split: the chunk that reaches chunk_leaves keeps its last whole)
+    assert [[len(v) for _, v in jobs] for jobs, _ in calls] == [
+        [1, 3, 1], [6], [2, 1, 4]]
+    assert all(depth == 0 for _, depth in calls)
+    handed = [job for jobs, _ in calls for job in jobs]
+    for addr, (keys, values) in zip(addrs, handed):
+        assert keys.dtype == np.uint8 and keys.shape == (len(values), 32)
+        assert [bytes(k) for k in keys] == [s for s, _ in table[addr]]
+        assert values == [rlp_encode(encode_int(v)) for _, v in table[addr]]
+        assert all(1 <= len(v) <= 33 for v in values)
+
+
 def test_pipeline_abort_mid_queue_resumes_bit_identical(monkeypatch):
     """Kill the OVERLAPPED rebuild pipeline mid-queue (fault injection via
     RETH_TPU_FAULT_PIPELINE_ABORT): the aborted chunk's transaction rolls
