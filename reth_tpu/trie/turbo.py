@@ -5,12 +5,14 @@ Python, on ONE path (``TurboCommitter.commit_hashed_pipelined``;
 ``commit_hashed_many`` is its older name):
 
   jobs: 32-byte hashed keys + RLP values, one job a trie
-    └─ _group_jobs → sweep groups; PACK_WINDOW consecutive groups a window
-        └─ _sweep_group, one a group: native/triebuild.cpp (C++ sweep:
-           structure + RLP templates/masks, flat per-level arrays, in place
-           of trie/committer.py's per-node recursion). Several groups: on a
+    └─ _group_jobs → sweep groups, closed by their leaves alone;
+       PACK_WINDOW consecutive groups a window
+        └─ _sweep_group, one a group: the group marshalled in one piece,
+           then native/triebuild.cpp (C++ sweep: structure + RLP
+           templates/masks, flat per-level arrays, in place of
+           trie/committer.py's per-node recursion). Several groups: on a
            thread pool, side by side. ONE group (a chunk of one subtrie, a
-           live-tip trie): by the caller, no thread
+           job list under LEAVES_PER_SWEEP leaves): by the caller, no thread
             └─ _pack_window: same-depth levels of a window's groups merged
                (one group's pass through uncopied); per level, deepest first:
                PACKED rows  → backend.dispatch_packed   (device)
@@ -36,7 +38,7 @@ import threading
 import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
-from itertools import islice
+from itertools import chain, islice
 from pathlib import Path
 
 import numpy as np
@@ -342,27 +344,58 @@ class _NumpyBackend:
         staged chunk here); the CPU twin hashes eagerly, so no-op."""
 
 
+def _marshal_one(keys, values):
+    """One job's keys sorted and its values in that order."""
+    keys = np.ascontiguousarray(keys, dtype=np.uint8).reshape(-1, 32)
+    if len(keys) != len(values):
+        raise ValueError("keys/values length mismatch")
+    order = np.argsort(keys.view("S32").ravel(), kind="stable")
+    return keys[order], [values[i] for i in order]
+
+
+def _marshal_group(jobs):
+    """A group of several jobs, marshalled in one piece: ONE stable sort of
+    the group's rows by (job number, key) and one pass over the values, so
+    the cost follows the group's leaves and not its number of tries. The
+    job number leads the sort key, so a key two jobs share is no duplicate,
+    and an order that comes out the identity (the stage hands over sorted
+    keys) moves neither keys nor values. Returns the sorted keys, the
+    values in that order and the jobs' key counts."""
+    key_list = [np.ascontiguousarray(k, dtype=np.uint8).reshape(-1, 32)
+                for k, _ in jobs]
+    counts = np.fromiter(map(len, key_list), dtype=np.int64, count=len(jobs))
+    if counts.tolist() != [len(v) for _, v in jobs]:
+        raise ValueError("keys/values length mismatch")
+    keys = np.concatenate(key_list)
+    rows = np.empty((len(keys), 36), dtype=np.uint8)
+    rows[:, :4] = np.repeat(
+        np.arange(len(jobs), dtype=">u4"), counts).view(np.uint8).reshape(-1, 4)
+    rows[:, 4:] = keys
+    order = np.argsort(rows.view("S36").ravel(), kind="stable")
+    values = list(chain.from_iterable(v for _, v in jobs))
+    if (order[1:] < order[:-1]).any():
+        keys = keys[order]
+        values = list(map(values.__getitem__, order.tolist()))
+    return keys, values, counts
+
+
 def _marshal_and_build(lib, jobs, collect_branches: bool, start_depth: int):
     """Sort each job's keys, flatten values, and run the native structure
-    sweep. Returns (handle, per-job sorted key arrays); the caller owns the
-    handle (``rtb_free``). Raises ``ValueError`` on sweep rejection —
-    exactly the condition the MerkleStage uses to fall back to the general
-    committer."""
+    sweep: one job by its own sort (``_marshal_one``), several in one piece
+    (``_marshal_group``). Returns (handle, the group's sorted keys, job
+    after job in one array); the caller owns the handle (``rtb_free``).
+    Raises ``ValueError`` on sweep rejection — exactly the condition the
+    MerkleStage uses to fall back to the general committer."""
     from ..metrics import trie_metrics
 
     with trie_metrics.phase("marshal"):
-        key_arrays, val_chunks, job_off = [], [], [0]
-        for keys, values in jobs:
-            keys = np.ascontiguousarray(keys, dtype=np.uint8).reshape(-1, 32)
-            if len(keys) != len(values):
-                raise ValueError("keys/values length mismatch")
-            order = np.argsort(keys.view("S32").ravel(), kind="stable")
-            key_arrays.append(keys[order])
-            val_chunks.extend(values[i] for i in order)
-            job_off.append(job_off[-1] + len(keys))
-        all_keys = np.ascontiguousarray(
-            np.concatenate(key_arrays) if key_arrays
-            else np.zeros((0, 32), np.uint8))
+        if len(jobs) == 1:
+            all_keys, val_chunks = _marshal_one(*jobs[0])
+            counts = [len(all_keys)]
+        else:
+            all_keys, val_chunks, counts = _marshal_group(jobs)
+        job_off = np.zeros((len(jobs) + 1,), dtype=np.uint64)
+        job_off[1:] = np.cumsum(counts)
         flat_vals = b"".join(val_chunks)
         val_off = np.zeros((len(val_chunks) + 1,), dtype=np.uint64)
         if val_chunks:
@@ -371,12 +404,11 @@ def _marshal_and_build(lib, jobs, collect_branches: bool, start_depth: int):
                             count=len(val_chunks))
             )
         vals_np = np.frombuffer(flat_vals, dtype=np.uint8) if flat_vals else np.zeros(1, np.uint8)
-        job_off_np = np.asarray(job_off, dtype=np.uint64)
     err = ctypes.c_int32(0)
     with trie_metrics.phase("sweep"):
         h = lib.rtb_build(
             _ptr(all_keys, _u8p), len(all_keys),
-            _ptr(job_off_np, _u64p), len(jobs),
+            _ptr(job_off, _u64p), len(jobs),
             _ptr(vals_np, _u8p), _ptr(val_off, _u64p),
             1 if collect_branches else 0, start_depth, ctypes.byref(err),
         )
@@ -384,7 +416,7 @@ def _marshal_and_build(lib, jobs, collect_branches: bool, start_depth: int):
         reason = {1: "unsorted", 2: "duplicate keys", 3: "bad input",
                   4: "oversized leaf value"}.get(err.value, "unknown")
         raise ValueError(f"triebuild failed (err={err.value}: {reason})")
-    return h, key_arrays
+    return h, all_keys
 
 
 # -- the rebuild pipeline: every commit's path ------------------------------
@@ -396,14 +428,14 @@ class _SweepResult:
     group's own 1..max_slot namespace; the consumer rebases them into the
     shared arena."""
 
-    __slots__ = ("job_ids", "key_arrays", "levels", "root_slots",
+    __slots__ = ("job_ids", "keys", "levels", "root_slots",
                  "root_inlines", "meta_rec", "max_slot", "n_levels",
-                 "wire_bytes", "hashed_nodes", "leaves", "sweep_s")
+                 "wire_bytes", "hashed_nodes", "sweep_s")
 
-    def __init__(self, job_ids, key_arrays, levels, root_slots, root_inlines,
+    def __init__(self, job_ids, keys, levels, root_slots, root_inlines,
                  meta_rec, max_slot, wire_bytes, sweep_s):
         self.job_ids = job_ids
-        self.key_arrays = key_arrays
+        self.keys = keys  # the group's sorted keys, job after job
         self.levels = levels
         self.root_slots = root_slots
         self.root_inlines = root_inlines
@@ -412,7 +444,6 @@ class _SweepResult:
         self.n_levels = len(levels)
         self.wire_bytes = wire_bytes
         self.hashed_nodes = sum(len(lv.row_slot) + len(lv.masks) for lv in levels)
-        self.leaves = sum(len(k) for k in key_arrays)
         self.sweep_s = sweep_s
 
 
@@ -422,11 +453,12 @@ def _sweep_group(lib, jobs, job_ids, collect_branches, start_depth) -> _SweepRes
     from ..metrics import trie_metrics
 
     t0 = time.perf_counter()
-    h, key_arrays = _marshal_and_build(lib, jobs, collect_branches, start_depth)
+    h, keys = _marshal_and_build(lib, jobs, collect_branches, start_depth)
     try:
         n_levels = lib.rtb_num_levels(h)
         # one "stage" a group: the levels and the roots out of the handle
-        # (the root loop is per job: 64 a group of a storage chunk)
+        # (the root loop is per job: a thousand and more a group of a
+        # storage chunk)
         with trie_metrics.phase("stage"):
             levels = [_Level(lib, h, i) for i in range(n_levels)]
             root_slots = np.zeros((len(jobs),), dtype=np.int32)
@@ -450,7 +482,7 @@ def _sweep_group(lib, jobs, job_ids, collect_branches, start_depth) -> _SweepRes
         lib.rtb_free(h)
     wire_bytes = sum(lv.flat.nbytes + lv.row_off.nbytes + lv.row_len.nbytes
                      + lv.masks.nbytes + lv.children.nbytes for lv in levels)
-    return _SweepResult(job_ids, key_arrays, levels, root_slots, root_inlines,
+    return _SweepResult(job_ids, keys, levels, root_slots, root_inlines,
                         meta_rec, max_slot, wire_bytes,
                         time.perf_counter() - t0)
 
@@ -552,40 +584,46 @@ def _pack_window(parts: list[tuple[int, _SweepResult]]) -> list[_MergedLevel]:
 # are submitted ahead of the consumer (a result parked behind every running
 # sweep; no more host arrays alive than that).
 SWEEP_THREADS = max(2, min(4, os.cpu_count() or 1))
-# consecutive groups a window: same-depth rows of up to 16 * 64 small tries
-# share a dispatch, and hashing starts before a storage chunk of thousands
-# of tries is all swept
+# consecutive groups a window: same-depth rows of the window's tries share
+# a dispatch. A stage chunk of 500,000 leaves is at most 16 groups, so ONE
+# window: one `ensure`, one set of merged levels, one program a level
 PACK_WINDOW = 16
-# a group closes at the job that reaches either bound: a sweep short against
-# a chunk of 500,000 leaves, long against the cost of a native call
+# a group closes at the job that reaches the bound, and at nothing else: a
+# sweep short against a chunk of 500,000 leaves, long against the cost of a
+# native call and of the extraction of its levels. What a group costs on the
+# host follows its leaves (`_marshal_group`), never its number of tries, so
+# thousands of one-slot storage tries are one group like one trie of as
+# many slots
 LEAVES_PER_SWEEP = 32768
-JOBS_PER_SWEEP = 64
 
 
-def _group_jobs(jobs, max_leaves: int, max_jobs: int):
+def _group_jobs(jobs, max_leaves: int):
     """Slice the job list into sweep groups: each group is one native
-    build (shared levels within the group), bounded by leaves and job
-    count so sweeps stay small enough to overlap hashing."""
+    build (shared levels within the group), closed by the job that brings
+    it to ``max_leaves``, so sweeps stay small enough to run side by side."""
     groups = []
-    lo = 0
-    while lo < len(jobs):
-        hi, leaves = lo, 0
-        while hi < len(jobs) and (hi - lo) < max_jobs:
-            leaves += len(jobs[hi][1])
-            hi += 1
-            if leaves >= max_leaves:
-                break
-        groups.append((lo, hi))
-        lo = hi
+    lo = leaves = 0
+    for hi, (_, values) in enumerate(jobs, 1):
+        leaves += len(values)
+        if leaves >= max_leaves:
+            groups.append((lo, hi))
+            lo, leaves = hi, 0
+    if lo < len(jobs):
+        groups.append((lo, len(jobs)))
     return groups
 
 
 class RebuildPipeline:
     """The commit of one chunk: sweep groups, windows, one digest arena.
 
-    The job list is cut into sweep groups (``_group_jobs``) and the groups
-    into windows of ``PACK_WINDOW`` consecutive groups before the first
-    sweep starts. ONE group (one large subtrie, a live-tip trie) is swept
+    The job list is cut into sweep groups (``_group_jobs``: a group closes
+    at the job that brings it to ``LEAVES_PER_SWEEP``, however many tries
+    it holds) and the groups into windows of ``PACK_WINDOW`` consecutive
+    groups before the first sweep starts; a chunk of the stage's 500,000
+    leaves is at most 16 groups, so ONE window. A group of several jobs is
+    marshalled in one piece (``_marshal_group``), so what it costs on the
+    host follows its leaves and not its number of tries. ONE group (one
+    large subtrie, any job list under ``LEAVES_PER_SWEEP`` leaves) is swept
     by the calling thread; several by a small thread pool
     (``native/triebuild.cpp``; the ctypes call releases the GIL), side by
     side and ahead of the consumer, which takes the results in SUBMISSION
@@ -606,10 +644,13 @@ class RebuildPipeline:
     per-level engines hash a window as it is dispatched, and an engine
     with ``flush_window`` (the whole-subtrie family) executes its staged
     window there: on those, hashing window k overlaps the sweeps of
-    window k+1. ``MegaFusedEngine`` (the single-chip default) only STAGES
-    what it is fed and runs every level program in ``finish()``: on it
-    the sweeps overlap one another and the packing and staging of earlier
-    windows, and with one group nothing overlaps anything.
+    window k+1; a job list of one window (under ``PACK_WINDOW`` *
+    ``LEAVES_PER_SWEEP`` leaves, whatever its number of tries) is hashed
+    there after its last sweep. ``MegaFusedEngine`` (the single-chip
+    default) only STAGES what it is fed and runs every level program in
+    ``finish()``: on it the sweeps overlap one another and the packing and
+    staging of earlier windows, and with one group nothing overlaps
+    anything.
 
     Fault surface: a supervised backend ("auto") fails over mid-commit to
     the numpy twin via its journal; the pipeline keeps feeding it, which
@@ -667,7 +708,7 @@ class RebuildPipeline:
             return []
         t_wall = time.perf_counter()
         met = pipeline_metrics
-        groups = _group_jobs(jobs, LEAVES_PER_SWEEP, JOBS_PER_SWEEP)
+        groups = _group_jobs(jobs, LEAVES_PER_SWEEP)
         busy = [0]
         busy_lock = threading.Lock()
         lib, backend = self.lib, self.backend
@@ -796,7 +837,7 @@ class RebuildPipeline:
                     if sw.meta_rec is None or not len(sw.meta_rec):
                         continue
                     group_results = [results[j] for j in sw.job_ids]
-                    _collect_meta_records(sw.meta_rec, sw.key_arrays,
+                    _collect_meta_records(sw.meta_rec, sw.keys,
                                           digests, group_results,
                                           start_depth, slot_base=base)
         stages["fetch"] += time.perf_counter() - t0
@@ -971,12 +1012,13 @@ _META_REC = np.dtype([
 ])
 
 
-def _collect_meta_records(meta_rec, key_arrays, digests, results,
+def _collect_meta_records(meta_rec, keys, digests, results,
                           start_depth=0, slot_base=0):
     """Decode native BranchMeta records into per-job TrieUpdates, every
     record of the call at once: the fields, the path nibbles and the child
     hashes are gathered by numpy into Python lists and two blobs, and the
     one loop over records only slices those and builds the objects.
+    ``keys``: the sweep group's sorted keys, job after job in one array.
     ``slot_base`` rebases the records' group-local digest slots into the
     pipeline's shared arena slot space."""
     from ..metrics import trie_metrics
@@ -991,8 +1033,7 @@ def _collect_meta_records(meta_rec, key_arrays, digests, results,
     # stored path skips the start_depth prefix nibbles of the full key
     depth = rec["depth"].astype(np.intp)
     n_bytes = (start_depth + int(depth.max()) + 1) // 2
-    heads = np.concatenate([k[:, :n_bytes] for k in key_arrays])[
-        rec["rep_key"]]  # rep_key is global
+    heads = keys[:, :n_bytes][rec["rep_key"]]  # rep_key: a row of the group
     nibs = np.empty((n, 2 * n_bytes), dtype=np.uint8)
     nibs[:, 0::2] = heads >> 4
     nibs[:, 1::2] = heads & 0xF

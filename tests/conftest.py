@@ -30,9 +30,10 @@ def pytest_configure(config):
 @pytest.fixture
 def rebuild_layout(monkeypatch):
     """Set the rebuild's layout constants (``reth_tpu/trie/turbo.py``:
-    ``SWEEP_THREADS``, ``PACK_WINDOW``, ``LEAVES_PER_SWEEP``,
-    ``JOBS_PER_SWEEP``) for one test, so that a tiny chunk is laid out as
-    many sweep groups and windows: ``rebuild_layout(JOBS_PER_SWEEP=1)``."""
+    ``SWEEP_THREADS``, ``PACK_WINDOW``, ``LEAVES_PER_SWEEP``) for one test,
+    so that a tiny chunk is laid out as many sweep groups and windows:
+    ``rebuild_layout(LEAVES_PER_SWEEP=1)`` is a group a job (a group closes
+    at the job that brings it to the bound)."""
     from reth_tpu.trie import turbo
 
     def set_layout(**constants):

@@ -73,7 +73,7 @@ def test_every_phase_moves_on_the_serial_path_and_sums_within_the_wall():
 def test_every_phase_moves_on_the_pipelined_path_with_two_jobs(rebuild_layout):
     committer = TurboCommitter(backend="device", min_tier=8)
     jobs = [_job(2000, 2, prefix=0x10), _job(2000, 3, prefix=0x11)]
-    rebuild_layout(JOBS_PER_SWEEP=1)    # two groups: the sweep pool
+    rebuild_layout(LEAVES_PER_SWEEP=1)  # two groups: the sweep pool
     before = _counters(PHASES)
     t0 = time.perf_counter()
     res = committer.commit_hashed_pipelined(jobs, collect_branches=True,
@@ -127,7 +127,7 @@ def test_the_pipelined_decode_gives_the_serial_paths_branch_nodes(
 
         serial, s_moved, s_spans = commit(committer.commit_hashed_many)
         assert bases == [0]
-        rebuild_layout(JOBS_PER_SWEEP=2)
+        rebuild_layout(LEAVES_PER_SWEEP=901)  # (900, 1) (300, 1500) (40)
         piped, p_moved, p_spans = commit(committer.commit_hashed_pipelined)
     finally:
         tracing.set_trace_enabled(False)

@@ -7,14 +7,17 @@ device engine.
 
 What only this shape has: every trie has its own root, extensions at the top,
 values of 1-33 bytes, leaves and branch children UNDER 32 bytes (embedded in
-their parent, not hashed), sweep groups closed by the job bound, many windows
-a chunk. Every answer is held to the plain reference
-(``benchmark/reference/mpt.py``), bit for bit.
+their parent, not hashed), sweep groups of hundreds or thousands of tries,
+closed by their leaves alone and marshalled in one piece, ONE window a chunk.
+Every answer is held to the plain reference (``benchmark/reference/mpt.py``),
+bit for bit.
 """
 
 from __future__ import annotations
 
+import json
 import time
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -27,6 +30,7 @@ from reth_tpu.ops import fused_commit as fc
 from reth_tpu.trie import turbo
 from reth_tpu.trie.turbo import TurboCommitter
 
+ROOT = Path(__file__).resolve().parents[1]
 TRAFFIC = {
     "kind": "storage_chunks", "distinct_ops": 1,
     "jobs": {"chunk_leaves": 2000,
@@ -144,18 +148,36 @@ def test_a_branch_under_32_bytes_is_embedded_too(backend):
 
 # -- the plan of a storage-shaped chunk ---------------------------------------
 
-# groups closed by the job bound (16 jobs) or by one larger trie (150 leaves),
-# four groups a window: the cell's layout in small (64 jobs / 32,768 leaves a
-# group, 16 groups a window)
-_LAYOUT = dict(JOBS_PER_SWEEP=16, LEAVES_PER_SWEEP=150, PACK_WINDOW=4,
-               SWEEP_THREADS=4)
-_GOLDEN = {"jobs": 505, "groups": 33, "windows": 9, "dispatches": 69,
-           "s_tier": 4096, "lens": (114688, 7168)}
+# A group closes at the job that brings it to LEAVES_PER_SWEEP, whatever the
+# number of tries in it. "cell": the cell's layout in small (32,768 leaves a
+# group of a 500,000-leaf chunk, 16 groups a window: at most 16 groups, ONE
+# window); "windows": smaller groups, four a window, so that slots are rebased
+# across many windows. The arena's tier and the staging buffers' lengths are
+# the same under both; the dispatches are not.
+_LAYOUT = dict(LEAVES_PER_SWEEP=60, PACK_WINDOW=4, SWEEP_THREADS=4)
+_LAYOUTS = {
+    "windows": _LAYOUT,
+    "cell": dict(LEAVES_PER_SWEEP=131, PACK_WINDOW=16, SWEEP_THREADS=4),
+}
+_GOLDEN = {
+    "windows": {"jobs": 505, "groups": 24, "windows": 6, "dispatches": 50,
+                "s_tier": 4096, "lens": (114688, 7168)},
+    "cell": {"jobs": 505, "groups": 13, "windows": 1, "dispatches": 9,
+             "s_tier": 4096, "lens": (114688, 7168)},
+}
 _GOLDEN_SIGNATURES = {
-    ("mega.packed", 1, 64, 64), ("mega.packed", 1, 128, 64),
-    ("mega.packed", 1, 256, 64), ("mega.branch", 64, 64),
-    ("mega.branch", 64, 128), ("mega.branch", 64, 256),
-    ("mega.branch", 64, 512), ("mega.branch", 128, 256),
+    "windows": {
+        ("mega.packed", 1, 64, 64), ("mega.packed", 1, 128, 64),
+        ("mega.packed", 1, 256, 64), ("mega.branch", 64, 64),
+        ("mega.branch", 64, 128), ("mega.branch", 64, 256),
+        ("mega.branch", 64, 512), ("mega.branch", 128, 256),
+    },
+    "cell": {
+        ("mega.packed", 1, 64, 64), ("mega.packed", 1, 512, 64),
+        ("mega.packed", 1, 1024, 64), ("mega.branch", 64, 64),
+        ("mega.branch", 256, 512), ("mega.branch", 256, 1024),
+        ("mega.branch", 512, 1024),
+    },
 }
 
 
@@ -204,20 +226,62 @@ def _planned_commit(monkeypatch, seen_plans, jobs, jitter=None):
 
 
 @pytest.mark.parametrize("jitter", [None, 7, 8])
+@pytest.mark.parametrize("layout", ["windows", "cell"])
 def test_the_plan_of_a_storage_chunk_follows_from_the_job_list(
-        monkeypatch, rebuild_layout, small_tiers, seen_plans, jitter):
+        monkeypatch, rebuild_layout, small_tiers, seen_plans, layout, jitter):
     jobs = _chunk(3300000001)
-    rebuild_layout(**_LAYOUT)
+    rebuild_layout(**_LAYOUTS[layout])
     results, got = _planned_commit(monkeypatch, seen_plans, jobs, jitter)
-    for name, want in _GOLDEN.items():
+    golden = _GOLDEN[layout]
+    for name, want in golden.items():
         assert got[name] == want, name
-    assert got["signatures"] == _GOLDEN_SIGNATURES
-    assert got["closing"] == {_GOLDEN["lens"] + (_GOLDEN["s_tier"],)}
+    assert got["signatures"] == _GOLDEN_SIGNATURES[layout]
+    assert got["closing"] == {golden["lens"] + (golden["s_tier"],)}
     # many windows fragment the chunk: far more dispatches than levels
     assert got["dispatches"] > 3 * got["windows"]
     again_results, again = _planned_commit(monkeypatch, seen_plans, jobs, jitter)
     assert again == got                              # entry for entry
     assert [r.root for r in again_results] == [r.root for r in results]
+    _assert_equals_the_reference(jobs, results)
+
+
+# -- a chunk is ONE window -----------------------------------------------------
+
+
+def _layout_of_the_last_commit():
+    last = pipeline_metrics.last
+    return last["jobs"], last["groups"], last["windows"]
+
+
+def test_the_rehearsal_chunk_is_one_group_in_one_window():
+    """At the program's constants 2,000 slots close no group: ~505 tries are
+    ONE group, marshalled in one piece and swept by the caller."""
+    jobs = _chunk(6)
+    assert sum(len(v) for _, v in jobs) < turbo.LEAVES_PER_SWEEP
+    results = _commit("numpy", jobs)
+    assert _layout_of_the_last_commit() == (len(jobs), 1, 1)
+    _assert_equals_the_reference(jobs, results)
+
+
+@pytest.mark.parametrize("backend", ["numpy", "device"])
+def test_a_chunk_under_the_real_law_is_one_window(rebuild_layout, backend):
+    """The cell's own size law (one trie holds a third of the chunk) at 4,000
+    slots for 500,000, ``LEAVES_PER_SWEEP`` scaled down alike: as the cell's
+    chunk, at most ``PACK_WINDOW`` groups whatever the number of tries."""
+    config = json.loads((ROOT / "benchmark" / "configs"
+                         / "sync-rebuild-storage.json").read_text())
+    chunk_leaves = 4000
+    traffic = dict(TRAFFIC, jobs={
+        "chunk_leaves": chunk_leaves,
+        "size_law": config["storage_trie_size_law"]})
+    jobs = gen.storage_chunk_ops(traffic, 11)[0]
+    assert max(len(v) for _, v in jobs) > chunk_leaves // 4
+    rebuild_layout(LEAVES_PER_SWEEP=turbo.LEAVES_PER_SWEEP * chunk_leaves
+                   // config["chunk_leaves"])
+    results = _commit(backend, jobs, min_tier=8)
+    n_jobs, groups, windows = _layout_of_the_last_commit()
+    assert n_jobs == len(jobs) > 100
+    assert 4 <= groups <= turbo.PACK_WINDOW and windows == 1
     _assert_equals_the_reference(jobs, results)
 
 
@@ -243,8 +307,7 @@ def test_groups_counter_and_collect_phase_move_once_a_commit(rebuild_layout):
     finally:
         tracing.set_trace_enabled(False)
     moved = {n: REGISTRY.counter(n).value - before[n] for n in names}
-    groups = turbo._group_jobs(jobs, _LAYOUT["LEAVES_PER_SWEEP"],
-                               _LAYOUT["JOBS_PER_SWEEP"])
+    groups = turbo._group_jobs(jobs, _LAYOUT["LEAVES_PER_SWEEP"])
     assert moved["trie_pipeline_groups_total"] == len(groups) > 16
     assert moved["trie_pipeline_windows_total"] == -(-len(groups) // 4)
     assert moved["trie_pipeline_subtries_total"] == len(jobs)

@@ -159,12 +159,12 @@ def test_turbo_start_depth_subtrie_parity(turbo_np):
 # -- the bulk decode of branch records against the record-by-record loop ------
 
 
-def _loop_results(meta_rec, key_arrays, digests, n_jobs, start_depth,
-                  slot_base):
-    """What the loop that ``_collect_meta_records`` replaced gives."""
+def _loop_results(meta_rec, keys, digests, n_jobs, start_depth, slot_base):
+    """What the loop that ``_collect_meta_records`` replaced gives, over the
+    sweep group's one sorted key array (``rep_key`` is a row of it: every job
+    starts at 0 for the loop)."""
     want = [TrieBuildResult(root=b"") for _ in range(n_jobs)]
-    job_starts = np.cumsum([0] + [len(k) for k in key_arrays])
-    return collect_meta_records_loop(meta_rec, key_arrays, job_starts,
+    return collect_meta_records_loop(meta_rec, [keys] * n_jobs, [0] * n_jobs,
                                      digests, want, start_depth, slot_base)
 
 
@@ -198,12 +198,10 @@ def test_bulk_decode_equals_the_loop_on_real_sweeps(turbo_np, monkeypatch,
     calls = []
     real = turbo._collect_meta_records
 
-    def spy(meta_rec, key_arrays, digests, results, start_depth=0,
-            slot_base=0):
-        calls.append((meta_rec.copy(), key_arrays, digests, len(results),
+    def spy(meta_rec, keys, digests, results, start_depth=0, slot_base=0):
+        calls.append((meta_rec.copy(), keys, digests, len(results),
                       start_depth, slot_base))
-        return real(meta_rec, key_arrays, digests, results, start_depth,
-                    slot_base)
+        return real(meta_rec, keys, digests, results, start_depth, slot_base)
 
     monkeypatch.setattr(turbo, "_collect_meta_records", spy)
     jobs = [_job(n, seed=50 + i) for i, n in enumerate(sizes)]
@@ -250,8 +248,8 @@ def _synthetic_records(rng, n, job_sizes, max_slot, max_depth=6):
 def test_bulk_decode_equals_the_loop_on_synthetic_records(start_depth,
                                                           slot_base):
     rng = np.random.default_rng(start_depth * 7 + slot_base)
-    key_arrays = [rng.integers(0, 256, (n, 32), dtype=np.uint8)
-                  for n in (5, 4, 6)]
+    # three jobs of 5, 4 and 6 sorted keys in the group's one array
+    keys = rng.integers(0, 256, (5 + 4 + 6, 32), dtype=np.uint8)
     digests = rng.integers(0, 256, (slot_base + 64, 32), dtype=np.uint8)
     slots = list(range(1, 17))
     meta_rec = np.frombuffer(b"".join([
@@ -264,24 +262,24 @@ def test_bulk_decode_equals_the_loop_on_synthetic_records(start_depth,
         # job 1 of the three gets no record
     ]), dtype=np.uint8).reshape(-1, 80)
     got = [TrieBuildResult(root=b"") for _ in range(3)]
-    assert turbo._collect_meta_records(meta_rec, key_arrays, digests, got,
+    assert turbo._collect_meta_records(meta_rec, keys, digests, got,
                                        start_depth, slot_base) is got
     _assert_same_branch_nodes(
-        got, _loop_results(meta_rec, key_arrays, digests, 3, start_depth,
+        got, _loop_results(meta_rec, keys, digests, 3, start_depth,
                            slot_base))
     assert [len(r.branch_nodes) for r in got] == [3, 0, 2]
     assert got[0].branch_nodes[b""].hashes == ()
     full = got[2].branch_nodes[bytes(
-        unpack_nibbles(key_arrays[2][3].tobytes())[start_depth:start_depth + 7])]
+        unpack_nibbles(keys[9 + 3].tobytes())[start_depth:start_depth + 7])]
     assert full.hashes == tuple(
         digests[slot_base + s].tobytes() for s in slots)
     # and a larger random set, with records of every job interleaved
     many = _synthetic_records(rng, 300, (5, 4, 6), 64)
     got = [TrieBuildResult(root=b"") for _ in range(3)]
-    turbo._collect_meta_records(many, key_arrays, digests, got, start_depth,
+    turbo._collect_meta_records(many, keys, digests, got, start_depth,
                                 slot_base)
     _assert_same_branch_nodes(
-        got, _loop_results(many, key_arrays, digests, 3, start_depth,
+        got, _loop_results(many, keys, digests, 3, start_depth,
                            slot_base))
 
 
@@ -290,7 +288,7 @@ def test_bulk_decode_of_no_records_leaves_the_results_alone():
     before = REGISTRY.counter("trie_commit_decode_records_total").value
     turbo._collect_meta_records(
         np.zeros((0, 80), dtype=np.uint8),
-        [np.zeros((1, 32), dtype=np.uint8)],
+        np.zeros((1, 32), dtype=np.uint8),
         np.zeros((8, 32), dtype=np.uint8), got)
     assert got[0].branch_nodes == {} and type(got[0].branch_nodes) is dict
     assert REGISTRY.counter(
@@ -301,7 +299,7 @@ def test_bulk_decode_stays_faster_than_the_loop():
     """A guard that the per-record numpy loop does not come back: a ratio
     in one process, so it holds on a loaded machine (expected 2.5x-5x)."""
     rng = np.random.default_rng(27)
-    keys = [rng.integers(0, 256, (60_000, 32), dtype=np.uint8)]
+    keys = rng.integers(0, 256, (60_000, 32), dtype=np.uint8)
     digests = rng.integers(0, 256, (1 << 16, 32), dtype=np.uint8)
     meta_rec = _synthetic_records(rng, 20_000, (60_000,), 1 << 16)
 

@@ -7,8 +7,8 @@ group swept by the caller, the answers are bit-identical: pooled
 `native/triebuild.cpp` sweeps + cross-subtrie level packing + resident
 digest arena may change WHEN rows hash, never WHAT they hash. Roots and
 TrieUpdates branch metadata are pinned against the same chunk as one group
-(the layout of every chunk at the program's constants below 64 jobs and
-32,768 leaves; ``commit_hashed_many`` is the same call by its older name)
+(the layout of every chunk at the program's constants below 32,768
+leaves; ``commit_hashed_many`` is the same call by its older name)
 and against the plain reference (``benchmark/reference/mpt.py``).
 """
 
@@ -74,9 +74,9 @@ def turbo_np():
 
 
 @pytest.mark.parametrize("layout", [
-    dict(JOBS_PER_SWEEP=1, PACK_WINDOW=1),       # no packing, max overlap
-    dict(JOBS_PER_SWEEP=4, PACK_WINDOW=16),      # grouped sweeps, wide packs
-    dict(JOBS_PER_SWEEP=64, LEAVES_PER_SWEEP=200),  # leaf-bounded groups
+    dict(LEAVES_PER_SWEEP=1, PACK_WINDOW=1),     # a group a job, no packing
+    dict(LEAVES_PER_SWEEP=400, PACK_WINDOW=16),  # grouped sweeps, wide packs
+    dict(LEAVES_PER_SWEEP=200, PACK_WINDOW=2),   # smaller groups, windows
 ])
 def test_pipelined_root_and_branch_parity(turbo_np, rebuild_layout, layout):
     jobs = [_job(30 + 17 * i, seed=i) for i in range(12)]
@@ -94,7 +94,7 @@ def test_pipelined_subtrie_start_depth_parity(turbo_np, rebuild_layout):
     jobs = _prefix_jobs(600, seed=7)
     want = [turbo_np.commit_hashed_many([j], collect_branches=True,
                                         start_depth=2)[0] for j in jobs]
-    rebuild_layout(JOBS_PER_SWEEP=8)
+    rebuild_layout(LEAVES_PER_SWEEP=20)      # ~8 prefix subtries a group
     got = turbo_np.commit_hashed_pipelined(jobs, collect_branches=True,
                                            start_depth=2)
     assert [r.root for r in got] == [r.root for r in want]
@@ -122,9 +122,143 @@ def test_pipelined_rejects_like_serial(turbo_np, rebuild_layout):
     values[3] = b"\xb9\xff\xff" + bytes(65535)  # > native leaf cap
     with pytest.raises(ValueError, match="oversized"):
         turbo_np.commit_hashed_pipelined([(keys, values)])
-    rebuild_layout(JOBS_PER_SWEEP=1)
+    rebuild_layout(LEAVES_PER_SWEEP=1)
     with pytest.raises(ValueError, match="oversized"):
         turbo_np.commit_hashed_pipelined([(keys, values), _job(10, seed=4)])
+
+
+# -- a group marshalled in one piece ------------------------------------------
+#
+# A group of several jobs is sorted once, by (job number, key), and its values
+# are passed over once. What the native sweep is handed is what the sort a job
+# gave it: the keys, the value blob, ``val_off`` and ``job_off``.
+
+
+def _per_job_marshal(jobs):
+    """The marshal as it was before a group was marshalled in one piece: one
+    stable sort and one value reorder a job, kept here as the reference."""
+    key_arrays, values, job_off = [], [], [0]
+    for keys, vals in jobs:
+        keys = np.ascontiguousarray(keys, dtype=np.uint8).reshape(-1, 32)
+        order = np.argsort(keys.view("S32").ravel(), kind="stable")
+        key_arrays.append(keys[order])
+        values.extend(vals[i] for i in order)
+        job_off.append(job_off[-1] + len(keys))
+    return np.concatenate(key_arrays), values, job_off
+
+
+def _blob_and_offsets(values):
+    return b"".join(values), np.cumsum([0] + [len(v) for v in values])
+
+
+def _sorted_job(job):
+    keys, values = job
+    order = np.argsort(keys.view("S32").ravel())
+    return keys[order], [values[i] for i in order]
+
+
+def _marshal_cases():
+    jobs = [_job(n, seed=500 + n) for n in (40, 1, 7, 300, 2)]
+    shared = [(jobs[0][0][:5].copy(), [b"\x05"] * 5), jobs[0], jobs[2]]
+    empty = (np.zeros((0, 32), dtype=np.uint8), [])
+    return {
+        "unsorted": jobs,
+        "sorted": [_sorted_job(j) for j in jobs],
+        "sorted_then_not": [_sorted_job(j) for j in jobs[:3]] + jobs[3:],
+        "an_empty_job_inside": jobs[:2] + [empty] + jobs[2:] + [empty],
+        "only_empty_jobs": [empty, empty],
+        "one_key_in_two_jobs": shared,
+    }
+
+
+@pytest.mark.parametrize("case", list(_marshal_cases()))
+def test_the_group_marshal_equals_the_per_job_marshal(case):
+    jobs = _marshal_cases()[case]
+    want_keys, want_values, want_off = _per_job_marshal(jobs)
+    keys, values, counts = turbo._marshal_group(jobs)
+    assert keys.dtype == np.uint8 and keys.flags.c_contiguous
+    assert np.array_equal(keys, want_keys)
+    assert [0] + np.cumsum(counts).tolist() == want_off
+    assert values == want_values and type(values) is list
+    blob, val_off = _blob_and_offsets(values)
+    want_blob, want_val_off = _blob_and_offsets(want_values)
+    assert blob == want_blob and np.array_equal(val_off, want_val_off)
+    if case == "one_key_in_two_jobs":
+        # job 0's five keys are all in job 1 too: no duplicate
+        assert ({bytes(k) for k in keys[:5]}
+                < {bytes(k) for k in keys[5:45]})
+
+
+@pytest.mark.parametrize("case", ["unsorted", "sorted", "an_empty_job_inside",
+                                  "one_key_in_two_jobs"])
+def test_a_group_in_one_piece_commits_to_the_plain_reference(turbo_np, case):
+    from reth_tpu.metrics import pipeline_metrics
+
+    jobs = _marshal_cases()[case]
+    results = turbo_np.commit_hashed_pipelined(jobs, collect_branches=True)
+    assert pipeline_metrics.last["groups"] == 1
+    _assert_equals_the_plain_reference(jobs, results, 0)
+
+
+@pytest.fixture
+def marshal_spy(monkeypatch):
+    """How many jobs each marshal was handed."""
+    seen = {"one": 0, "group": []}
+    real_one, real_group = turbo._marshal_one, turbo._marshal_group
+
+    def one(keys, values):
+        seen["one"] += 1
+        return real_one(keys, values)
+
+    def group(jobs):
+        seen["group"].append(len(jobs))
+        return real_group(jobs)
+
+    monkeypatch.setattr(turbo, "_marshal_one", one)
+    monkeypatch.setattr(turbo, "_marshal_group", group)
+    return seen
+
+
+def test_a_group_of_one_job_takes_the_path_it_took(turbo_np, marshal_spy,
+                                                   rebuild_layout):
+    """Chosen by the number of jobs in the group, which the code sees in its
+    input: one job is sorted by its own ``argsort`` of ``S32`` with no job
+    column (the account cells' chunks), several are one piece."""
+    jobs = [_job(50, seed=700 + i) for i in range(3)]
+    turbo_np.commit_hashed_pipelined(jobs[:1], collect_branches=True)
+    assert marshal_spy == {"one": 1, "group": []}
+    turbo_np.commit_hashed_pipelined(jobs)           # one group of three
+    assert marshal_spy == {"one": 1, "group": [3]}
+    rebuild_layout(LEAVES_PER_SWEEP=1)               # a group a job
+    turbo_np.commit_hashed_pipelined(jobs)
+    assert marshal_spy == {"one": 4, "group": [3]}
+    rebuild_layout(LEAVES_PER_SWEEP=100)             # (50, 50) (50)
+    turbo_np.commit_hashed_pipelined(jobs)
+    assert marshal_spy == {"one": 5, "group": [3, 2]}
+
+
+def _with_a_duplicate(job):
+    keys, values = job
+    return np.concatenate([keys, keys[3:4]]), values + [b"\x01"]
+
+
+@pytest.mark.parametrize("fault,match", [
+    (_with_a_duplicate, "duplicate keys"),
+    (lambda job: (job[0], job[1][:-1]), "length mismatch"),
+    (lambda job: (job[0], [b"\xb9\xff\xff" + bytes(65535)] + job[1][1:]),
+     "oversized"),
+])
+@pytest.mark.parametrize("where", [0, 1, 2])
+def test_a_group_rejects_what_a_job_rejects(turbo_np, fault, match, where):
+    """A duplicate inside ONE job of a group, a keys/values mismatch and an
+    oversized value are the ``ValueError`` they were, wherever in the group
+    the job sits."""
+    jobs = [_job(12, seed=800 + i) for i in range(3)]
+    jobs[where] = fault(jobs[where])
+    with pytest.raises(ValueError, match=match):
+        turbo_np.commit_hashed_pipelined(jobs)
+    with pytest.raises(ValueError, match=match):
+        turbo_np.commit_hashed_pipelined([jobs[where]])
 
 
 # -- grouping / packing ------------------------------------------------------
@@ -132,19 +266,26 @@ def test_pipelined_rejects_like_serial(turbo_np, rebuild_layout):
 
 def test_group_jobs_bounds():
     jobs = [(None, [b""] * n) for n in (10, 10, 10, 50, 5, 5)]
-    # leaf bound splits after the job that crosses it; job bound caps width
-    assert _group_jobs(jobs, max_leaves=20, max_jobs=64) == [
-        (0, 2), (2, 4), (4, 6)]
-    assert _group_jobs(jobs, max_leaves=10**9, max_jobs=2) == [
-        (0, 2), (2, 4), (4, 6)]
-    assert _group_jobs([], 100, 4) == []
+    # the leaf bound closes a group at the job that reaches it
+    assert _group_jobs(jobs, max_leaves=20) == [(0, 2), (2, 4), (4, 6)]
+    assert _group_jobs(jobs, max_leaves=60) == [(0, 4), (4, 6)]
+    # a bound of 1 is a job a group; an empty job goes with the next one
+    assert _group_jobs(jobs, max_leaves=1) == [(i, i + 1) for i in range(6)]
+    assert _group_jobs([(None, [])] + jobs[:2], max_leaves=1) == [
+        (0, 2), (2, 3)]
+    assert _group_jobs(jobs, max_leaves=10**9) == [(0, 6)]
+    assert _group_jobs([], 100) == []
+    # and nothing else closes one: the number of tries costs nothing
+    tiny = [(None, [b""])] * 40000
+    assert _group_jobs(tiny, turbo.LEAVES_PER_SWEEP) == [
+        (0, 32768), (32768, 40000)]
 
 
 def test_pipeline_metrics_recorded(turbo_np, rebuild_layout):
     from reth_tpu.metrics import pipeline_metrics
 
     jobs = [_job(30, seed=40 + i) for i in range(8)]
-    rebuild_layout(JOBS_PER_SWEEP=2)
+    rebuild_layout(LEAVES_PER_SWEEP=60)      # two jobs of 30 a group
     turbo_np.commit_hashed_pipelined(jobs)
     last = pipeline_metrics.last
     assert last is not None
@@ -212,7 +353,7 @@ def test_injected_pipeline_abort(turbo_np, monkeypatch, rebuild_layout):
     jobs = [_job(20, seed=60 + i) for i in range(8)]
     want = turbo_np.commit_hashed_many(jobs)    # one group, one window
     monkeypatch.setenv("RETH_TPU_FAULT_PIPELINE_ABORT", "2")
-    rebuild_layout(JOBS_PER_SWEEP=1, PACK_WINDOW=1)
+    rebuild_layout(LEAVES_PER_SWEEP=1, PACK_WINDOW=1)
     with pytest.raises(InjectedPipelineAbort, match="window #2"):
         turbo_np.commit_hashed_pipelined(jobs)
     # the wounded committer must still complete the next (clean) commit
@@ -238,7 +379,7 @@ def test_mid_pipeline_failover_drains_onto_cpu(rebuild_layout):
     auto = TurboCommitter(backend="auto", min_tier=64, supervisor=sup)
     jobs = [_job(40, seed=80 + i) for i in range(10)]
     want = TurboCommitter(backend="numpy").commit_hashed_many(jobs)
-    rebuild_layout(JOBS_PER_SWEEP=2)
+    rebuild_layout(LEAVES_PER_SWEEP=80)      # two jobs of 40 a group
     got = auto.commit_hashed_pipelined(jobs)
     assert [r.root for r in got] == [r.root for r in want]
     assert sup.failovers >= 1
@@ -291,7 +432,7 @@ def test_pipeline_concurrent_sweeps_deterministic(turbo_np, rebuild_layout):
     """Python-level rerun determinism: many small groups racing through the
     pool must always produce the same roots."""
     jobs = [_job(15, seed=200 + i) for i in range(16)]
-    rebuild_layout(JOBS_PER_SWEEP=1, PACK_WINDOW=2)
+    rebuild_layout(LEAVES_PER_SWEEP=1, PACK_WINDOW=2)
     runs = [
         [r.root for r in turbo_np.commit_hashed_pipelined(jobs)]
         for _ in range(3)
@@ -467,11 +608,12 @@ def test_pipelined_equals_serial_and_the_plain_reference(
     path was); "pipelined" the same chunk as several groups and windows."""
     jobs = _chunk(_mixed_sizes(n_jobs), seed=1000 + n_jobs, prefix0=0x10)
     committer = _steady_committer(backend)
-    rebuild_layout(LEAVES_PER_SWEEP=10**9, JOBS_PER_SWEEP=10**9)
+    rebuild_layout(LEAVES_PER_SWEEP=10**9)
     serial = committer.commit_hashed_many(jobs, collect_branches=True,
                                           start_depth=start_depth)
-    # several sweep groups and several windows, so slots are rebased
-    rebuild_layout(LEAVES_PER_SWEEP=1000, JOBS_PER_SWEEP=3, PACK_WINDOW=2)
+    # several sweep groups and several windows, so slots are rebased: two
+    # or three of the tiny jobs a group, a group each of the large ones
+    rebuild_layout(LEAVES_PER_SWEEP=4, PACK_WINDOW=2)
     piped = committer.commit_hashed_pipelined(
         jobs, collect_branches=True, start_depth=start_depth)
     for got, want in zip(piped, serial):
@@ -527,7 +669,7 @@ def test_the_arena_rises_a_tier_at_a_time_however_many_windows(
             asks.append(max_slots)
             super().ensure(max_slots)
 
-    rebuild_layout(JOBS_PER_SWEEP=1, PACK_WINDOW=1)
+    rebuild_layout(LEAVES_PER_SWEEP=1, PACK_WINDOW=1)
     pipe = RebuildPipeline(Spy())
     got = pipe.run(jobs, collect_branches=True)
     assert pipe.windows == 48
@@ -672,15 +814,13 @@ def test_a_window_of_one_sweep_copies_nothing():
 
 def test_a_one_group_chunk_makes_no_thread(pipeline_spy, turbo_np):
     one = _chunk((300, 200), seed=3300)          # two jobs, one group
-    assert _group_jobs(one, turbo.LEAVES_PER_SWEEP,
-                       turbo.JOBS_PER_SWEEP) == [(0, 2)]
+    assert _group_jobs(one, turbo.LEAVES_PER_SWEEP) == [(0, 2)]
     turbo_np.commit_hashed_pipelined(one, collect_branches=True,
                                      start_depth=2)
     turbo_np.commit_hashed_many(one[:1])
     assert pipeline_spy["threads"] == []
     two = _chunk((40000, 300), seed=3310)        # the first fills a group
-    assert len(_group_jobs(two, turbo.LEAVES_PER_SWEEP,
-                           turbo.JOBS_PER_SWEEP)) == 2
+    assert len(_group_jobs(two, turbo.LEAVES_PER_SWEEP)) == 2
     turbo_np.commit_hashed_pipelined(two, start_depth=2)
     assert pipeline_spy["threads"]
     assert all(n.startswith("trie-sweep") for n in pipeline_spy["threads"])
