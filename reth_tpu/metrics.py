@@ -401,6 +401,13 @@ class PipelineMetrics:
         self._groups = reg.counter(
             "trie_pipeline_groups_total",
             "sweep groups laid out: one native build and one decode call each")
+        self._leaves = reg.counter(
+            "trie_pipeline_leaves_total",
+            "leaves of every sweep group laid out")
+        self._largest = reg.counter(
+            "trie_pipeline_largest_group_leaves_total",
+            "leaves of each commit's largest sweep group: over the leaves of "
+            "all, the share of a chunk that one thread sweeps alone")
         self._drains = reg.counter(
             "trie_pipeline_queue_drains_total",
             "windows hashed on the CPU twin after a mid-rebuild failover")
@@ -418,7 +425,8 @@ class PipelineMetrics:
     def set_pool_busy(self, n: int) -> None:
         self._busy.set(n)
 
-    def record_run(self, *, jobs: int, groups: int, windows: int,
+    def record_run(self, *, jobs: int, groups: int, leaves: int,
+                   largest_group_leaves: int, windows: int,
                    queue_peak: int, drained_windows: int, backend,
                    wall_s: float, sweep: float, wait: float, pack: float,
                    dispatch: float, fetch: float) -> None:
@@ -426,12 +434,15 @@ class PipelineMetrics:
         self._windows.increment(windows)
         self._subtries.increment(jobs)
         self._groups.increment(groups)
+        self._leaves.increment(leaves)
+        self._largest.increment(largest_group_leaves)
         self._drains.increment(drained_windows)
         for k, v in (("sweep", sweep), ("wait", wait), ("pack", pack),
                      ("dispatch", dispatch), ("fetch", fetch)):
             self._stage_s[k].increment(round(v, 6))
         self.last = {
-            "jobs": jobs, "groups": groups, "windows": windows,
+            "jobs": jobs, "groups": groups, "leaves": leaves,
+            "largest_group_leaves": largest_group_leaves, "windows": windows,
             "queue_peak": queue_peak, "drained_windows": drained_windows,
             "backend": backend, "wall_s": round(wall_s, 4),
             "sweep_s": round(sweep, 4), "wait_s": round(wait, 4),

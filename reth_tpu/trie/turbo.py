@@ -709,6 +709,10 @@ class RebuildPipeline:
         t_wall = time.perf_counter()
         met = pipeline_metrics
         groups = _group_jobs(jobs, LEAVES_PER_SWEEP)
+        # a job is never cut, so the largest group is what ONE thread sweeps
+        group_leaves = [sum(len(values) for _, values in jobs[lo:hi])
+                        for lo, hi in groups]
+        leaves, largest = sum(group_leaves), max(group_leaves)
         busy = [0]
         busy_lock = threading.Lock()
         lib, backend = self.lib, self.backend
@@ -788,7 +792,8 @@ class RebuildPipeline:
             sweeps.close()  # an aborted run: the pool is shut down here
             wall_s = time.perf_counter() - t_wall
             met.record_run(
-                jobs=len(jobs), groups=len(groups), windows=self.windows,
+                jobs=len(jobs), groups=len(groups), leaves=leaves,
+                largest_group_leaves=largest, windows=self.windows,
                 queue_peak=self.queue_peak, drained_windows=drained,
                 backend=getattr(backend, "effective_kind", None),
                 wall_s=wall_s, **stages)
@@ -796,6 +801,7 @@ class RebuildPipeline:
                 "trie::pipeline", "rebuild", time.time() - wall_s, wall_s,
                 ctx=trace_ctx,
                 fields={"jobs": len(jobs), "windows": self.windows,
+                        "leaves": leaves, "largest_group_leaves": largest,
                         **{k: round(v, 4) for k, v in stages.items()}})
 
     def _collect(self, swept, n_jobs, collect_branches, start_depth, stages):
