@@ -252,19 +252,20 @@ test-pipeline: test-subtrie
 
 native:
 	mkdir -p native/build
-	g++ -O2 -std=c++17 -shared -fPIC native/triebuild.cpp -o native/build/libtriebuild.so
+	g++ -O2 -std=c++17 -shared -fPIC -pthread native/triebuild.cpp -o native/build/libtriebuild.so
 	g++ -O2 -std=c++17 -shared -fPIC native/secp256k1.cpp -o native/build/libsecp.so
 	g++ -O2 -std=c++17 -shared -fPIC native/kvstore.cpp -o native/build/libkvstore.so
 	g++ -O2 -std=c++17 -shared -fPIC native/pagedkv.cpp -o native/build/libpagedkv.so
 	g++ -O2 -std=c++17 -shared -fPIC -pthread native/evmexec.cpp -o native/build/libevmexec.so
 
 # threaded stress of the native structure sweep under TSAN (the rebuild
-# pipeline calls rtb_build from a thread pool); mirrors kvstore_tsan.cpp.
+# pipeline calls rtb_build from a thread pool, and rtb_build sweeps a large
+# job on threads of its own); mirrors kvstore_tsan.cpp.
 # Where gcc's libtsan breaks on the running kernel, build with
 # -fsanitize=address,undefined instead (tests/test_turbo_pipeline.py
 # probes and picks automatically).
 tsan-triebuild:
 	mkdir -p native/build
-	g++ -std=c++17 -O1 -g -fsanitize=thread \
+	g++ -std=c++17 -O1 -g -fsanitize=thread -pthread \
 	  native/triebuild.cpp native/triebuild_tsan.cpp -o native/build/triebuild_stress
 	./native/build/triebuild_stress

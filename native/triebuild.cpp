@@ -24,9 +24,27 @@
 // Secure-trie keys only: every key is exactly 32 bytes (64 nibbles), as
 // produced by keccak256(address|slot) — the MerkleStage full-rebuild shape
 // (reference crates/stages/stages/src/stages/merkle.rs:184).
+//
+// Threads: the recursion shares nothing between a branch's children but the
+// append-only collectors and the slot counter, and child k's subtree takes a
+// contiguous run of slots and of every level's rows. So a job of
+// `threaded_job_leaves` leaves or more builds the children of its FIRST
+// branch side by side, each into a Build of its own, and the same threads
+// lay the pieces into the job's collectors in nibble order, slots and rows
+// shifted by what the children before took: the arrays of the serial sweep,
+// byte for byte, whatever the threads' timing (Build::build_children_threaded;
+// tests/test_sweep_threads.py, native/triebuild_tsan.cpp).
 
+#include <algorithm>
+#include <atomic>
 #include <cstdint>
 #include <cstring>
+#include <cstdlib>
+#include <new>
+#include <system_error>
+#include <thread>
+#include <type_traits>
+#include <utility>
 #include <vector>
 
 namespace {
@@ -46,16 +64,70 @@ struct Child {          // bitmap-branch child
     int32_t src;
 };
 
+// A growable array of plain values on realloc(): resize() leaves new
+// elements unwritten, so the threaded sweep sizes a level once and its
+// threads write their own pieces (a std::vector would zero-fill: one serial
+// pass over the job's output), and growing copies no byte that the
+// allocator can keep in place.
+template <class T>
+struct Vec {
+    static_assert(std::is_trivially_copyable<T>::value, "Vec holds plain values");
+    T* ptr = nullptr;
+    size_t len = 0, cap = 0;
+
+    Vec() = default;
+    Vec(const Vec&) = delete;
+    Vec& operator=(const Vec&) = delete;
+    Vec(Vec&& o) noexcept : ptr(o.ptr), len(o.len), cap(o.cap) {
+        o.ptr = nullptr;
+        o.len = o.cap = 0;
+    }
+    Vec& operator=(Vec&& o) noexcept {
+        std::swap(ptr, o.ptr);
+        std::swap(len, o.len);
+        std::swap(cap, o.cap);
+        return *this;
+    }
+    ~Vec() { free(ptr); }
+
+    size_t size() const { return len; }
+    bool empty() const { return len == 0; }
+    T* data() { return ptr; }
+    const T* data() const { return ptr; }
+    T& operator[](size_t i) { return ptr[i]; }
+    const T& operator[](size_t i) const { return ptr[i]; }
+    const T* begin() const { return ptr; }
+    const T* end() const { return ptr + len; }
+    void resize(size_t n) {
+        if (n > cap) {
+            size_t want = std::max(n, std::max<size_t>(16, 2 * cap));
+            T* grown = static_cast<T*>(realloc(ptr, want * sizeof(T)));
+            if (!grown) throw std::bad_alloc();
+            ptr = grown;
+            cap = want;
+        }
+        len = n;
+    }
+    void push_back(const T& v) {
+        resize(len + 1);
+        ptr[len - 1] = v;
+    }
+    void append(const T* from, size_t n) {
+        resize(len + n);
+        memcpy(ptr + len - n, from, n * sizeof(T));
+    }
+};
+
 struct Level {
     // packed group
-    std::vector<uint8_t> bytes;
-    std::vector<uint32_t> row_off;   // size rows+1
-    std::vector<int32_t> row_slot;
-    std::vector<Hole> holes;
+    Vec<uint8_t> bytes;
+    Vec<uint32_t> row_off;   // size rows+1
+    Vec<int32_t> row_slot;
+    Vec<Hole> holes;
     // bitmap group
-    std::vector<uint16_t> masks;
-    std::vector<int32_t> bmp_slot;
-    std::vector<Child> children;
+    Vec<uint16_t> masks;
+    Vec<int32_t> bmp_slot;
+    Vec<Child> children;
 };
 
 struct BranchMeta {      // TrieUpdates record (reference BranchNodeCompact)
@@ -67,6 +139,27 @@ struct BranchMeta {      // TrieUpdates record (reference BranchNodeCompact)
     uint16_t hash_mask;
     int32_t child_slot[16];  // slot when hashed, -1 otherwise
 };
+
+// fn(k) for every k in [0, count) on up to `threads` threads, the caller one
+// of them, each taking the next k off a counter. A thread the system refuses
+// is work the others take.
+template <class F>
+void each_on_threads(int threads, int count, F fn) {
+    std::atomic<int> next{0};
+    auto work = [&] {
+        for (int k; (k = next.fetch_add(1, std::memory_order_relaxed)) < count;) fn(k);
+    };
+    std::vector<std::thread> pool;
+    for (int t = 1; t < std::min(threads, count); t++) {
+        try {
+            pool.emplace_back(work);
+        } catch (const std::system_error&) {
+            break;
+        }
+    }
+    work();
+    for (auto& t : pool) t.join();
+}
 
 // A finalized child reference flowing up the recursion.
 struct Ref {
@@ -84,9 +177,14 @@ struct Build {
     bool collect_meta;
     std::vector<Level> levels{NIBS + 1};
     std::vector<uint8_t> scratch;          // inline-node RLP bytes
-    std::vector<BranchMeta> meta;
+    Vec<BranchMeta> meta;
     int32_t next_slot = 1;                 // 0 reserved dummy
     int err = 0;
+    // a branch over `threaded_leaves` keys or more builds its children on
+    // `threads` threads. The children's own Builds keep threads 1, so it is
+    // the first branch a job's recursion meets and no other
+    int threads = 1;
+    uint64_t threaded_leaves = 0;
 
     inline uint8_t nib(uint64_t key, int k) const {
         uint8_t b = keys[key * 32 + (k >> 1)];
@@ -164,7 +262,7 @@ struct Build {
         if (lv.row_off.empty()) lv.row_off.push_back(0);
         int32_t row = int32_t(lv.row_off.size()) - 1;
         r.slot = next_slot++;
-        lv.bytes.insert(lv.bytes.end(), tmp.begin(), tmp.end());
+        lv.bytes.append(tmp.data(), tmp.size());
         lv.row_off.push_back(uint32_t(lv.bytes.size()));
         lv.row_slot.push_back(r.slot);
         for (Hole h : node_holes) {
@@ -223,22 +321,38 @@ struct Build {
             tmp.insert(tmp.end(), payload.begin(), payload.end());
             return emit(at_depth, tmp, holes, c.has_branch);
         }
-        // branch over the distinct nibbles at `depth`
+        // branch over the distinct nibbles at `depth`: the children's key
+        // ranges, the children, then the branch's own row
         Ref kids[16];
         bool present[16] = {};
-        uint64_t i = lo;
-        uint16_t state_mask = 0;
-        bool all_hashed = true;
-        while (i < hi) {
+        int n_kids = 0;
+        uint8_t kid_nib[16];
+        uint64_t kid_lo[17];
+        for (uint64_t i = lo; i < hi;) {
             uint8_t nb = nib(i, depth);
             uint64_t j = i;
             while (j < hi && nib(j, depth) == nb) j++;
-            kids[nb] = build(i, j, depth + 1, at_depth + 1);
+            kid_nib[n_kids] = nb;
+            kid_lo[n_kids++] = i;
+            i = j;
+        }
+        kid_lo[n_kids] = hi;
+        if (threads > 1 && hi - lo >= threaded_leaves) {
+            build_children_threaded(n_kids, kid_nib, kid_lo, depth, at_depth, kids);
             if (err) return Ref{};
+        } else {
+            for (int k = 0; k < n_kids; k++) {
+                kids[kid_nib[k]] = build(kid_lo[k], kid_lo[k + 1], depth + 1, at_depth + 1);
+                if (err) return Ref{};
+            }
+        }
+        uint16_t state_mask = 0;
+        bool all_hashed = true;
+        for (int k = 0; k < n_kids; k++) {
+            uint8_t nb = kid_nib[k];
             present[nb] = true;
             state_mask |= uint16_t(1) << nb;
             if (kids[nb].slot == 0) all_hashed = false;
-            i = j;
         }
         Ref r{};
         if (all_hashed) {
@@ -297,6 +411,127 @@ struct Build {
         }
         return r;
     }
+
+    // The children of one branch, each built into a Build of its own (its
+    // own collectors, slots from 1) and then laid into this Build's
+    // collectors where the serial recursion would have put them: child k's
+    // slots after those of the children before it, its rows of every level
+    // after theirs. Offsets come from the children's sizes in nibble order,
+    // never from which thread finished first, and the threads that built
+    // the pieces copy them, so no serial pass over the output is added.
+    // An error is the first failed child's in nibble order, as the serial
+    // recursion stops at it.
+    void build_children_threaded(int n_kids, const uint8_t* kid_nib, const uint64_t* kid_lo,
+                                 int depth, int at_depth, Ref* kids) {
+        std::vector<Build> parts(n_kids);
+        std::vector<Ref> refs(n_kids);
+        each_on_threads(threads, n_kids, [&](int k) {
+            Build& c = parts[k];
+            c.keys = keys;
+            c.values = values;
+            c.val_off = val_off;
+            c.job = job;
+            c.collect_meta = collect_meta;
+            refs[k] = c.build(kid_lo[k], kid_lo[k + 1], depth + 1, at_depth + 1);
+        });
+        for (Build& c : parts)
+            if (c.err) {
+                err = c.err;
+                return;
+            }
+        // what every child shifts by: slots, the records, and per level the
+        // packed rows, bytes and holes and the bitmap rows and children that
+        // this Build holds already plus those of the children before
+        struct Base {
+            size_t rows, bytes, holes, masks, children;
+        };
+        std::vector<int32_t> slot_base(n_kids);
+        std::vector<size_t> meta_base(n_kids);
+        std::vector<Base> base(size_t(n_kids) * (NIBS + 1));
+        size_t n_meta = meta.size();
+        for (int k = 0; k < n_kids; k++) {
+            slot_base[k] = next_slot - 1;
+            next_slot += parts[k].next_slot - 1;
+            meta_base[k] = n_meta;
+            n_meta += parts[k].meta.size();
+        }
+        meta.resize(n_meta);
+        for (int d = at_depth + 1; d <= NIBS; d++) {
+            Level& lv = levels[d];
+            Base at{lv.row_slot.size(), lv.bytes.size(), lv.holes.size(), lv.masks.size(),
+                    lv.children.size()};
+            for (int k = 0; k < n_kids; k++) {
+                const Level& c = parts[k].levels[d];
+                base[size_t(k) * (NIBS + 1) + d] = at;
+                at.rows += c.row_slot.size();
+                at.bytes += c.bytes.size();
+                at.holes += c.holes.size();
+                at.masks += c.masks.size();
+                at.children += c.children.size();
+            }
+            if (at.rows) {
+                if (lv.row_off.empty()) lv.row_off.push_back(0);
+                lv.row_off.resize(at.rows + 1);
+            }
+            lv.row_slot.resize(at.rows);
+            lv.bytes.resize(at.bytes);
+            lv.holes.resize(at.holes);
+            lv.masks.resize(at.masks);
+            lv.bmp_slot.resize(at.masks);
+            lv.children.resize(at.children);
+        }
+        each_on_threads(threads, n_kids, [&](int k) {
+            Build& c = parts[k];
+            const int32_t sb = slot_base[k];
+            for (int d = at_depth + 1; d <= NIBS; d++) {
+                Level& to = levels[d];
+                Level& from = c.levels[d];
+                const Base b = base[size_t(k) * (NIBS + 1) + d];
+                const size_t rows = from.row_slot.size();
+                if (rows) {
+                    memcpy(to.bytes.data() + b.bytes, from.bytes.data(), from.bytes.size());
+                    for (size_t t = 0; t < rows; t++) {
+                        to.row_off[b.rows + t + 1] = from.row_off[t + 1] + uint32_t(b.bytes);
+                        to.row_slot[b.rows + t] = from.row_slot[t] + sb;
+                    }
+                    for (size_t t = 0; t < from.holes.size(); t++) {
+                        const Hole& h = from.holes[t];
+                        to.holes[b.holes + t] = Hole{h.row + int32_t(b.rows), h.off, h.src + sb};
+                    }
+                }
+                const size_t masks = from.masks.size();
+                if (masks) {
+                    memcpy(to.masks.data() + b.masks, from.masks.data(), masks * 2);
+                    for (size_t t = 0; t < masks; t++)
+                        to.bmp_slot[b.masks + t] = from.bmp_slot[t] + sb;
+                    for (size_t t = 0; t < from.children.size(); t++) {
+                        const Child& ch = from.children[t];
+                        to.children[b.children + t] =
+                            Child{ch.row + int32_t(b.masks), ch.nib, ch.src + sb};
+                    }
+                }
+                from = Level{};  // the piece is in place: its memory goes back now
+            }
+            BranchMeta* out = meta.data() + meta_base[k];
+            for (const BranchMeta& m : c.meta) {
+                *out = m;
+                for (int nb = 0; nb < 16; nb++)
+                    if (out->child_slot[nb] >= 0) out->child_slot[nb] += sb;
+                out++;
+            }
+        });
+        for (int k = 0; k < n_kids; k++) {
+            Ref r = refs[k];
+            if (r.slot > 0) {
+                r.slot += slot_base[k];
+            } else {  // an inline child: its bytes where the branch's row reads them
+                const uint8_t* src = parts[k].scratch.data() + r.inline_off;
+                r.inline_off = uint32_t(scratch.size());
+                scratch.insert(scratch.end(), src, src + r.inline_len);
+            }
+            kids[kid_nib[k]] = r;
+        }
+    }
 };
 
 struct Handle {
@@ -304,7 +539,7 @@ struct Handle {
     std::vector<uint32_t> depths;
     std::vector<int32_t> root_slot;      // per job; -1 => inline/empty
     std::vector<std::vector<uint8_t>> root_inline;
-    std::vector<BranchMeta> meta;
+    Vec<BranchMeta> meta;
     int32_t max_slot = 0;
 };
 
@@ -319,9 +554,13 @@ extern "C" {
 // sharing a start_depth-nibble prefix yield exactly the embedded node).
 // Chunked MerkleStage rebuilds commit per-prefix account subtries this
 // way and stitch them as opaque boundaries (reth_tpu/stages/merkle.py).
+// threads, threaded_job_leaves: a job of that many leaves or more builds
+// its first branch's children on `threads` threads; the handle is the
+// serial one byte for byte, and threads <= 1 is the serial sweep.
 void* rtb_build(const uint8_t* keys, uint64_t n_keys, const uint64_t* job_off,
                 uint32_t n_jobs, const uint8_t* values, const uint64_t* val_off,
-                int collect_meta, int start_depth, int* err) {
+                int collect_meta, int start_depth, int threads,
+                uint64_t threaded_job_leaves, int* err) {
     *err = 0;
     if (!keys || !job_off || !values || !val_off || n_jobs == 0 ||
         start_depth < 0 || start_depth >= NIBS) {
@@ -333,6 +572,8 @@ void* rtb_build(const uint8_t* keys, uint64_t n_keys, const uint64_t* job_off,
     b.values = values;
     b.val_off = val_off;
     b.collect_meta = collect_meta != 0;
+    b.threads = threads;
+    b.threaded_leaves = threaded_job_leaves;
     auto h = new Handle();
     for (uint32_t j = 0; j < n_jobs; j++) {
         uint64_t lo = job_off[j], hi = job_off[j + 1];

@@ -8,14 +8,19 @@
 // UBSan fallback where gcc's libtsan breaks on the running kernel).
 //
 // Build + run (tests/test_turbo_pipeline.py::test_triebuild_threaded_stress):
-//   g++ -std=c++17 -O1 -g -fsanitize=thread triebuild.cpp \
+//   g++ -std=c++17 -O1 -g -fsanitize=thread -pthread triebuild.cpp \
 //       triebuild_tsan.cpp -o build/triebuild_stress && ./build/triebuild_stress
 //
 // Workload: N threads × R rounds. Odd threads sweep a PRIVATE key set;
 // even threads all sweep the SAME shared arrays concurrently (the
-// pipeline's job-list sharing). Two failure modes: (a) memory/race errors
-// under the sanitizer, (b) nondeterminism — any round whose level count,
-// max slot, or packed byte total differs from round 0 (exit 2).
+// pipeline's job-list sharing). Every call asks for the THREADED sweep
+// (threads 4, a threshold under the key count): threads inside threads,
+// as when a pool thread meets a large job while other groups sweep. Two
+// failure modes: (a) memory/race errors under the sanitizer, (b)
+// nondeterminism — any round whose arrays (every level's packed bytes,
+// row offsets, slots, holes, masks, children; the roots; the branch
+// records) hash differently from round 0, or from the one-thread sweep of
+// the same input (exit 2).
 
 #include <atomic>
 #include <cstdint>
@@ -28,13 +33,28 @@
 extern "C" {
 void* rtb_build(const uint8_t* keys, uint64_t n_keys, const uint64_t* job_off,
                 uint32_t n_jobs, const uint8_t* values, const uint64_t* val_off,
-                int collect_meta, int start_depth, int* err);
+                int collect_meta, int start_depth, int threads,
+                uint64_t threaded_job_leaves, int* err);
 void rtb_free(void* h);
 int32_t rtb_num_levels(void* h);
 int32_t rtb_max_slot(void* h);
+uint32_t rtb_level_depth(void* h, int32_t i);
 uint64_t rtb_packed_bytes(void* h, int32_t i);
+uint32_t rtb_packed_rows(void* h, int32_t i);
+uint32_t rtb_packed_holes(void* h, int32_t i);
+void rtb_packed_get(void* h, int32_t i, uint8_t* bytes, uint32_t* rowoff, int32_t* slots);
+void rtb_packed_get_holes(void* h, int32_t i, int32_t* row, int32_t* off, int32_t* src);
+uint32_t rtb_bmp_rows(void* h, int32_t i);
+uint32_t rtb_bmp_children(void* h, int32_t i);
+void rtb_bmp_get(void* h, int32_t i, uint16_t* masks, int32_t* slots);
+void rtb_bmp_get_children(void* h, int32_t i, int32_t* row, int32_t* nb, int32_t* src);
+void rtb_roots(void* h, int32_t* out);
 uint64_t rtb_meta_count(void* h);
+void rtb_meta_get(void* h, uint8_t* out);
 }
+
+constexpr int kSweepThreads = 4;
+constexpr uint64_t kThreadedLeaves = 64;  // under every input's key count
 
 static std::atomic<bool> failed{false};
 static std::atomic<long> builds{0};
@@ -67,30 +87,78 @@ static Input make_input(uint64_t seed, int n) {
     return in;
 }
 
+// FNV-1a over every array the getters hand out, sizes included: two handles
+// with one fingerprint hold the same arrays.
+struct Fingerprint {
+    uint64_t h = 1469598103934665603ULL;
+    void bytes(const void* p, size_t n) {
+        const uint8_t* b = static_cast<const uint8_t*>(p);
+        for (size_t i = 0; i < n; i++) h = (h ^ b[i]) * 1099511628211ULL;
+    }
+    template <class T> void vec(const std::vector<T>& v) {
+        uint64_t n = v.size();
+        bytes(&n, 8);
+        bytes(v.data(), v.size() * sizeof(T));
+    }
+};
+
+static uint64_t fingerprint(void* h) {
+    Fingerprint f;
+    int32_t levels = rtb_num_levels(h), slot = rtb_max_slot(h);
+    f.bytes(&levels, 4);
+    f.bytes(&slot, 4);
+    for (int32_t i = 0; i < levels; i++) {
+        uint32_t depth = rtb_level_depth(h, i);
+        f.bytes(&depth, 4);
+        uint32_t rows = rtb_packed_rows(h, i), holes = rtb_packed_holes(h, i);
+        std::vector<uint8_t> flat(rtb_packed_bytes(h, i));
+        std::vector<uint32_t> row_off(rows ? rows + 1 : 0);
+        std::vector<int32_t> row_slot(rows);
+        if (rows) rtb_packed_get(h, i, flat.data(), row_off.data(), row_slot.data());
+        std::vector<int32_t> hr(holes), ho(holes), hs(holes);
+        if (holes) rtb_packed_get_holes(h, i, hr.data(), ho.data(), hs.data());
+        uint32_t bmp = rtb_bmp_rows(h, i), kids = rtb_bmp_children(h, i);
+        std::vector<uint16_t> masks(bmp);
+        std::vector<int32_t> bmp_slot(bmp), cr(kids), cn(kids), cs(kids);
+        if (bmp) rtb_bmp_get(h, i, masks.data(), bmp_slot.data());
+        if (kids) rtb_bmp_get_children(h, i, cr.data(), cn.data(), cs.data());
+        f.vec(flat); f.vec(row_off); f.vec(row_slot);
+        f.vec(hr); f.vec(ho); f.vec(hs);
+        f.vec(masks); f.vec(bmp_slot); f.vec(cr); f.vec(cn); f.vec(cs);
+    }
+    int32_t root = 0;
+    rtb_roots(h, &root);  // one job a build here
+    f.bytes(&root, 4);
+    std::vector<uint8_t> meta(rtb_meta_count(h) * 80);
+    if (!meta.empty()) rtb_meta_get(h, meta.data());
+    f.vec(meta);
+    return f.h;
+}
+
+static bool sweep(const Input* in, int collect, int threads, uint64_t* out) {
+    int err = 0;
+    void* h = rtb_build(in->keys.data(), in->job_off[1], in->job_off.data(),
+                        1, in->values.data(), in->val_off.data(),
+                        collect, 0, threads, kThreadedLeaves, &err);
+    if (!h || err) {
+        std::fprintf(stderr, "build failed err=%d\n", err);
+        failed.store(true);
+        return false;
+    }
+    *out = fingerprint(h);
+    rtb_free(h);
+    return true;
+}
+
 static void worker(const Input* in, int rounds, int collect) {
-    int64_t want_levels = -1, want_slot = -1;
-    uint64_t want_bytes = 0;
+    uint64_t serial = 0, got = 0;
+    if (!sweep(in, collect, 1, &serial)) return;
     for (int r = 0; r < rounds && !failed.load(); r++) {
-        int err = 0;
-        void* h = rtb_build(in->keys.data(), in->job_off[1], in->job_off.data(),
-                            1, in->values.data(), in->val_off.data(),
-                            collect, 0, &err);
-        if (!h || err) {
-            std::fprintf(stderr, "build failed err=%d\n", err);
-            failed.store(true);
-            return;
-        }
-        int32_t levels = rtb_num_levels(h);
-        int32_t slot = rtb_max_slot(h);
-        uint64_t bytes = 0;
-        for (int32_t i = 0; i < levels; i++) bytes += rtb_packed_bytes(h, i);
-        if (collect) bytes += rtb_meta_count(h);
-        rtb_free(h);
-        if (r == 0) {
-            want_levels = levels; want_slot = slot; want_bytes = bytes;
-        } else if (levels != want_levels || slot != want_slot ||
-                   bytes != want_bytes) {
-            std::fprintf(stderr, "NONDETERMINISM: round %d differs\n", r);
+        if (!sweep(in, collect, kSweepThreads, &got)) return;
+        // every round is held to the one-thread sweep, and so to round 0
+        if (got != serial) {
+            std::fprintf(stderr, "NONDETERMINISM: round %d differs from the "
+                                 "one-thread sweep\n", r);
             failed.store(true);
             return;
         }

@@ -407,7 +407,16 @@ class PipelineMetrics:
         self._largest = reg.counter(
             "trie_pipeline_largest_group_leaves_total",
             "leaves of each commit's largest sweep group: over the leaves of "
-            "all, the share of a chunk that one thread sweeps alone")
+            "all, the share of a chunk that one thread marshals and extracts "
+            "alone (its large jobs are swept on several)")
+        self._threaded_jobs = reg.counter(
+            "trie_sweep_threaded_jobs_total",
+            "jobs of SWEEP_THREADS * LEAVES_PER_SWEEP leaves or more: swept "
+            "on SWEEP_THREADS threads inside the native call")
+        self._threaded_leaves = reg.counter(
+            "trie_sweep_threaded_leaves_total",
+            "leaves of those jobs: over trie_pipeline_leaves_total, the share "
+            "of the leaves laid out that several threads sweep")
         self._drains = reg.counter(
             "trie_pipeline_queue_drains_total",
             "windows hashed on the CPU twin after a mid-rebuild failover")
@@ -424,6 +433,10 @@ class PipelineMetrics:
 
     def set_pool_busy(self, n: int) -> None:
         self._busy.set(n)
+
+    def record_threaded_sweeps(self, jobs: int, leaves: int) -> None:
+        self._threaded_jobs.increment(jobs)
+        self._threaded_leaves.increment(leaves)
 
     def record_run(self, *, jobs: int, groups: int, leaves: int,
                    largest_group_leaves: int, windows: int,

@@ -12,7 +12,10 @@ Python, on ONE path (``TurboCommitter.commit_hashed_pipelined``;
            templates/masks, flat per-level arrays, in place of
            trie/committer.py's per-node recursion). Several groups: on a
            thread pool, side by side. ONE group (a chunk of one subtrie, a
-           job list under LEAVES_PER_SWEEP leaves): by the caller, no thread
+           job list under LEAVES_PER_SWEEP leaves): by the caller. A job of
+           SWEEP_THREADS * LEAVES_PER_SWEEP leaves or more: its first
+           branch's children on SWEEP_THREADS threads inside the native
+           call, into the arrays one thread would have made
             └─ _pack_window: same-depth levels of a window's groups merged
                (one group's pass through uncopied); per level, deepest first:
                PACKED rows  → backend.dispatch_packed   (device)
@@ -73,14 +76,16 @@ def load_library() -> ctypes.CDLL:
             return _lib
         if not _SO.exists() or _SO.stat().st_mtime < _SRC.stat().st_mtime:
             _SO.parent.mkdir(parents=True, exist_ok=True)
-            cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", str(_SRC), "-o", str(_SO)]
+            cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
+                   str(_SRC), "-o", str(_SO)]
             proc = subprocess.run(cmd, capture_output=True, text=True)
             if proc.returncode != 0:
                 raise RuntimeError(f"g++ failed building triebuild:\n{proc.stderr}")
         lib = ctypes.CDLL(str(_SO))
         lib.rtb_build.restype = ctypes.c_void_p
         lib.rtb_build.argtypes = [_u8p, ctypes.c_uint64, _u64p, ctypes.c_uint32,
-                                  _u8p, _u64p, ctypes.c_int, ctypes.c_int, _i32p]
+                                  _u8p, _u64p, ctypes.c_int, ctypes.c_int,
+                                  ctypes.c_int, ctypes.c_uint64, _i32p]
         lib.rtb_free.argtypes = [ctypes.c_void_p]
         for name, res in [("rtb_num_levels", ctypes.c_int32),
                           ("rtb_max_slot", ctypes.c_int32)]:
@@ -382,11 +387,14 @@ def _marshal_group(jobs):
 def _marshal_and_build(lib, jobs, collect_branches: bool, start_depth: int):
     """Sort each job's keys, flatten values, and run the native structure
     sweep: one job by its own sort (``_marshal_one``), several in one piece
-    (``_marshal_group``). Returns (handle, the group's sorted keys, job
-    after job in one array); the caller owns the handle (``rtb_free``).
+    (``_marshal_group``). A job of ``SWEEP_THREADS * LEAVES_PER_SWEEP``
+    leaves or more is swept on ``SWEEP_THREADS`` threads inside the native
+    call, into the arrays one thread would have made. Returns (handle, the
+    group's sorted keys, job after job in one array, the number of jobs
+    swept so and their leaves); the caller owns the handle (``rtb_free``).
     Raises ``ValueError`` on sweep rejection — exactly the condition the
     MerkleStage uses to fall back to the general committer."""
-    from ..metrics import trie_metrics
+    from ..metrics import pipeline_metrics, trie_metrics
 
     with trie_metrics.phase("marshal"):
         if len(jobs) == 1:
@@ -404,19 +412,27 @@ def _marshal_and_build(lib, jobs, collect_branches: bool, start_depth: int):
                             count=len(val_chunks))
             )
         vals_np = np.frombuffer(flat_vals, dtype=np.uint8) if flat_vals else np.zeros(1, np.uint8)
+    # read at call time: the layout's constants are what a test moves
+    threads, threaded_job_leaves = SWEEP_THREADS, SWEEP_THREADS * LEAVES_PER_SWEEP
     err = ctypes.c_int32(0)
     with trie_metrics.phase("sweep"):
         h = lib.rtb_build(
             _ptr(all_keys, _u8p), len(all_keys),
             _ptr(job_off, _u64p), len(jobs),
             _ptr(vals_np, _u8p), _ptr(val_off, _u64p),
-            1 if collect_branches else 0, start_depth, ctypes.byref(err),
+            1 if collect_branches else 0, start_depth,
+            threads, threaded_job_leaves, ctypes.byref(err),
         )
     if not h:
         reason = {1: "unsorted", 2: "duplicate keys", 3: "bad input",
                   4: "oversized leaf value"}.get(err.value, "unknown")
         raise ValueError(f"triebuild failed (err={err.value}: {reason})")
-    return h, all_keys
+    # the native side's own comparison, on the same two numbers
+    counts = np.asarray(counts, dtype=np.int64)
+    threaded = counts[counts >= threaded_job_leaves] if threads > 1 else counts[:0]
+    threaded_jobs, threaded_leaves = len(threaded), int(threaded.sum())
+    pipeline_metrics.record_threaded_sweeps(threaded_jobs, threaded_leaves)
+    return h, all_keys, threaded_jobs, threaded_leaves
 
 
 # -- the rebuild pipeline: every commit's path ------------------------------
@@ -430,10 +446,12 @@ class _SweepResult:
 
     __slots__ = ("job_ids", "keys", "levels", "root_slots",
                  "root_inlines", "meta_rec", "max_slot", "n_levels",
-                 "wire_bytes", "hashed_nodes", "sweep_s")
+                 "wire_bytes", "hashed_nodes", "sweep_s", "threaded_jobs",
+                 "threaded_leaves")
 
     def __init__(self, job_ids, keys, levels, root_slots, root_inlines,
-                 meta_rec, max_slot, wire_bytes, sweep_s):
+                 meta_rec, max_slot, wire_bytes, sweep_s, threaded_jobs,
+                 threaded_leaves):
         self.job_ids = job_ids
         self.keys = keys  # the group's sorted keys, job after job
         self.levels = levels
@@ -445,6 +463,9 @@ class _SweepResult:
         self.wire_bytes = wire_bytes
         self.hashed_nodes = sum(len(lv.row_slot) + len(lv.masks) for lv in levels)
         self.sweep_s = sweep_s
+        # the group's jobs that the native call swept on several threads
+        self.threaded_jobs = threaded_jobs
+        self.threaded_leaves = threaded_leaves
 
 
 def _sweep_group(lib, jobs, job_ids, collect_branches, start_depth) -> _SweepResult:
@@ -453,7 +474,8 @@ def _sweep_group(lib, jobs, job_ids, collect_branches, start_depth) -> _SweepRes
     from ..metrics import trie_metrics
 
     t0 = time.perf_counter()
-    h, keys = _marshal_and_build(lib, jobs, collect_branches, start_depth)
+    h, keys, threaded_jobs, threaded_leaves = _marshal_and_build(
+        lib, jobs, collect_branches, start_depth)
     try:
         n_levels = lib.rtb_num_levels(h)
         # one "stage" a group: the levels and the roots out of the handle
@@ -484,7 +506,8 @@ def _sweep_group(lib, jobs, job_ids, collect_branches, start_depth) -> _SweepRes
                      + lv.masks.nbytes + lv.children.nbytes for lv in levels)
     return _SweepResult(job_ids, keys, levels, root_slots, root_inlines,
                         meta_rec, max_slot, wire_bytes,
-                        time.perf_counter() - t0)
+                        time.perf_counter() - t0, threaded_jobs,
+                        threaded_leaves)
 
 
 class _MergedLevel:
@@ -582,7 +605,14 @@ def _pack_window(parts: list[tuple[int, _SweepResult]]) -> list[_MergedLevel]:
 # than four only contend for the interpreter; never fewer than two, so one
 # group is swept while the consumer packs another. 2 * SWEEP_THREADS sweeps
 # are submitted ahead of the consumer (a result parked behind every running
-# sweep; no more host arrays alive than that).
+# sweep; no more host arrays alive than that). The same number of threads
+# sweeps ONE job inside rtb_build where the job holds what that many groups
+# would, SWEEP_THREADS * LEAVES_PER_SWEEP leaves (the children of its first
+# branch side by side, laid into the arrays of the one-thread sweep byte for
+# byte: native/triebuild.cpp). A job is never cut into groups, so without
+# that a chunk of one large trie waited on one thread. A pool thread that
+# meets such a job starts SWEEP_THREADS - 1 more while the others sweep:
+# at most 2 * SWEEP_THREADS - 1 native threads, none holding the interpreter
 SWEEP_THREADS = max(2, min(4, os.cpu_count() or 1))
 # consecutive groups a window: same-depth rows of the window's tries share
 # a dispatch. A stage chunk of 500,000 leaves is at most 16 groups, so ONE
@@ -626,7 +656,13 @@ class RebuildPipeline:
     large subtrie, any job list under ``LEAVES_PER_SWEEP`` leaves) is swept
     by the calling thread; several by a small thread pool
     (``native/triebuild.cpp``; the ctypes call releases the GIL), side by
-    side and ahead of the consumer, which takes the results in SUBMISSION
+    side and ahead of the consumer. A job is never cut, so a group may be
+    one trie of millions of leaves: the native call sweeps a job of
+    ``SWEEP_THREADS * LEAVES_PER_SWEEP`` leaves or more on ``SWEEP_THREADS``
+    threads of its own, the children of its first branch side by side, and
+    hands back the arrays of the one-thread sweep byte for byte (the
+    group's marshalling and the extraction of its levels stay on the one
+    thread). The consumer takes the results in SUBMISSION
     order, however the threads finish, and packs same-depth levels of a
     window's groups into fused dispatches (``_pack_window``) against the
     resident digest arena. A window of one group is that group's own
@@ -709,7 +745,8 @@ class RebuildPipeline:
         t_wall = time.perf_counter()
         met = pipeline_metrics
         groups = _group_jobs(jobs, LEAVES_PER_SWEEP)
-        # a job is never cut, so the largest group is what ONE thread sweeps
+        # a job is never cut, so the largest group is what ONE thread
+        # marshals and extracts (its large jobs are swept on several)
         group_leaves = [sum(len(values) for _, values in jobs[lo:hi])
                         for lo, hi in groups]
         leaves, largest = sum(group_leaves), max(group_leaves)
@@ -802,6 +839,10 @@ class RebuildPipeline:
                 ctx=trace_ctx,
                 fields={"jobs": len(jobs), "windows": self.windows,
                         "leaves": leaves, "largest_group_leaves": largest,
+                        "threaded_jobs": sum(
+                            sw.threaded_jobs for _, sw in swept),
+                        "threaded_leaves": sum(
+                            sw.threaded_leaves for _, sw in swept),
                         **{k: round(v, 4) for k, v in stages.items()}})
 
     def _collect(self, swept, n_jobs, collect_branches, start_depth, stages):
@@ -965,7 +1006,9 @@ class TurboCommitter:
         (root + optional BranchNode TrieUpdates, paths subtrie-relative).
 
         Groups of jobs are swept side by side on a thread pool (one group:
-        by the caller, no thread), same-depth levels packed across them
+        by the caller; a job of ``SWEEP_THREADS * LEAVES_PER_SWEEP`` leaves
+        or more: on threads inside the native call, to the same arrays),
+        same-depth levels packed across them
         into fused dispatches, and hashed into the resident digest arena.
         The windows, the arena's tier and every program shape follow from
         the job list alone, never from which sweep thread finished first.

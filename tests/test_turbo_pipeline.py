@@ -407,15 +407,17 @@ def _probe_tsan(tmp: Path) -> bool:
 
 @pytest.mark.slow
 def test_triebuild_threaded_stress(tmp_path):
-    """The pipeline calls rtb_build from a thread pool: run the real access
-    pattern (shared read-only arrays, concurrent handles) under TSAN
-    (ASan+UBSan where libtsan breaks on the running kernel) and require
-    deterministic per-round results — native/triebuild_tsan.cpp."""
+    """The pipeline calls rtb_build from a thread pool, and rtb_build
+    sweeps a large job on threads of its own: run the real access pattern
+    (shared read-only arrays, concurrent handles, threads inside threads)
+    under TSAN (ASan+UBSan where libtsan breaks on the running kernel) and
+    require every round's arrays to be the one-thread sweep's —
+    native/triebuild_tsan.cpp."""
     use_tsan = _probe_tsan(tmp_path)
     san = "thread" if use_tsan else "address,undefined"
     exe = tmp_path / "triebuild_stress"
     r = subprocess.run(
-        ["g++", "-std=c++17", "-O1", "-g", f"-fsanitize={san}",
+        ["g++", "-std=c++17", "-O1", "-g", f"-fsanitize={san}", "-pthread",
          str(NATIVE / "triebuild.cpp"), str(NATIVE / "triebuild_tsan.cpp"),
          "-o", str(exe)],
         capture_output=True, text=True, timeout=300)
