@@ -317,17 +317,22 @@ class TrieMetrics:
     host seconds of each phase of a turbo commit (:meth:`phase`)."""
 
     # a turbo commit's phases in the order a commit runs them (trie/turbo.py
-    # marshal..stage and decode; ops/fused_commit.py assemble..fetch).
+    # marshal..stage, predecode and collect..decode; ops/fused_commit.py
+    # assemble..enqueue and device_wait..fetch).
     # "pack" is the pipelined path's alone (RebuildPipeline: one window's
     # levels merged across subtries); there marshal, sweep and the levels'
     # extraction run on the sweep pool and their counters hold
     # thread-seconds. A backend that hashes as it is fed (the numpy twin,
-    # the per-level engines) does so inside "stage". "collect" is the loop
-    # that builds one result a job after the digests are back: nothing for
-    # a chunk of one subtrie, a phase of its own for a storage chunk of
-    # tens of thousands of tries.
+    # the per-level engines) does so inside "stage". With branch nodes
+    # collected, "predecode" is what needs no digest: one result a job (a
+    # phase's worth for a storage chunk of tens of thousands of tries) and
+    # the first half of the branch decode, run after the backend's
+    # ``launch`` and before its wait; then "collect" lays the roots in from
+    # the fetched arena and "decode" the child hashes. Roots alone:
+    # "collect" builds the results after the fetch.
     PHASES = ("marshal", "sweep", "pack", "stage", "assemble", "upload",
-              "enqueue", "device_wait", "fetch", "collect", "decode")
+              "enqueue", "predecode", "device_wait", "fetch", "collect",
+              "decode")
 
     def __init__(self, registry: MetricsRegistry | None = None):
         reg = registry or REGISTRY
@@ -338,6 +343,10 @@ class TrieMetrics:
             "CPU seconds of the threads inside marshal: over its wall, the "
             "share not spent waiting for the interpreter or the allocator")
         self._decode_records = reg.counter("trie_commit_decode_records_total")
+        self._predecode_records = reg.counter(
+            "trie_commit_predecode_records_total",
+            "branch records whose digest-free half of the decode ran before "
+            "the wait for the device")
         self._nodes = {k: reg.counter(f"trie_commit_nodes_total_{k}")
                        for k in ("device", "numpy")}
         self._leaves = reg.counter("trie_commit_leaves_total")
@@ -366,6 +375,11 @@ class TrieMetrics:
         ``trie_commit_decode_seconds_total`` over this is seconds a
         record, whatever the chunk size."""
         self._decode_records.increment(records)
+
+    def record_predecode(self, records: int) -> None:
+        """Branch records whose paths, masks and digest rows one call of
+        the decode's first half made, before the wait for the device."""
+        self._predecode_records.increment(records)
 
     def phase_seconds(self) -> dict[str, float]:
         """Every phase's counter as it stands: a run's phases are the

@@ -224,8 +224,10 @@ class NodeEventReporter:
         # the last rebuild commit's phases: during a chunked Merkle rebuild
         # this is the line that says where the time goes (the pool's
         # marshal+sweep thread-seconds, the consumer's wait for them, the
-        # host feeding the device, the exposed device block, the branch
-        # decode, and the collector's passes inside all of them)
+        # host feeding the device, the branch decode's digest-free half
+        # (under the device block where the backend has a ``launch``), the
+        # exposed device block, the decode's second half, and the
+        # collector's passes inside all of them)
         from ..metrics import pipeline_metrics
 
         pm = pipeline_metrics.last
@@ -237,6 +239,7 @@ class NodeEventReporter:
                      f" wait={pm['wait_s']}s"
                      f" marshal+sweep={ph['marshal'] + ph['sweep']:.4f}s"
                      f" stage..enqueue={feed:.4f}s"
+                     f" predecode={ph['predecode']}s"
                      f" device_wait={ph['device_wait']}s"
                      f" decode={ph['decode']}s gc={pm['gc_s']}s]")
             if pm["drained_windows"]:

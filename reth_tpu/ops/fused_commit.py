@@ -1010,6 +1010,13 @@ class MegaFusedEngine(FusedLevelEngine):
         self._buf = buf
         self._plan, self._u8_parts, self._i32_parts = [], [], []
 
+    def launch(self) -> None:
+        """Start what is staged: assemble, upload and enqueue every level
+        program, all asynchronous, and return without waiting, so the host
+        can work while the device hashes until ``finish()`` waits. Idempotent;
+        ``finish`` and ``fetch_slots`` start it themselves."""
+        self._execute()
+
     def finish(self) -> np.ndarray:
         self._execute()
         return super().finish()

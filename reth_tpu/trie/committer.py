@@ -23,7 +23,9 @@ branch node with its state/tree/hash masks and child hashes
 
 from __future__ import annotations
 
+from collections import deque
 from dataclasses import dataclass, field
+from itertools import repeat
 
 from ..primitives.keccak import RATE, keccak256
 from ..primitives.nibbles import Nibbles, common_prefix_len, encode_path
@@ -74,7 +76,7 @@ class _Node:
     opaque_branch: bool = True      # OPAQUE: subtree contains stored branches
 
 
-@dataclass(frozen=True)
+@dataclass(frozen=True, slots=True)
 class BranchNode:
     """Stored branch node (reference `BranchNodeCompact`)."""
 
@@ -88,6 +90,28 @@ class BranchNode:
             return None
         idx = bin(self.hash_mask & ((1 << nibble) - 1)).count("1")
         return self.hashes[idx]
+
+
+def branch_nodes_hashed_later(state_masks, tree_masks, hash_masks):
+    """BranchNodes whose masks are known before their child hashes (the
+    turbo commit's decode: the masks come from the sweep, the hashes from
+    the device). Makes one node for each mask triple now, without its
+    hashes, and returns the only way to reach them: ``lay_in(hashes)``,
+    which sets each node's ``hashes`` from an iterable of one tuple a node,
+    in order, and returns the nodes. So no node is seen before it is whole,
+    and each is then the frozen value ``BranchNode(...)`` would have made.
+    The fields are set by C-level maps: no Python frame a node."""
+    set_field = object.__setattr__
+    nodes = list(map(BranchNode.__new__, repeat(BranchNode, len(state_masks))))
+    for name, values in (("state_mask", state_masks), ("tree_mask", tree_masks),
+                         ("hash_mask", hash_masks)):
+        deque(map(set_field, nodes, repeat(name), values), 0)
+
+    def lay_in(hashes) -> list[BranchNode]:
+        deque(map(set_field, nodes, repeat("hashes"), hashes), 0)
+        return nodes
+
+    return lay_in
 
 
 @dataclass

@@ -23,7 +23,9 @@ Python, on ONE path (``TurboCommitter.commit_hashed_pipelined``;
                ... or the numpy twin (`_NumpyBackend`), the measured CPU
                baseline and the no-jax fallback
                 └─ ONE digest fetch: roots (+ branch-node hashes when
-                   TrieUpdates collection is requested)
+                   TrieUpdates collection is requested: what of the
+                   results needs no digest is built between the programs'
+                   launch and the wait)
 
 Reference analogue: StateRoot's cursor walk + HashBuilder + asm-keccak
 (reference crates/trie/trie/src/trie.rs:32, crates/stages/stages/src/
@@ -41,7 +43,8 @@ import threading
 import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
-from itertools import chain, islice
+from contextlib import contextmanager
+from itertools import chain, islice, repeat
 from pathlib import Path
 
 import numpy as np
@@ -53,7 +56,7 @@ from ..primitives.keccak import (
     keccak256_words_masked_np,
 )
 from ..primitives.types import EMPTY_ROOT_HASH
-from .committer import BranchNode, TrieBuildResult
+from .committer import TrieBuildResult, branch_nodes_hashed_later
 
 _SRC = Path(__file__).resolve().parent.parent.parent / "native" / "triebuild.cpp"
 _SO = _SRC.parent / "build" / "libtriebuild.so"
@@ -680,10 +683,11 @@ class RebuildPipeline:
     window k+1; a job list of one window (under ``PACK_WINDOW`` *
     ``LEAVES_PER_SWEEP`` leaves, whatever its number of tries) is hashed
     there after its last sweep. ``MegaFusedEngine`` (the single-chip
-    default) only STAGES what it is fed and runs every level program in
-    ``finish()``: on it the sweeps overlap one another and the packing and
-    staging of earlier windows, and with one group nothing overlaps
-    anything.
+    default) only STAGES what it is fed and starts every level program in
+    its ``launch()``: on it the sweeps overlap one another and the packing
+    and staging of earlier windows, and the device's levels overlap the
+    half of the results and the branch decode that needs no digest
+    (``_collect``), whatever the number of groups.
 
     Fault surface: a supervised backend ("auto") fails over mid-commit to
     the numpy twin via its journal; the pipeline keeps feeding it, which
@@ -700,6 +704,7 @@ class RebuildPipeline:
         self.queue_peak = 0
         self.wire_bytes = 0
         self.wait_s = 0.0
+        self.overlapped = False  # the backend had a `launch`: see _collect
 
     def _sweeps(self, groups, sweep):
         """Each group's ``sweep(lo, hi)``, in the groups' order. One group:
@@ -844,51 +849,94 @@ class RebuildPipeline:
                         "threaded_leaves": sum(
                             sw.threaded_leaves for _, sw in swept),
                         "wait": round(self.wait_s, 4),
+                        "predecode_s": round(phases["predecode"], 4),
+                        "overlapped": self.overlapped,
                         "gc_s": round(gc_s, 4),
                         "gc_full_s": round(gc_full_s, 4)})
 
     def _collect(self, swept, n_jobs, collect_branches, start_depth):
+        """One result a job. Roots alone: the roots' slots fetched, then the
+        results built. With branch nodes: the backend's ``launch`` (an
+        engine that stages the commit starts its level programs), then the
+        half that needs no digest (``predecode``: the results, inline roots
+        and each group's first half of the decode) while the device hashes,
+        then ``finish()``'s wait and fetch, then the roots (``collect``) and
+        the child hashes (``decode``) laid in. A backend without ``launch``
+        runs the same halves in the same order; its ``finish`` starts what
+        it has not. Results are handed out only once both halves ran."""
         from ..metrics import trie_metrics
 
         backend = self.backend
-        results: list = [None] * n_jobs
-        if collect_branches:
-            digests = backend.finish()
-            roots_raw = None
-        else:
-            digests = None
-            flat_slots = np.concatenate([
-                np.where(sw.root_slots > 0, sw.root_slots + base, 0)
-                for base, sw in swept])
-            roots_raw = backend.fetch_slots(flat_slots)
-        cursor = 0
-        total_hashed = 0
-        # one result a job: a storage chunk holds tens of thousands
+        rows = np.concatenate([sw.root_slots[sw.root_slots > 0] + base
+                               for base, sw in swept])
+        if not collect_branches:
+            roots = backend.fetch_slots(rows)
+            with trie_metrics.phase("collect"):
+                results, hashed = _job_results(swept, n_jobs)
+                _lay_in_roots(hashed, roots)
+            return results
+        launch = getattr(backend, "launch", None)
+        self.overlapped = launch is not None
+        if launch is not None:
+            launch()
+        with trie_metrics.phase("predecode"), _collector_held_off():
+            results, hashed = _job_results(swept, n_jobs)
+            pending = [
+                _collect_meta_records(sw.meta_rec, sw.keys,
+                                      [results[j] for j in sw.job_ids],
+                                      start_depth, slot_base=base)
+                for base, sw in swept if len(sw.meta_rec)]
+        digests = backend.finish()
         with trie_metrics.phase("collect"):
-            for base, sw in swept:
-                total_hashed += sw.hashed_nodes
-                for k, j in enumerate(sw.job_ids):
-                    slot = int(sw.root_slots[k])
-                    if slot > 0:
-                        root = (digests[base + slot] if digests is not None
-                                else roots_raw[cursor + k]).tobytes()
-                    else:
-                        inline = sw.root_inlines[k]
-                        root = keccak256(inline) if inline else EMPTY_ROOT_HASH
-                    results[j] = TrieBuildResult(root=root, levels=sw.n_levels)
-                cursor += len(sw.job_ids)
-        if results:
-            results[-1].hashed_nodes = total_hashed
-        if collect_branches:
-            with trie_metrics.phase("decode"):
-                for base, sw in swept:
-                    if sw.meta_rec is None or not len(sw.meta_rec):
-                        continue
-                    group_results = [results[j] for j in sw.job_ids]
-                    _collect_meta_records(sw.meta_rec, sw.keys,
-                                          digests, group_results,
-                                          start_depth, slot_base=base)
+            _lay_in_roots(hashed, digests[rows])
+        with trie_metrics.phase("decode"), _collector_held_off():
+            for branches in pending:
+                branches.lay_in(digests)
         return results
+
+
+@contextmanager
+def _collector_held_off():
+    """The collector off over a block that makes objects by the million:
+    a decode's nodes, tuples and paths hold no cycle, and its passes over
+    them, were it left on, would add a third to the block's time."""
+    was_enabled = gc.isenabled()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if was_enabled:
+            gc.enable()
+
+
+def _job_results(swept, n_jobs):
+    """One result a job, in job order, the roots of inline tries set (their
+    ``keccak256``, or the empty root); returns the results and, in the
+    order of the swept groups' hashed root slots, the results whose root
+    is a digest of the arena still to come."""
+    results: list = [None] * n_jobs
+    hashed: list = []
+    total_hashed = 0
+    for _, sw in swept:
+        total_hashed += sw.hashed_nodes
+        levels = sw.n_levels
+        for j, slot, inline in zip(sw.job_ids, sw.root_slots.tolist(),
+                                   sw.root_inlines):
+            if slot > 0:
+                results[j] = res = TrieBuildResult(root=b"", levels=levels)
+                hashed.append(res)
+            else:
+                results[j] = TrieBuildResult(
+                    root=keccak256(inline) if inline else EMPTY_ROOT_HASH,
+                    levels=levels)
+    results[-1].hashed_nodes = total_hashed
+    return results, hashed
+
+
+def _lay_in_roots(hashed, roots: np.ndarray) -> None:
+    """The fetched root digests, row after row, into their results."""
+    deque(map(setattr, hashed, repeat("root"),
+              roots.view("V32").ravel().tolist()), 0)
 
 
 class TurboCommitter:
@@ -1015,7 +1063,8 @@ class TurboCommitter:
         Hashing overlaps the sweeps on the numpy twin, the per-level engines
         and the whole-subtrie engines; the single-chip default,
         ``MegaFusedEngine``, stages the windows and starts the device in
-        ``finish()``. A sweep's rejection is a ``ValueError``, the condition
+        ``launch()``, and the half of the decode that needs no digest runs
+        while it hashes. A sweep's rejection is a ``ValueError``, the condition
         on which the MerkleStage falls back to the general committer."""
         if not jobs:
             return []
@@ -1061,22 +1110,60 @@ _META_REC = np.dtype([
 ])
 
 
-def _collect_meta_records(meta_rec, keys, digests, results,
-                          start_depth=0, slot_base=0):
-    """Decode native BranchMeta records into per-job TrieUpdates, every
-    record of the call at once: the fields, the path nibbles and the child
-    hashes are gathered by numpy into Python lists and two blobs, and the
-    one loop over records only slices those and builds the objects.
+class _PendingBranches:
+    """One sweep group's branch records with the half of their decode that
+    needs no digest done: the records' runs of one job, their paths, their
+    nodes made without hashes, and the arena rows of their child hashes.
+    ``lay_in`` is the other half."""
+
+    __slots__ = ("results", "runs", "paths", "rows", "n_hashed",
+                 "nodes_later")
+
+    def __init__(self, results, runs=(), paths=(), rows=None, n_hashed=(),
+                 nodes_later=None):
+        self.results, self.runs, self.paths = results, runs, paths
+        self.rows, self.n_hashed = rows, n_hashed
+        self.nodes_later = nodes_later
+
+    def lay_in(self, digests):
+        """The decode's second half, once ``digests`` holds the arena: ONE
+        gather of the child digests, a tuple of them a node, the nodes
+        finished, and each run of records put into its job's
+        ``branch_nodes`` in record order; C-level maps, so no Python frame
+        a record (a loop step a run). Returns the results."""
+        from ..metrics import trie_metrics
+
+        if self.nodes_later is None:
+            return self.results
+        trie_metrics.record_decode(len(self.paths))
+        child_hashes = iter(digests[self.rows].view("V32").ravel().tolist())
+        nodes = iter(self.nodes_later(
+            map(tuple, map(islice, repeat(child_hashes), self.n_hashed))))
+        paths, results = iter(self.paths), self.results
+        for j, k in self.runs:
+            results[j].branch_nodes.update(zip(islice(paths, k),
+                                               islice(nodes, k)))
+        return results
+
+
+def _collect_meta_records(meta_rec, keys, results, start_depth=0,
+                          slot_base=0) -> _PendingBranches:
+    """Decode native BranchMeta records into per-job TrieUpdates, split at
+    the digest boundary: this call is the half that needs no digest, every
+    record of the group at once (the fields, the paths as ``bytes``, the
+    masks, each node made without its hashes, the arena rows of the child
+    hashes); the ``lay_in(digests)`` of what it returns is the other.
     ``keys``: the sweep group's sorted keys, job after job in one array.
+    ``results``: the group's results, indexed by the records' job number.
     ``slot_base`` rebases the records' group-local digest slots into the
     pipeline's shared arena slot space."""
     from ..metrics import trie_metrics
 
     rec = np.ascontiguousarray(meta_rec).view(_META_REC).ravel()
     n = len(rec)
-    trie_metrics.record_decode(n)
+    trie_metrics.record_predecode(n)
     if not n:
-        return results
+        return _PendingBranches(results)
     # paths: the leading nibbles of every record's representative key, row
     # after row in one blob. BranchMeta depths are SUBTRIE-relative; the
     # stored path skips the start_depth prefix nibbles of the full key
@@ -1086,29 +1173,20 @@ def _collect_meta_records(meta_rec, keys, digests, results,
     nibs = np.empty((n, 2 * n_bytes), dtype=np.uint8)
     nibs[:, 0::2] = heads >> 4
     nibs[:, 1::2] = heads & 0xF
-    path_blob = nibs.tobytes()
     path_lo = np.arange(n, dtype=np.intp) * (2 * n_bytes) + start_depth
-    # child hashes: the hashed children's digests of all records, in record
-    # order and ascending nibble order within a record, as one list
-    hashed = ((rec["hash_mask"][:, None] >> np.arange(16, dtype=np.uint16))
-              & 1).astype(bool)
-    child_hashes = iter(
-        digests[rec["child_slot"][hashed] + slot_base]
-        .view("V32").ravel().tolist())
-    branch_nodes = [r.branch_nodes for r in results]
-    # the nodes and tuples made here hold no cycle; the collector's passes
-    # over them, were it left on, would add a third to this function's time
-    gc_was_enabled = gc.isenabled()
-    gc.disable()
-    try:
-        for j, lo, hi, state, tree, hmask, n_hashed in zip(
-                rec["job"].tolist(), path_lo.tolist(),
-                (path_lo + depth).tolist(), rec["state_mask"].tolist(),
-                rec["tree_mask"].tolist(), rec["hash_mask"].tolist(),
-                hashed.sum(axis=1).tolist()):
-            branch_nodes[j][path_blob[lo:hi]] = BranchNode(
-                state, tree, hmask, tuple(islice(child_hashes, n_hashed)))
-    finally:
-        if gc_was_enabled:
-            gc.enable()
-    return results
+    paths = list(map(nibs.tobytes().__getitem__,
+                     map(slice, path_lo.tolist(), (path_lo + depth).tolist())))
+    # child hashes: the arena rows of the hashed children of all records,
+    # in record order and ascending nibble order within a record
+    hmask = rec["hash_mask"]
+    hashed = ((hmask[:, None] >> np.arange(16, dtype=np.uint16)) & 1).astype(bool)
+    # runs of consecutive records of one job (the sweep emits a job's
+    # records together): one dict update a run
+    job = rec["job"]
+    starts = np.flatnonzero(np.r_[True, job[1:] != job[:-1]])
+    runs = list(zip(job[starts].tolist(), np.diff(np.r_[starts, n]).tolist()))
+    return _PendingBranches(
+        results, runs, paths,
+        rec["child_slot"][hashed] + slot_base, hashed.sum(axis=1).tolist(),
+        branch_nodes_hashed_later(rec["state_mask"].tolist(),
+                                  rec["tree_mask"].tolist(), hmask.tolist()))

@@ -243,8 +243,8 @@ def test_the_events_line_prints_the_phases_under_their_names(rebuild_layout):
     line = rep.report_once()
     frag = line[line.index(" rebuild["):]
     frag = frag[:frag.index("]") + 1]
-    for name in ("wait=", "marshal+sweep=", "stage..enqueue=", "device_wait=",
-                 "decode=", "gc="):
+    for name in ("wait=", "marshal+sweep=", "stage..enqueue=", "predecode=",
+                 "device_wait=", "decode=", "gc="):
         assert f" {name}" in frag, frag
     for retired in (" sweep=", " pack=", " disp=", " fetch="):
         assert retired not in frag, frag

@@ -195,26 +195,32 @@ def _prefixed(job, prefix):
                          ids=["1job", "5jobs"])
 def test_bulk_decode_equals_the_loop_on_real_sweeps(turbo_np, monkeypatch,
                                                     start_depth, sizes):
-    calls = []
-    real = turbo._collect_meta_records
+    calls, fetched = [], []
+    real, real_finish = turbo._collect_meta_records, turbo._NumpyBackend.finish
 
-    def spy(meta_rec, keys, digests, results, start_depth=0, slot_base=0):
-        calls.append((meta_rec.copy(), keys, digests, len(results),
-                      start_depth, slot_base))
-        return real(meta_rec, keys, digests, results, start_depth, slot_base)
+    def spy(meta_rec, keys, results, start_depth=0, slot_base=0):
+        calls.append((meta_rec.copy(), keys, len(results), start_depth,
+                      slot_base))
+        return real(meta_rec, keys, results, start_depth, slot_base)
+
+    def finish(backend):
+        fetched.append(real_finish(backend))
+        return fetched[-1]
 
     monkeypatch.setattr(turbo, "_collect_meta_records", spy)
+    monkeypatch.setattr(turbo._NumpyBackend, "finish", finish)
     jobs = [_job(n, seed=50 + i) for i, n in enumerate(sizes)]
     if start_depth:
         jobs = [_prefixed(j, 0x30 + i) for i, j in enumerate(jobs)]
     before = REGISTRY.counter("trie_commit_decode_records_total").value
     got = turbo_np.commit_hashed_many(jobs, collect_branches=True,
                                       start_depth=start_depth)
-    (call,) = calls
-    assert call[3:] == (len(jobs), start_depth, 0)
-    _assert_same_branch_nodes(got, _loop_results(*call))
+    ((meta_rec, keys, *rest),), (digests,) = calls, fetched
+    assert rest == [len(jobs), start_depth, 0]
+    _assert_same_branch_nodes(got, _loop_results(meta_rec, keys, digests,
+                                                 *rest))
     n_records = sum(len(r.branch_nodes) for r in got)
-    assert n_records == len(call[0]) > 0
+    assert n_records == len(meta_rec) > 0
     assert (REGISTRY.counter("trie_commit_decode_records_total").value
             - before) == n_records
     if len(sizes) > 1:
@@ -262,8 +268,8 @@ def test_bulk_decode_equals_the_loop_on_synthetic_records(start_depth,
         # job 1 of the three gets no record
     ]), dtype=np.uint8).reshape(-1, 80)
     got = [TrieBuildResult(root=b"") for _ in range(3)]
-    assert turbo._collect_meta_records(meta_rec, keys, digests, got,
-                                       start_depth, slot_base) is got
+    assert turbo._collect_meta_records(meta_rec, keys, got, start_depth,
+                                       slot_base).lay_in(digests) is got
     _assert_same_branch_nodes(
         got, _loop_results(meta_rec, keys, digests, 3, start_depth,
                            slot_base))
@@ -276,8 +282,8 @@ def test_bulk_decode_equals_the_loop_on_synthetic_records(start_depth,
     # and a larger random set, with records of every job interleaved
     many = _synthetic_records(rng, 300, (5, 4, 6), 64)
     got = [TrieBuildResult(root=b"") for _ in range(3)]
-    turbo._collect_meta_records(many, keys, digests, got, start_depth,
-                                slot_base)
+    turbo._collect_meta_records(many, keys, got, start_depth,
+                                slot_base).lay_in(digests)
     _assert_same_branch_nodes(
         got, _loop_results(many, keys, digests, 3, start_depth,
                            slot_base))
@@ -287,9 +293,8 @@ def test_bulk_decode_of_no_records_leaves_the_results_alone():
     got = [TrieBuildResult(root=b"r")]
     before = REGISTRY.counter("trie_commit_decode_records_total").value
     turbo._collect_meta_records(
-        np.zeros((0, 80), dtype=np.uint8),
-        np.zeros((1, 32), dtype=np.uint8),
-        np.zeros((8, 32), dtype=np.uint8), got)
+        np.zeros((0, 80), dtype=np.uint8), np.zeros((1, 32), dtype=np.uint8),
+        got).lay_in(np.zeros((8, 32), dtype=np.uint8))
     assert got[0].branch_nodes == {} and type(got[0].branch_nodes) is dict
     assert REGISTRY.counter(
         "trie_commit_decode_records_total").value == before
@@ -315,7 +320,7 @@ def test_bulk_decode_stays_faster_than_the_loop():
     new, loop = [], []
     for _ in range(5):
         new.append(timed(lambda: turbo._collect_meta_records(
-            meta_rec, keys, digests, [TrieBuildResult(root=b"")], 2)))
+            meta_rec, keys, [TrieBuildResult(root=b"")], 2).lay_in(digests)))
         loop.append(timed(
             lambda: _loop_results(meta_rec, keys, digests, 1, 2, 0)))
     (new_s, got), (loop_s, want) = (min(r, key=lambda t: t[0])
