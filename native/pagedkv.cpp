@@ -20,7 +20,11 @@
 //     process — so crash recovery can reuse everything in the list);
 //   * DUPSORT: per-key sorted duplicate sets, inline in the leaf cell while
 //     small, spilled to a nested B+tree when large (sub-database, as MDBX);
-//   * overflow page chains for values larger than a leaf cell.
+//   * overflow page chains for values larger than a leaf cell;
+//   * a sorted bulk append (rtpg_append: MDB_APPEND / MDB_APPENDDUP) that
+//     lays a batch sorting after a tree's last entry along its right edge,
+//     page after full page, and refuses a batch out of order with nothing
+//     written; commits count the pages they write (rtpg_pages_written).
 //
 // Deliberate simplifications vs libmdbx (documented, not hidden): pages are
 // not rebalanced on underflow (only emptied pages are unlinked; heavy delete
@@ -236,6 +240,7 @@ struct Env {
   std::vector<uint32_t> reusable;
   std::vector<std::pair<uint64_t, std::vector<uint32_t>>> pending;
   std::vector<uint32_t> freelist_pages;  // current persisted chain
+  uint64_t pages_written = 0;  // by every commit: dirty pages + free-list chain
 };
 
 // Back [env->mapped, size) of the reservation with the data file. The
@@ -1034,6 +1039,7 @@ int tx_commit(Txn* t) {
   if (!write_meta(env, m)) return -1;
   {
     std::lock_guard<std::mutex> g(env->state_mu);
+    env->pages_written += t->dirty.size() + nchain;
     env->meta = m;
     if (!newly_freed.empty()) env->pending.emplace_back(m.txnid, newly_freed);
     env->reusable.insert(env->reusable.end(), t->recycle.begin(),
@@ -1185,6 +1191,187 @@ bool cur_find(Cur* c, Path& path, LeafView* v) {
   return true;
 }
 
+// -- sorted bulk append (MDBX's MDB_APPEND / MDB_APPENDDUP) -------------------
+// A batch that sorts after everything a tree holds is laid along the tree's
+// right edge: the last page of each level takes new cells until it is full,
+// then fresh pages follow it, full too, and their first keys go one level up
+// as separators (the root grows a level where they do not fit). No page is
+// split at a midpoint and no cell is searched for: what a cursor reads back
+// is what the same entries put one by one would give, on fewer pages.
+
+void edge_append(Txn* t, uint32_t* root, uint8_t type,
+                 std::vector<std::string> pending) {
+  if (pending.empty()) return;
+  std::vector<uint32_t> edge;  // the right edge, root first, copied on write
+  if (*root) {
+    uint32_t pg = tx_cow(t, *root);
+    *root = pg;
+    while (true) {
+      edge.push_back(pg);
+      const uint8_t* p = tx_page(t, pg);
+      if (hdr(p)->type != P_BRANCH) break;
+      int idx = hdr(p)->n_cells - 1;
+      uint32_t child = branch_child(cell_at(p, idx));
+      uint32_t nc = tx_cow(t, child);
+      if (nc != child) {
+        uint8_t* wp = tx_writable(t, pg);
+        s32(wp + g16(wp + sizeof(PageHdr) + 2 * idx) + 2, nc);
+      }
+      pg = nc;
+    }
+  }
+  int lvl = static_cast<int>(edge.size()) - 1;  // below 0: a level above the root
+  while (true) {
+    uint32_t keep = lvl >= 0 ? edge[lvl] : 0;
+    std::vector<std::string> all;
+    if (keep) all = explode(tx_page(t, keep));
+    for (auto& c : pending) all.push_back(std::move(c));
+    pending.clear();
+    uint32_t leftmost = 0;
+    size_t a = 0, used = sizeof(PageHdr);
+    for (size_t i = 0; i <= all.size(); i++) {
+      size_t need = i < all.size() ? all[i].size() + 2 : 0;
+      if (i < all.size() && (used + need <= PAGE || i == a)) {
+        used += need;
+        continue;
+      }
+      uint32_t pg = (a == 0 && keep) ? keep : tx_alloc(t);
+      rebuild(tx_writable(t, pg), type, all, a, i);
+      if (a == 0) {
+        leftmost = pg;
+      } else {
+        const uint8_t* p = tx_page(t, pg);
+        std::string_view k = type == P_BRANCH ? branch_key(cell_at(p, 0))
+                                              : leaf_view(cell_at(p, 0)).key;
+        pending.push_back(make_branch_cell(k, pg));
+      }
+      a = i;
+      used = sizeof(PageHdr) + need;
+    }
+    if (pending.empty()) {
+      if (!keep) *root = leftmost;  // a fresh level of one page: the root
+      return;                       // a kept page's parent points at it
+    }
+    if (lvl <= 0) pending.insert(pending.begin(), make_branch_cell("", leftmost));
+    lvl--;
+    type = P_BRANCH;
+  }
+}
+
+bool dups_inline(std::string_view key, size_t payload) {
+  return 8 + key.size() + payload <= MAXCELL && payload <= DUP_SPILL + 4;
+}
+
+// the leaf cell of one key's duplicate set: inline while it fits, else a
+// subtree laid by edge_append
+std::string dup_cell(Txn* t, std::string_view key,
+                     const std::vector<std::string>& dups) {
+  std::string payload = dup_pack(dups);
+  if (dups_inline(key, payload.size()))
+    return make_leaf_cell(L_DUPIN, key, static_cast<uint32_t>(payload.size()),
+                          payload.data(), static_cast<uint32_t>(payload.size()));
+  std::vector<std::string> cells;
+  cells.reserve(dups.size());
+  for (auto& d : dups) cells.push_back(make_leaf_cell(L_INLINE, d, 0, nullptr, 0));
+  uint32_t sub = 0;
+  edge_append(t, &sub, P_LEAF, std::move(cells));
+  uint8_t pb[12];
+  s32(pb, sub);
+  s64(pb + 4, dups.size());
+  return make_leaf_cell(L_DUPTREE, key, 12, pb, 12);
+}
+
+struct Batch {
+  const uint8_t* keys;
+  const uint32_t* klens;
+  const uint8_t* vals;
+  const uint32_t* vlens;
+  uint64_t n;
+  std::vector<std::string_view> k, v;
+};
+
+// 0 = appended; 1 = a pair out of order (within the batch or against the
+// table's last), nothing written; -1 = a pair too large, nothing written
+int table_append(Txn* t, TableInfo* ti, Batch& b, bool dupsort) {
+  b.k.resize(b.n);
+  b.v.resize(b.n);
+  uint64_t ko = 0, vo = 0;
+  for (uint64_t i = 0; i < b.n; i++) {
+    if (b.klens[i] > MAXKEY) return -1;
+    if (dupsort && 8 + b.klens[i] + b.vlens[i] > MAXCELL) return -1;
+    b.k[i] = std::string_view(reinterpret_cast<const char*>(b.keys + ko), b.klens[i]);
+    b.v[i] = std::string_view(reinterpret_cast<const char*>(b.vals + vo), b.vlens[i]);
+    ko += b.klens[i];
+    vo += b.vlens[i];
+    if (i && (b.k[i] < b.k[i - 1] ||
+              (b.k[i] == b.k[i - 1] && (!dupsort || b.v[i] <= b.v[i - 1]))))
+      return 1;
+  }
+  if (!b.n) return 0;
+  // against the table's last entry
+  bool merge = false;  // the batch opens with more duplicates of the last key
+  DupPos last{};
+  Path path;
+  if (ti->root) {
+    descend_edge(t, ti->root, true, path);
+    LeafView lv = leaf_view(cell_at(tx_page(t, path.back().pgno), path.back().idx));
+    if (b.k[0] < lv.key) return 1;
+    if (b.k[0] == lv.key) {
+      if (!dupsort || lv.flags == L_OVERFLOW) return 1;
+      cell_dups(t, lv, &last);
+      std::string top;
+      if (last.is_tree) {
+        if (!subtree_edge(t, last.sub, true, &top)) return 1;
+      } else {
+        if (last.inl.empty()) return 1;
+        top = last.inl.back();
+      }
+      if (b.v[0] <= top) return 1;
+      merge = true;
+    }
+  }
+  ti->dirty = true;
+  ti->count += b.n;
+  uint64_t i = 0;
+  if (merge) {  // more duplicates of the tree's last key
+    std::string_view key = b.k[0];
+    std::vector<std::string> more;
+    while (i < b.n && b.k[i] == key) more.emplace_back(b.v[i++]);
+    std::string cell;
+    if (last.is_tree) {
+      std::vector<std::string> cells;
+      for (auto& d : more) cells.push_back(make_leaf_cell(L_INLINE, d, 0, nullptr, 0));
+      uint32_t sub = last.sub;
+      edge_append(t, &sub, P_LEAF, std::move(cells));
+      uint8_t pb[12];
+      s32(pb, sub);
+      s64(pb + 4, last.count + more.size());
+      cell = make_leaf_cell(L_DUPTREE, key, 12, pb, 12);
+    } else {
+      for (auto& d : more) last.inl.push_back(std::move(d));
+      cell = dup_cell(t, key, last.inl);
+    }
+    bool exact;
+    tree_descend(t, &ti->root, key, path, true, &exact);
+    leaf_put_cell(t, &ti->root, path, std::move(cell), true);
+  }
+  std::vector<std::string> cells;
+  while (i < b.n) {
+    if (!dupsort) {
+      cells.push_back(plain_cell(t, b.k[i], reinterpret_cast<const uint8_t*>(b.v[i].data()),
+                                 static_cast<uint32_t>(b.v[i].size())));
+      i++;
+      continue;
+    }
+    std::string_view key = b.k[i];
+    std::vector<std::string> dups;
+    while (i < b.n && b.k[i] == key) dups.emplace_back(b.v[i++]);
+    cells.push_back(dup_cell(t, key, dups));
+  }
+  edge_append(t, &ti->root, P_LEAF, std::move(cells));
+  return 0;
+}
+
 }  // namespace
 
 extern "C" {
@@ -1328,6 +1515,28 @@ int rtpg_get(void* txnp, const char* table, const uint8_t* key, uint32_t klen,
   *out = reinterpret_cast<const uint8_t*>(txn->valbuf.data());
   *out_len = static_cast<uint32_t>(txn->valbuf.size());
   return 1;
+}
+
+// Sorted bulk append: n (key, value) pairs, keys and values each laid end
+// to end with their lengths beside them, in ascending (key, value) order and
+// above the table's last entry. 0 = appended, 1 = out of order, -1 = a pair
+// too large or a read-only txn; on 1 and -1 nothing was written.
+int rtpg_append(void* txnp, const char* table, const uint8_t* keys,
+                const uint32_t* klens, const uint8_t* vals,
+                const uint32_t* vlens, uint64_t n, int dupsort) {
+  auto txn = static_cast<Txn*>(txnp);
+  std::lock_guard<std::recursive_mutex> op_guard(txn->op_mu);
+  if (!txn->write) return -1;
+  TableInfo* ti = tx_table(txn, table, true);
+  Batch b{keys, klens, vals, vlens, n, {}, {}};
+  return table_append(txn, ti, b, dupsort != 0);
+}
+
+// pages the environment's commits have written since it was opened
+uint64_t rtpg_pages_written(void* envp) {
+  auto env = static_cast<Env*>(envp);
+  std::lock_guard<std::mutex> g(env->state_mu);
+  return env->pages_written;
 }
 
 uint64_t rtpg_entry_count(void* txnp, const char* table) {

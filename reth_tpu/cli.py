@@ -1077,7 +1077,9 @@ def cmd_p2p(args):
         peer.close()
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
+    """Every command's arguments and their defaults: what ``main`` parses,
+    for any caller that wants the arguments a command line gives."""
     parser = argparse.ArgumentParser(prog="reth-tpu", description=__doc__)
     sub = parser.add_subparsers(dest="command", required=True)
 
@@ -1534,8 +1536,11 @@ def main(argv=None) -> int:
     add_hasher(pr)
     add_db_arg(pr)
     pr.set_defaults(fn=cmd_stage_run)
+    return parser
 
-    args = parser.parse_args(argv)
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
     if getattr(args, "hasher", "cpu") != "cpu":
         # the ONE place the persistent XLA compile cache is configured,
         # before anything compiles (ops/device.py)

@@ -575,6 +575,96 @@ def _install_gc_hook(hook: CollectorMetrics) -> None:
 _install_gc_hook(gc_metrics)
 
 
+class MerkleStageMetrics:
+    """The clean ``MerkleStage``'s storage chunk (``stages/merkle.py``
+    ``_storage_chunk``), leg by leg, the way :meth:`TrieMetrics.phase`
+    times a commit: ``read`` (one cursor a trie over ``HashedStorages``,
+    each slot decoded and RLP-encoded), ``commit`` (the ``_commit_subtries``
+    call) and ``write`` (the branch nodes into ``StoragesTrie``, the
+    storage roots into ``HashedAccounts``, the progress blob). Each leg is a
+    ``stages::merkle:<leg>`` span (on the profiler's clock too) whose wall
+    adds to ``stage_merkle_<leg>_seconds_total``; beside them the chunk's
+    counts. The transaction's commit that follows is ``DbMetrics``'."""
+
+    LEGS = ("read", "commit", "write")
+
+    def __init__(self, registry: MetricsRegistry | None = None):
+        reg = registry or REGISTRY
+        self._leg_s = {k: reg.counter(f"stage_merkle_{k}_seconds_total")
+                       for k in self.LEGS}
+        self._slots = reg.counter(
+            "stage_merkle_slots_read_total",
+            "storage slots a chunk read from HashedStorages")
+        self._nodes = reg.counter(
+            "stage_merkle_branch_nodes_written_total",
+            "branch nodes a chunk put into StoragesTrie")
+        self._accounts = reg.counter(
+            "stage_merkle_accounts_written_total",
+            "HashedAccounts entries a chunk gave a new storage root")
+
+    @contextlib.contextmanager
+    def leg(self, name: str):
+        t0 = time.perf_counter()
+        try:
+            with tracing.span("stages::merkle", name):
+                yield
+        finally:
+            self._leg_s[name].increment(time.perf_counter() - t0)
+
+    def record_chunk(self, slots: int, nodes: int, accounts: int) -> None:
+        self._slots.increment(slots)
+        self._nodes.increment(nodes)
+        self._accounts.increment(accounts)
+
+
+merkle_stage_metrics = MerkleStageMetrics()
+
+
+class DbMetrics:
+    """A read-write transaction's commit (``storage/native.py``
+    ``NativeTx.commit``): its wall, the engine's syncs included, is the
+    annotation ``storage::commit`` on the profiler's clock (with tracing on,
+    a ``storage`` ``commit`` flight-recorder span with ``pages``) and adds
+    to ``db_commit_seconds_total``; ``db_commits_total`` counts the commits
+    and ``db_commit_pages_written_total`` the pages they wrote, as the
+    paged engine counts them (``rtpg_pages_written``: dirty pages and the
+    free list's chain)."""
+
+    def __init__(self, registry: MetricsRegistry | None = None):
+        reg = registry or REGISTRY
+        self._seconds = reg.counter(
+            "db_commit_seconds_total",
+            "wall of read-write commits, the engine's fdatasync calls included")
+        self._commits = reg.counter("db_commits_total")
+        self._pages = reg.counter(
+            "db_commit_pages_written_total",
+            "pages the paged engine's commits wrote")
+
+    @contextlib.contextmanager
+    def commit(self, pages_written=None):
+        """Around one commit; ``pages_written()`` reads the engine's running
+        count of pages written, or None where it keeps none."""
+        p0 = pages_written() if pages_written is not None else None
+        ann = tracing.annotation("storage::commit")
+        wall0 = time.time()
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            dt = time.perf_counter() - t0
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            pages = (pages_written() - p0) if p0 is not None else 0
+            self._seconds.increment(dt)
+            self._commits.increment()
+            self._pages.increment(pages)
+            tracing.record_span("storage", "commit", wall0, dt,
+                                fields={"pages": pages})
+
+
+db_metrics = DbMetrics()
+
+
 class SparseCommitMetrics:
     """Parallel sparse-commit observability (trie/sparse.py
     ParallelSparseCommitter + the proof-worker pool): packed levels and

@@ -69,15 +69,23 @@ class Pipeline:
         """Run every stage to ``target`` (committing per stage iteration)."""
         for stage in self.stages:
             while True:
-                with self.factory.provider_rw() as provider:
-                    checkpoint = provider.stage_checkpoint(stage.id)
-                    if checkpoint >= target:
-                        break
-                    out = stage.execute(provider, ExecInput(target, checkpoint))
-                    provider.save_stage_checkpoint(stage.id, out.checkpoint)
-                    self.events.append(("stage", stage.id, out.checkpoint))
-                if out.done:
+                out = self.step(stage, target)
+                if out is None or out.done:
                     break
+
+    def step(self, stage: Stage, target: int) -> ExecOutput | None:
+        """One iteration of :meth:`run`'s loop: one read-write transaction,
+        one ``execute``, the stage's checkpoint, the commit (an exception
+        aborts the transaction). None where the stage already stood at
+        ``target``."""
+        with self.factory.provider_rw() as provider:
+            checkpoint = provider.stage_checkpoint(stage.id)
+            if checkpoint >= target:
+                return None
+            out = stage.execute(provider, ExecInput(target, checkpoint))
+            provider.save_stage_checkpoint(stage.id, out.checkpoint)
+            self.events.append(("stage", stage.id, out.checkpoint))
+        return out
 
     def unwind(self, target: int) -> None:
         """Unwind all stages (reverse order) down to ``target``."""

@@ -16,11 +16,28 @@ from __future__ import annotations
 
 from ..etl import Collector
 from ..storage.provider import DatabaseProvider
-from ..storage.tables import Tables, decode_account, decode_storage_entry
+from ..storage.tables import (Tables, decode_account, decode_storage_entry,
+                              encode_storage_entry)
 from ..trie.committer import TrieCommitter
 from .api import ExecInput, ExecOutput, Stage, UnwindInput
 
 _SCAN_CHUNK = 200_000  # keys hashed per device dispatch during clean scans
+
+
+def _drain(pairs, tx, table: str, dupsort: bool) -> None:
+    """A clean drain's sorted pairs into the table the drain cleared, by
+    the store's sorted bulk append (``Tx.append``), ``_SCAN_CHUNK`` pairs
+    a call."""
+    keys: list[bytes] = []
+    values: list[bytes] = []
+    for k, v in pairs:
+        keys.append(k)
+        values.append(v)
+        if len(keys) >= _SCAN_CHUNK:
+            tx.append(table, keys, values, dupsort)
+            keys, values = [], []
+    if keys:
+        tx.append(table, keys, values, dupsort)
 
 
 class AccountHashingStage(Stage):
@@ -54,8 +71,7 @@ class AccountHashingStage(Stage):
                         flush()
                 if batch:
                     flush()
-                for haddr, value in col:
-                    provider.tx.put(Tables.HashedAccounts.name, haddr, value)
+                _drain(col, provider.tx, Tables.HashedAccounts.name, dupsort=False)
         else:
             changed = provider.account_changes_in_range(inp.next_block, inp.target)
             addrs = sorted(changed.keys())
@@ -109,10 +125,14 @@ class StorageHashingStage(Stage):
                         flush()
                 if batch:
                     flush()
-                for key, value32 in col:
-                    provider.put_hashed_storage(
-                        key[:32], key[32:], int.from_bytes(value32, "big")
-                    )
+                def entries():
+                    for key, value32 in col:
+                        value = int.from_bytes(value32, "big")
+                        if value:  # a zero slot holds no entry
+                            yield key[:32], encode_storage_entry(key[32:], value)
+
+                _drain(entries(), provider.tx, Tables.HashedStorages.name,
+                       dupsort=True)
         else:
             changed = provider.storage_changes_in_range(inp.next_block, inp.target)
             self._apply_changed(provider, changed, use_prev_images=False)

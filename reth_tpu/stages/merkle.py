@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..metrics import merkle_stage_metrics
 from ..primitives.nibbles import unpack_nibbles
 from ..primitives.rlp import encode_int, rlp_encode
 from ..primitives.types import EMPTY_ROOT_HASH
@@ -166,38 +167,47 @@ class MerkleStage(Stage):
         return self._account_chunk(p, tb, blob[9:])
 
     def _storage_chunk(self, p: DatabaseProvider, tb: bytes, last_addr: bytes) -> None:
-        """Commit storage tries for the next batch of hashed addresses."""
-        cur = p.tx.cursor(Tables.HashedStorages.name)
-        entry = cur.seek((last_addr + b"\x00") if last_addr else b"")
-        # seek lands inside last_addr's dups when extending; skip them
-        while entry is not None and entry[0] <= last_addr:
-            entry = cur.next_no_dup()
-        addrs: list[bytes] = []
-        jobs = []
-        leaves = 0
-        while entry is not None and leaves < self.chunk_leaves:
-            addr = entry[0]
-            pairs = []
-            for _, dup in p.tx.cursor(Tables.HashedStorages.name).walk_dup(addr):
-                slot, value = T.decode_storage_entry(dup)
-                pairs.append((slot, rlp_encode(encode_int(value))))
-            addrs.append(addr)
-            keys = np.frombuffer(b"".join(s for s, _ in pairs), dtype=np.uint8).reshape(-1, 32)
-            jobs.append((keys, [v for _, v in pairs]))
-            leaves += len(pairs)
-            entry = cur.next_no_dup()
+        """Commit storage tries for the next batch of hashed addresses. Its
+        legs (``read``, ``commit``, ``write``) are ``merkle_stage_metrics``'
+        spans and counters."""
+        with merkle_stage_metrics.leg("read"):
+            cur = p.tx.cursor(Tables.HashedStorages.name)
+            entry = cur.seek((last_addr + b"\x00") if last_addr else b"")
+            # seek lands inside last_addr's dups when extending; skip them
+            while entry is not None and entry[0] <= last_addr:
+                entry = cur.next_no_dup()
+            addrs: list[bytes] = []
+            jobs = []
+            leaves = 0
+            while entry is not None and leaves < self.chunk_leaves:
+                addr = entry[0]
+                pairs = []
+                for _, dup in p.tx.cursor(Tables.HashedStorages.name).walk_dup(addr):
+                    slot, value = T.decode_storage_entry(dup)
+                    pairs.append((slot, rlp_encode(encode_int(value))))
+                addrs.append(addr)
+                keys = np.frombuffer(b"".join(s for s, _ in pairs), dtype=np.uint8).reshape(-1, 32)
+                jobs.append((keys, [v for _, v in pairs]))
+                leaves += len(pairs)
+                entry = cur.next_no_dup()
         if not addrs:  # storage phase complete
             p.save_stage_progress(self.id, b"A" + tb)
             return None
-        results = self._commit_subtries(jobs)
-        for addr, res in zip(addrs, results):
-            for path, node in res.branch_nodes.items():
-                p.put_storage_branch(addr, path, node)
-            acct = p.hashed_account(addr)
-            if acct is not None and acct.storage_root != res.root:
-                p.put_hashed_account(addr, acct.with_(storage_root=res.root),
-                                     preserve_storage_root=False)
-        p.save_stage_progress(self.id, b"S" + tb + addrs[-1])
+        with merkle_stage_metrics.leg("commit"):
+            results = self._commit_subtries(jobs)
+        nodes = accounts = 0
+        with merkle_stage_metrics.leg("write"):
+            for addr, res in zip(addrs, results):
+                for path, node in res.branch_nodes.items():
+                    p.put_storage_branch(addr, path, node)
+                nodes += len(res.branch_nodes)
+                acct = p.hashed_account(addr)
+                if acct is not None and acct.storage_root != res.root:
+                    p.put_hashed_account(addr, acct.with_(storage_root=res.root),
+                                         preserve_storage_root=False)
+                    accounts += 1
+            p.save_stage_progress(self.id, b"S" + tb + addrs[-1])
+        merkle_stage_metrics.record_chunk(leaves, nodes, accounts)
         return None
 
     def _account_chunk(self, p: DatabaseProvider, tb: bytes,

@@ -172,6 +172,37 @@ class Cursor:
 _EMPTY_TABLE: dict = {}
 
 
+def _rows(rows) -> list[bytes]:
+    """A batch's keys or values as bytes: rows of a 2-D uint8 array, or
+    the list itself."""
+    if hasattr(rows, "ndim"):
+        return [r.tobytes() for r in rows]
+    return list(rows)
+
+
+def check_append_order(last, keys, values, dupsort: bool) -> None:
+    """Refuse (``ValueError``) a batch for ``Tx.append`` that is not in
+    strictly ascending (key, value) order (key order alone without
+    ``dupsort``) or does not sort after ``last``, the table's last entry
+    (a cursor's ``last()``: None for an empty table)."""
+    if len(keys) != len(values):
+        raise ValueError(f"append: {len(keys)} keys and {len(values)} values")
+    prev = last
+    for k, v in zip(_rows(keys), _rows(values)):
+        if prev is not None and (k < prev[0] or (
+                k == prev[0] and (not dupsort or v <= prev[1]))):
+            raise ValueError("append: a pair out of order; nothing written")
+        prev = (k, v)
+
+
+def append_by_puts(tx, table: str, keys, values, dupsort: bool) -> None:
+    """``Tx.append`` as one ``put`` a pair once the whole batch's order is
+    checked: the stores with no bulk path of their own."""
+    check_append_order(tx.cursor(table).last(), keys, values, dupsort)
+    for k, v in zip(_rows(keys), _rows(values)):
+        tx.put(table, k, v, dupsort)
+
+
 class Tx:
     """A transaction with MVCC snapshot isolation.
 
@@ -317,6 +348,18 @@ class Tx:
                     dups.insert(j, value)
         else:
             t[key] = value
+
+    def append(self, table: str, keys, values, dupsort: bool = False):
+        """Sorted bulk append (MDBX's ``MDB_APPEND`` / ``MDB_APPENDDUP``,
+        reth's ``DbCursorRW::append`` / ``DbDupCursorRW::append_dup``):
+        ``keys`` and ``values`` (lists of bytes, or 2-D uint8 arrays of
+        rows) in strictly ascending (key, value) order, every pair above
+        the table's last entry; with ``dupsort`` a key repeats with its
+        duplicates. A pair out of order refuses the whole batch with
+        nothing written (``ValueError``). What a cursor reads back equals
+        the same pairs put one by one."""
+        assert self._write, "read-only transaction"
+        append_by_puts(self, table, keys, values, dupsort)
 
     def delete(self, table: str, key: bytes, value: bytes | None = None):
         """Delete a key (or one duplicate when ``value`` given)."""

@@ -20,6 +20,11 @@ import subprocess
 import threading
 from pathlib import Path
 
+import numpy as np
+
+from ..metrics import db_metrics
+from .kv import append_by_puts
+
 _NATIVE_DIR = Path(__file__).resolve().parent.parent.parent / "native"
 _build_lock = threading.Lock()
 _apis: dict = {}
@@ -39,11 +44,16 @@ class _Api:
         "cursor_next", "cursor_prev", "cursor_next_dup", "cursor_seek_dup",
     ]
 
+    # the paged engine's alone: None where the library lacks them
+    _OPTIONAL = ["append", "pages_written"]
+
     def __init__(self, lib: ctypes.CDLL, prefix: str):
         for name in self._FUNCS:
             # "del" is a Python keyword: expose as del_
             setattr(self, name if name != "del" else "del_",
                     getattr(lib, f"{prefix}_{name}"))
+        for name in self._OPTIONAL:
+            setattr(self, name, getattr(lib, f"{prefix}_{name}", None))
 
 
 def _load_api(src_name: str, prefix: str) -> _Api:
@@ -98,6 +108,14 @@ def _load_api(src_name: str, prefix: str) -> _Api:
         f("cursor_next_dup").argtypes = [ctypes.c_void_p] + out4
         f("cursor_seek_dup").argtypes = [
             ctypes.c_void_p, u8p, ctypes.c_uint32, u8p, ctypes.c_uint32] + out4
+        if hasattr(lib, f"{p}_append"):
+            u32p = ctypes.POINTER(ctypes.c_uint32)
+            f("append").restype = ctypes.c_int
+            f("append").argtypes = [ctypes.c_void_p, ctypes.c_char_p, ctypes.c_void_p,
+                                    u32p, ctypes.c_void_p, u32p,
+                                    ctypes.c_uint64, ctypes.c_int]
+            f("pages_written").restype = ctypes.c_uint64
+            f("pages_written").argtypes = [ctypes.c_void_p]
         api = _Api(lib, prefix)
         _apis[prefix] = api
         return api
@@ -110,6 +128,17 @@ def load_library():
 
 def _buf(b: bytes):
     return (ctypes.c_uint8 * len(b)).from_buffer_copy(b) if b else None
+
+
+def _packed(rows):
+    """A batch's keys or values laid end to end, and their lengths: from
+    rows of one width (a 2-D uint8 array) or a list of bytes."""
+    if isinstance(rows, np.ndarray):
+        n, width = rows.shape   # rows of one width: a 2-D array
+        return (np.ascontiguousarray(rows, dtype=np.uint8).reshape(-1),
+                np.full(n, width, dtype=np.uint32))
+    return (np.frombuffer(b"".join(rows) or b"\0", dtype=np.uint8),
+            np.fromiter(map(len, rows), dtype=np.uint32, count=len(rows)))
 
 
 class NativeCursor:
@@ -242,6 +271,32 @@ class NativeTx:
         self._api.put(self._txn, table.encode(), _buf(key), len(key),
                            _buf(value), len(value), 1 if dupsort else 0)
 
+    def append(self, table: str, keys, values, dupsort: bool = False):
+        """Sorted bulk append (``kv.Tx.append``): one call into the paged
+        engine for the batch; the WAL engine checks the order here and puts
+        the pairs one by one."""
+        assert self._write, "read-only transaction"
+        if self._api.append is None:
+            append_by_puts(self, table, keys, values, dupsort)
+            return
+        self._key_cache.pop(table, None)
+        kbuf, klens = _packed(keys)
+        vbuf, vlens = _packed(values)
+        if len(klens) != len(vlens):
+            raise ValueError(f"append to {table}: {len(klens)} keys and "
+                             f"{len(vlens)} values")
+        u32p = ctypes.POINTER(ctypes.c_uint32)
+        rc = self._api.append(self._txn, table.encode(), kbuf.ctypes.data,
+                              klens.ctypes.data_as(u32p), vbuf.ctypes.data,
+                              vlens.ctypes.data_as(u32p), len(klens),
+                              1 if dupsort else 0)
+        if rc == 1:
+            raise ValueError(f"append to {table}: a pair out of order; "
+                             f"nothing written")
+        if rc != 0:
+            raise ValueError(f"append to {table}: a pair too large; "
+                             f"nothing written")
+
     def delete(self, table: str, key: bytes, value: bytes | None = None) -> bool:
         assert self._write, "read-only transaction"
         self._key_cache.pop(table, None)
@@ -258,10 +313,19 @@ class NativeTx:
 
     def commit(self):
         assert not self._done
-        rc = self._api.commit(self._txn)
+        if self._write:
+            with db_metrics.commit(self._pages_written):
+                rc = self._api.commit(self._txn)
+        else:
+            rc = self._api.commit(self._txn)
         self._done = True
         if rc != 0:
             raise OSError("native KV commit failed (WAL write error)")
+
+    def _pages_written(self) -> int | None:
+        if self._api.pages_written is None:
+            return None
+        return int(self._api.pages_written(self._db._env))
 
     def abort(self):
         if not self._done:

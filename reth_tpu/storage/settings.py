@@ -113,6 +113,9 @@ class SplitTx:
     def put(self, table, key, value, dupsort: bool = False):
         return self._t(table).put(table, key, value, dupsort)
 
+    def append(self, table, keys, values, dupsort: bool = False):
+        return self._t(table).append(table, keys, values, dupsort)
+
     def delete(self, table, key, value=None):
         return self._t(table).delete(table, key, value)
 
