@@ -580,11 +580,15 @@ class MerkleStageMetrics:
     ``_storage_chunk``), leg by leg, the way :meth:`TrieMetrics.phase`
     times a commit: ``read`` (one cursor a trie over ``HashedStorages``,
     each slot decoded and RLP-encoded), ``commit`` (the ``_commit_subtries``
-    call) and ``write`` (the branch nodes into ``StoragesTrie``, the
-    storage roots into ``HashedAccounts``, the progress blob). Each leg is a
-    ``stages::merkle:<leg>`` span (on the profiler's clock too) whose wall
-    adds to ``stage_merkle_<leg>_seconds_total``; beside them the chunk's
-    counts. The transaction's commit that follows is ``DbMetrics``'."""
+    call) and ``write`` (the branch nodes into ``StoragesTrie`` by one
+    sorted append a chunk, or node by node where the store's last key or the
+    batch's order refuses it; the storage roots into ``HashedAccounts``; the
+    progress blob). Each leg is a ``stages::merkle:<leg>`` span (on the
+    profiler's clock too) whose wall adds to
+    ``stage_merkle_<leg>_seconds_total``; beside them the chunk's counts,
+    and the write's ``nodes_appended`` and ``append_replays``, which are
+    fields of its span too. The transaction's commit that follows is
+    ``DbMetrics``'."""
 
     LEGS = ("read", "commit", "write")
 
@@ -601,15 +605,31 @@ class MerkleStageMetrics:
         self._accounts = reg.counter(
             "stage_merkle_accounts_written_total",
             "HashedAccounts entries a chunk gave a new storage root")
+        self._appended = reg.counter(
+            "stage_merkle_branch_nodes_appended_total",
+            "branch nodes a chunk's one sorted append put into StoragesTrie")
+        self._replays = reg.counter(
+            "stage_merkle_append_replays_total",
+            "chunks whose branch nodes went into StoragesTrie node by node")
 
     @contextlib.contextmanager
     def leg(self, name: str):
+        """One leg; yields its span's context (None with tracing off)."""
         t0 = time.perf_counter()
         try:
-            with tracing.span("stages::merkle", name):
-                yield
+            with tracing.span("stages::merkle", name) as ctx:
+                yield ctx
         finally:
             self._leg_s[name].increment(time.perf_counter() - t0)
+
+    def record_append(self, ctx, appended: int, replayed: bool) -> None:
+        """The write leg's batch: the nodes its append took, or one replay;
+        ``ctx`` is the leg's span context, which takes both as fields."""
+        self._appended.increment(appended)
+        self._replays.increment(int(replayed))
+        if ctx is not None:
+            ctx.fields.update(nodes_appended=appended,
+                              append_replays=int(replayed))
 
     def record_chunk(self, slots: int, nodes: int, accounts: int) -> None:
         self._slots.increment(slots)

@@ -43,6 +43,12 @@ INVALID_STATE_ROOT = (
 _EMPTY_PREFIX = b"\x00" * 32  # progress marker: prefix holds no accounts
 
 
+def _trie_order(path: bytes) -> tuple[int, bytes]:
+    """A branch path's place among its trie's ``StoragesTrie`` entries,
+    which lead with the path's length."""
+    return len(path), path
+
+
 class MerkleStage(Stage):
     id = "MerkleExecute"
 
@@ -196,16 +202,21 @@ class MerkleStage(Stage):
         with merkle_stage_metrics.leg("commit"):
             results = self._commit_subtries(jobs)
         nodes = accounts = 0
-        with merkle_stage_metrics.leg("write"):
-            for addr, res in zip(addrs, results):
-                for path, node in res.branch_nodes.items():
-                    p.put_storage_branch(addr, path, node)
-                nodes += len(res.branch_nodes)
-                acct = p.hashed_account(addr)
-                if acct is not None and acct.storage_root != res.root:
-                    p.put_hashed_account(addr, acct.with_(storage_root=res.root),
-                                         preserve_storage_root=False)
-                    accounts += 1
+        with merkle_stage_metrics.leg("write") as span:
+            # the table's order: the batch goes in by one sorted append
+            with p.storage_branch_batch() as batch:
+                for addr, res in zip(addrs, results):
+                    branches = res.branch_nodes
+                    for path in sorted(branches, key=_trie_order):
+                        p.put_storage_branch(addr, path, branches[path])
+                    nodes += len(branches)
+                    acct = p.hashed_account(addr)
+                    if acct is not None and acct.storage_root != res.root:
+                        p.put_hashed_account(addr, acct.with_(storage_root=res.root),
+                                             preserve_storage_root=False)
+                        accounts += 1
+            merkle_stage_metrics.record_append(span, batch.appended,
+                                               batch.replayed)
             p.save_stage_progress(self.id, b"S" + tb + addrs[-1])
         merkle_stage_metrics.record_chunk(leaves, nodes, accounts)
         return None

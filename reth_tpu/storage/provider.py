@@ -11,6 +11,7 @@ non-breaking.
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 
 from ..primitives.types import Account, Block, Header, Receipt, Transaction, Withdrawal
@@ -34,6 +35,36 @@ class BlockBodyIndices:
         return self.first_tx_num + self.tx_count
 
 
+class StorageBranchBatch:
+    """The ``StoragesTrie`` entries ``put_storage_branch`` collects inside
+    :meth:`DatabaseProvider.storage_branch_batch`: hashed addresses and
+    encoded entries in two flat lists, in call order. Once the scope has
+    written them, ``appended`` counts the entries one ``Tx.append`` took and
+    ``replayed`` says whether they went in one ``_replace_dup`` each
+    instead."""
+
+    __slots__ = ("keys", "values", "appended", "replayed")
+
+    def __init__(self):
+        self.keys: list[bytes] = []
+        self.values: list[bytes] = []
+        self.appended = 0
+        self.replayed = False
+
+
+def _paths_ascend(keys: list[bytes], values: list[bytes]) -> bool:
+    """True where (address, path length, path) strictly increases through
+    a batch of ``StoragesTrie`` entries (a value's length byte and path
+    lead it, so they compare as that pair)."""
+    prev_k = prev_p = None
+    for k, v in zip(keys, values):
+        p = v[: v[0] + 1]
+        if prev_k is not None and (k < prev_k or (k == prev_k and p <= prev_p)):
+            return False
+        prev_k, prev_p = k, p
+    return True
+
+
 class DatabaseProvider:
     """A transaction-scoped typed view of the database.
 
@@ -44,6 +75,7 @@ class DatabaseProvider:
     def __init__(self, tx: Tx, static_files=None):
         self.tx = tx
         self.static_files = static_files
+        self._branch_batch: StorageBranchBatch | None = None
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -334,11 +366,49 @@ class DatabaseProvider:
         return T.decode_branch_node(raw) if raw else None
 
     def put_storage_branch(self, hashed_addr: bytes, path: bytes, node):
+        entry = T.encode_storage_trie_entry(path, node)
+        batch = self._branch_batch
+        if batch is not None:
+            batch.keys.append(hashed_addr)
+            batch.values.append(entry)
+            return
         # the 1-byte length prefix makes prefix-match == exact-path-match
-        self._replace_dup(
-            Tables.StoragesTrie.name, hashed_addr, bytes([len(path)]) + path,
-            T.encode_storage_trie_entry(path, node),
-        )
+        self._replace_dup(Tables.StoragesTrie.name, hashed_addr,
+                          entry[: len(path) + 1], entry)
+
+    @contextlib.contextmanager
+    def storage_branch_batch(self):
+        """Collect the scope's ``put_storage_branch`` calls (yielded as a
+        :class:`StorageBranchBatch`; reads inside the scope do not see them)
+        and write them on a clean exit by ONE sorted ``Tx.append``
+        (``MDB_APPENDDUP``), where that gives the table the per-node puts
+        would: the batch's first address sorts after the table's last key,
+        and (address, path length, path) strictly increases through the
+        batch, so no put would find an entry to replace. Otherwise, or where
+        the store refuses the append, the batch is replayed in call order,
+        one ``_replace_dup`` an entry. An exception drops the batch
+        unwritten."""
+        assert self._branch_batch is None, "nested storage_branch_batch"
+        batch = self._branch_batch = StorageBranchBatch()
+        try:
+            yield batch
+        finally:
+            self._branch_batch = None
+        if not batch.keys:
+            return
+        table = Tables.StoragesTrie.name
+        last = self.tx.cursor(table).last()
+        if ((last is None or batch.keys[0] > last[0])
+                and _paths_ascend(batch.keys, batch.values)):
+            try:
+                self.tx.append(table, batch.keys, batch.values, dupsort=True)
+                batch.appended = len(batch.keys)
+                return
+            except ValueError:
+                pass
+        batch.replayed = True
+        for key, entry in zip(batch.keys, batch.values):
+            self._replace_dup(table, key, entry[: entry[0] + 1], entry)
 
     def storage_branch(self, hashed_addr: bytes, path: bytes):
         dup = self._get_dup(
